@@ -477,7 +477,7 @@ static void hll_fold_u64_range(const uint64_t* keys, int64_t n, uint64_t seed,
 }
 
 // Host-side HLL fold over u64 keys: the transfer-adaptive ingest path.
-// When the host->device link is slow (e.g. a tunneled device), shipping
+// When the host->device link is slow, shipping
 // 8 B/key loses to folding locally and shipping the 16 KB sketch — the
 // same move-the-reduction-across-the-slow-link trick as PFMERGE across
 // ICI. Threads fold disjoint slices into private register arrays, merged

@@ -455,10 +455,7 @@ def pad_rows_repeat(rows):
 # np.maximum.reduce -> import round-trip).
 # ---------------------------------------------------------------------------
 
-try:  # jax >= 0.6 exports shard_map at top level
-    from jax import shard_map as _shard_map
-except ImportError:  # pragma: no cover - version-dependent import path
-    from jax.experimental.shard_map import shard_map as _shard_map
+from jax import shard_map as _shard_map
 
 from jax.sharding import PartitionSpec as _P
 
@@ -566,22 +563,14 @@ def bitset_pack(bits):
     """[m] uint8 cells -> packed bytes (numpy packbits big-endian order:
     absolute bit i -> byte i>>3, bit 7-(i&7)). The device half of pulling a
     bloom/bitset to the host mirror: 1 bit/bit over the link, not 1 byte."""
-    m = bits.shape[0]
-    pad = (-m) % 8
-    if pad:
-        bits = jnp.concatenate([bits, jnp.zeros((pad,), bits.dtype)])
-    w = jnp.asarray([128, 64, 32, 16, 8, 4, 2, 1], jnp.int32)
-    return jnp.sum(bits.reshape(-1, 8).astype(jnp.int32) * w, axis=1).astype(
-        jnp.uint8)
+    return bitset.pack(bits)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
 def bitset_absorb_packed(bits, packed):
     """OR a packed (big-endian) bitmap into [m] uint8 cells — the device
     half of the bloom hostfold absorb."""
-    m = bits.shape[0]
-    sh = jnp.asarray([7, 6, 5, 4, 3, 2, 1, 0], jnp.uint8)
-    unpacked = ((packed[:, None] >> sh[None, :]) & 1).reshape(-1)[:m]
+    unpacked = bitset.unpack(packed, bits.shape[0])
     return jnp.maximum(bits, unpacked.astype(bits.dtype))
 
 
@@ -600,9 +589,7 @@ def bitset_absorb_packed(bits, packed):
 def delta_unpack(packed, cells: int):
     """Packed big-endian byte plane -> [cells] uint8 cells (bit i lives at
     byte i>>3, bit 7-(i&7) — numpy packbits order, see bitset_pack)."""
-    sh = jnp.asarray([7, 6, 5, 4, 3, 2, 1, 0], jnp.uint8)
-    return ((packed[:, None] >> sh[None, :]) & 1).astype(
-        jnp.uint8).reshape(-1)[:cells]
+    return bitset.unpack(packed, cells)
 
 
 @functools.partial(jax.jit, static_argnames=("nbytes",))
@@ -625,9 +612,9 @@ def delta_merge_stack(old, delta):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=("n_hll", "lanes", "want_old"))
+                   static_argnames=("n_hll", "lanes", "want_old", "mesh"))
 def tape_apply(bank, wire, table, hll_rows, store_old, *,
-               n_hll: int, lanes: int, want_old: bool = False):
+               n_hll: int, lanes: int, want_old: bool = False, mesh=None):
     """Consume one encoded window tape in a SINGLE device call.
 
     The whole window retire — bank-row gather, old-state assembly,
@@ -646,7 +633,9 @@ def tape_apply(bank, wire, table, hll_rows, store_old, *,
     the host swaps in the merged rows). Returns ``(bank, merged [T2, L],
     changed [T2] bool, old_packed [T2, L//8] | None)`` where
     ``old_packed`` holds the PRE-merge bits of every row (big-endian
-    packbits order) for bitset old-bit reads."""
+    packbits order) for bitset old-bit reads. With ``mesh`` (the mesh
+    data plane's sharded bank) every device merges the replicated window:
+    a Mosaic kernel cannot be partitioned automatically."""
     from redisson_tpu.ops import window_kernel as wk
 
     t2 = table.shape[0]
@@ -668,11 +657,12 @@ def tape_apply(bank, wire, table, hll_rows, store_old, *,
     old = jnp.stack(rows)
     old_packed = None
     if want_old:
-        w8 = jnp.asarray([128, 64, 32, 16, 8, 4, 2, 1], jnp.int32)
-        old_packed = jnp.sum(
-            jnp.minimum(old, 1).astype(jnp.int32).reshape(t2, lanes // 8, 8)
-            * w8, axis=2).astype(jnp.uint8)
-    merged, changed = wk.window_merge(old, wire, table)
+        old_packed = bitset.pack(old.reshape(-1)).reshape(t2, lanes // 8)
+    merge = wk.window_merge
+    if mesh is not None:
+        merge = _shard_map(merge, mesh=mesh, in_specs=(_P(),) * 3,
+                           out_specs=(_P(), _P()), check_vma=False)
+    merged, changed = merge(old, wire, table)
     if n_hll:
         h2 = hll_rows.shape[0]
         sel = jnp.where(jnp.arange(h2) < n_hll, jnp.arange(h2), 0)

@@ -1,8 +1,8 @@
 """Double-buffered host->device staging pipeline.
 
-The round-5 host budget (`BENCH_r05.json`) put a 1M-key batch at
-~5 us host prep + ~4.3 ms H2D transfer + ~65 us device dispatch: the
-transfer dominates and used to serialize ahead of every dispatch.  The
+For a 1M-key batch the H2D transfer can outweigh host prep and the
+device dispatch (a pre-PR-1 host budget found it so; not re-measured on
+this round's chip), and it used to serialize ahead of every dispatch.  The
 pipeline runs *stage* (prep + `device_put`, the expensive host part)
 on a worker thread one batch ahead of *dispatch* (the jitted insert,
 cheap to issue, ordered), so batch N+1's transfer overlaps batch N's

@@ -135,7 +135,7 @@ class IngestPlanner:
         # Advisory seed costs (a previous process's table, a config hint):
         # NEVER a substitute for the first-use measurement. A stale prior
         # once let `sort` survive in the candidate set at 5x the measured
-        # scatter cost (BENCH_r05: 11.2 vs 58.0 M keys/s) — so plan() always
+        # scatter cost (a CPU-fallback bench before PR 1) — so plan() always
         # measures the row on first use and measured values override the
         # prior; a prior only fills paths the measurement cannot time.
         self._priors: Dict[tuple, Dict[str, float]] = {}

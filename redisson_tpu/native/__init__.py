@@ -22,6 +22,7 @@ import ctypes
 import os
 import subprocess
 import threading
+import warnings
 from typing import List, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -48,7 +49,11 @@ def _build() -> Optional[str]:
             check=True, capture_output=True, timeout=120,
         )
         return _SO_PATH if os.path.exists(_SO_PATH) else None
-    except (OSError, subprocess.SubprocessError):
+    except (OSError, subprocess.SubprocessError) as exc:
+        err = getattr(exc, "stderr", b"") or b""
+        warnings.warn(
+            f"native build failed, using the pure-Python fallback: {exc!r} "
+            f"{err[-2000:].decode(errors='replace')}", RuntimeWarning)
         return None
 
 

@@ -180,21 +180,39 @@ def bitop_xor(a, b):
     return a ^ b
 
 
+# Bit packing runs as one MXU contraction per 1024 cells against a fixed
+# 0/1 matrix, so both sides stay lane-dense. The obvious [n/8, 8] reshape
+# pads its minor dim of 8 to 128 lanes in TPU HBM: 16x the array, 17 GB
+# for 2^28 cells. bf16 x bf16 -> f32 is exact here: the inputs are bytes
+# (<= 255) or 0/1 cells, the weights powers of two, and every sum <= 255.
+
+
 def pack(bits: jnp.ndarray) -> jnp.ndarray:
     """Unpacked cells -> Redis byte layout (bit 0 is MSB of byte 0)."""
     n = bits.shape[0]
-    nbytes = (n + 7) // 8
-    padded = jnp.zeros((nbytes * 8,), jnp.uint8).at[:n].set(bits)
-    cells = padded.reshape(nbytes, 8).astype(jnp.uint32)
-    weights = (1 << (7 - jnp.arange(8, dtype=jnp.uint32)))[None, :]
-    return jnp.sum(cells * weights, axis=1).astype(jnp.uint8)
+    pad = (-n) % 1024
+    if pad:
+        bits = jnp.pad(bits, (0, pad))
+    c = jnp.arange(1024, dtype=jnp.int32)
+    weight = jnp.where(c[:, None] // 8 == jnp.arange(128)[None, :],
+                       (1 << (7 - c % 8))[:, None], 0).astype(jnp.bfloat16)
+    rows = jnp.minimum(bits, 1).astype(jnp.bfloat16).reshape(-1, 1024)
+    out = jnp.dot(rows, weight, preferred_element_type=jnp.float32)
+    return out.astype(jnp.uint8).reshape(-1)[: (n + 7) // 8]
 
 
 def unpack(data: jnp.ndarray, nbits: int) -> jnp.ndarray:
     """Redis bytes -> unpacked cells of length nbits."""
-    shifts = (7 - jnp.arange(8, dtype=jnp.uint32))[None, :]
-    cells = ((data.astype(jnp.uint32)[:, None] >> shifts) & 1).astype(jnp.uint8)
-    return cells.reshape(-1)[:nbits]
+    pad = (-data.shape[0]) % 128
+    if pad:
+        data = jnp.pad(data, (0, pad))
+    c = jnp.arange(1024, dtype=jnp.int32)
+    spread = (c[None, :] // 8 == jnp.arange(128)[:, None]).astype(
+        jnp.bfloat16)
+    rows = data.astype(jnp.bfloat16).reshape(-1, 128)
+    rep = jnp.dot(rows, spread, preferred_element_type=jnp.float32)
+    cells = (rep.astype(jnp.int32) >> (7 - c % 8)) & 1
+    return cells.astype(jnp.uint8).reshape(-1)[:nbits]
 
 
 cardinality_partials_jit = jax.jit(cardinality_partials)

@@ -63,8 +63,10 @@ def _merge_kernel(stack_ref, out_ref):
     out_ref[:] = jnp.maximum(out_ref[:], jnp.max(stack_ref[:], axis=0))
 
 
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("block",))
-def merge_stack(stack: jnp.ndarray, block: int = 64) -> jnp.ndarray:
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("block", "interpret"))
+def merge_stack(stack: jnp.ndarray, block: int = 64,
+                interpret=None) -> jnp.ndarray:
     """Elementwise max over the leading axis of an [S, m] int32 bank.
 
     Streams `block` sketches per grid step through VMEM (block * m * 4
@@ -90,7 +92,7 @@ def merge_stack(stack: jnp.ndarray, block: int = 64) -> jnp.ndarray:
             pl.BlockSpec((block, m), lambda i: (i, 0), memory_space=pltpu.VMEM)
         ],
         out_specs=pl.BlockSpec((m,), lambda i: (0,), memory_space=pltpu.VMEM),
-        interpret=_interpret(),
+        interpret=_interpret() if interpret is None else interpret,
     )(stack)
 
 
@@ -101,56 +103,73 @@ def merge_stack(stack: jnp.ndarray, block: int = 64) -> jnp.ndarray:
 
 def _delta_merge_kernel(old_ref, delta_ref, out_ref, changed_ref):
     j = pl.program_id(1)
+    old = old_ref[:].astype(jnp.int32)
+    merged = jnp.maximum(old, delta_ref[:].astype(jnp.int32))
+    out_ref[:] = merged.astype(out_ref.dtype)
+    accumulate_changed(changed_ref, j, merged, old)
+
+
+def accumulate_changed(changed_ref, j, merged, old):
+    """OR this cell block's per-row "merge changed something" flag into
+    the [rows, 1] accumulator; `j` is the cell-block grid index, and the
+    first block of a row tile resets it."""
+    diff = jnp.max((merged != old).astype(jnp.int32), axis=1, keepdims=True)
 
     @pl.when(j == 0)
     def _():
-        changed_ref[0, 0] = 0
+        changed_ref[:] = diff
 
-    merged = jnp.maximum(old_ref[:], delta_ref[:])
-    out_ref[:] = merged
-    changed_ref[0, 0] = changed_ref[0, 0] | jnp.any(
-        merged != old_ref[:]).astype(jnp.int32)
+    @pl.when(j > 0)
+    def _():
+        changed_ref[:] = changed_ref[:] | diff
 
 
-@functools.partial(jax.jit, donate_argnums=(0,), static_argnames=("block",))
-def delta_merge(old: jnp.ndarray, delta: jnp.ndarray, block: int = 1 << 15):
+def row_block(t: int) -> int:
+    """Rows per grid step for a [T, L] uint8 stack: the whole stack when
+    T <= 32, else 32 (uint8 tiles are (32, 128); callers pad T to a power
+    of two, so 32 divides it)."""
+    if t > 32 and t % 32:
+        raise ValueError(f"{t} rows: pad the stack to a multiple of 32")
+    return min(t, 32)
+
+
+@functools.partial(jax.jit, donate_argnums=(0,),
+                   static_argnames=("block", "interpret"))
+def delta_merge(old: jnp.ndarray, delta: jnp.ndarray, block: int = 1 << 13,
+                interpret=None):
     """The delta-ingest retire kernel: elementwise max of two [T, L] uint8
     stacks (one row per target; OR == max in the unpacked 0/1 cell domain,
     HLL registers fit uint8) plus a per-row changed flag.
 
-    Streams `block` cells of one row per grid step; rows iterate on the
-    outer grid axis with a per-row SMEM changed accumulator (the TPU grid
-    is sequential, inner axis fastest, so the `j == 0` reset is safe).
+    Streams a (rows, `block`) tile per grid step; the cell axis is the
+    inner grid axis, so each row tile's [rows, 1] changed accumulator
+    resets at `j == 0` and stays resident across its cell blocks.
     Purely elementwise — bandwidth-bound, no scatter issue port in sight.
     `old` donates AND aliases the merged output, so the merge lands in
     place: peak HBM is one [T, L] stack plus the delta, never two copies
     of the old state (the memstat ledger test pins this).
     Returns (merged [T, L], changed [T] bool)."""
     t, l = old.shape
+    rows = row_block(t)
     block = min(block, l)
     # Callers pad L to a power of two >= 1024, so block divides l.
-    grid = (t, l // block)
     merged, changed = pl.pallas_call(
         _delta_merge_kernel,
         out_shape=(
             jax.ShapeDtypeStruct((t, l), old.dtype),
             jax.ShapeDtypeStruct((t, 1), jnp.int32),
         ),
-        grid=grid,
+        grid=(t // rows, l // block),
         in_specs=[
-            pl.BlockSpec((1, block), lambda i, j: (i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, block), lambda i, j: (i, j),
-                         memory_space=pltpu.VMEM),
+            pl.BlockSpec((rows, block), lambda i, j: (i, j)),
+            pl.BlockSpec((rows, block), lambda i, j: (i, j)),
         ],
         out_specs=(
-            pl.BlockSpec((1, block), lambda i, j: (i, j),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, 1), lambda i, j: (i, 0),
-                         memory_space=pltpu.SMEM),
+            pl.BlockSpec((rows, block), lambda i, j: (i, j)),
+            pl.BlockSpec((rows, 1), lambda i, j: (i, 0)),
         ),
         input_output_aliases={0: 0},
-        interpret=_interpret(),
+        interpret=_interpret() if interpret is None else interpret,
     )(old, delta)
     return merged, changed[:, 0] != 0
 
@@ -163,13 +182,15 @@ def delta_merge(old: jnp.ndarray, delta: jnp.ndarray, block: int = 1 << 15):
 def _popcount_kernel(cells_ref, out_ref):
     # Per-block partial sums; each block holds <= `block` cells of value
     # 0/1, so an int32 partial cannot overflow for any practical block.
-    # Scalars land in SMEM — Mosaic rejects scalar stores to VMEM.
+    # The scalar is broadcast over one lane-dense (1, 128) row per step.
     # graftlint: allow-int-reduce(per-block partial over <= `block` 0/1 cells; the 64-bit combine is host-side)
-    out_ref[0, 0] = jnp.sum(cells_ref[:].astype(jnp.int32))
+    total = jnp.sum(cells_ref[:].astype(jnp.int32))
+    out_ref[:] = jnp.full(out_ref.shape, total, jnp.int32)
 
 
-@functools.partial(jax.jit, static_argnames=("block",))
-def popcount_partials(cells: jnp.ndarray, block: int = 1 << 18) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("block", "interpret"))
+def popcount_partials(cells: jnp.ndarray, block: int = 1 << 18,
+                      interpret=None) -> jnp.ndarray:
     """Per-block int32 set-bit partials over the unpacked cell layout.
 
     Each partial counts <= `block` 0/1 cells so int32 cannot overflow;
@@ -184,16 +205,15 @@ def popcount_partials(cells: jnp.ndarray, block: int = 1 << 18) -> jnp.ndarray:
     if pad:
         cells = jnp.concatenate([cells, jnp.zeros((pad,), cells.dtype)])
     grid_n = cells.shape[0] // block
-    return pl.pallas_call(
+    out = pl.pallas_call(
         _popcount_kernel,
-        out_shape=jax.ShapeDtypeStruct((grid_n, 1), jnp.int32),
+        out_shape=jax.ShapeDtypeStruct((grid_n, 1, 128), jnp.int32),
         grid=(grid_n,),
-        in_specs=[
-            pl.BlockSpec((block,), lambda i: (i,), memory_space=pltpu.VMEM)
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i: (i, 0), memory_space=pltpu.SMEM),
-        interpret=_interpret(),
+        in_specs=[pl.BlockSpec((block,), lambda i: (i,))],
+        out_specs=pl.BlockSpec((1, 1, 128), lambda i: (i, 0, 0)),
+        interpret=_interpret() if interpret is None else interpret,
     )(cells)
+    return out[:, :, 0]
 
 
 @functools.partial(jax.jit, static_argnames=("block",))
@@ -219,8 +239,9 @@ def _bitop_kernel(op, stack_ref, out_ref):
     out_ref[:] = acc
 
 
-@functools.partial(jax.jit, static_argnames=("op", "block"))
-def bitop_cells(stack: jnp.ndarray, op: str, block: int = 1 << 18) -> jnp.ndarray:
+@functools.partial(jax.jit, static_argnames=("op", "block", "interpret"))
+def bitop_cells(stack: jnp.ndarray, op: str, block: int = 1 << 18,
+                interpret=None) -> jnp.ndarray:
     """BITOP over K unpacked-cell operands stacked as [K, n] uint8.
 
     Grid over n so arbitrarily long bit arrays stream through VMEM; K is
@@ -241,6 +262,6 @@ def bitop_cells(stack: jnp.ndarray, op: str, block: int = 1 << 18) -> jnp.ndarra
             pl.BlockSpec((k, block), lambda i: (0, i), memory_space=pltpu.VMEM)
         ],
         out_specs=pl.BlockSpec((block,), lambda i: (i,), memory_space=pltpu.VMEM),
-        interpret=_interpret(),
+        interpret=_interpret() if interpret is None else interpret,
     )(stack)
     return out[:n]

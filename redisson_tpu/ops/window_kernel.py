@@ -30,11 +30,11 @@ Tape layout (one arena row per folded delta plane):
   (bank-resident HLL rows gathered as uint8 + store cell arrays),
   donated so the merge lands in place — no copy-in/copy-out per target.
 
-The kernel grid-iterates ``(entry, cell-block)``; each step switches on
-the prefetched ``op_code`` (scalar-prefetch table, SMEM) to decode its
-wire block — raw bytes for dense entries, an unpack-by-shift for packed
-entries — and max-merges into the old row (OR == max in the 0/1 cell
-domain; HLL registers are 0..64).  A per-row SMEM flag accumulates
+The kernel grid-iterates ``(entry-tile, cell-block)``; each step reads
+its entries' ``op_code`` and ``length`` from the table tile to decode
+their wire block — raw bytes for dense entries, an unpack-by-shift for
+packed entries — and max-merges into the old rows (OR == max in the 0/1
+cell domain; HLL registers are 0..64).  A per-row flag accumulates
 ``changed`` (the PFADD result bit).  Off-TPU the lax fallback computes
 the identical function (bit-for-bit — tests pin it), so CPU CI and the
 TPU kernel share one contract.
@@ -42,14 +42,13 @@ TPU kernel share one contract.
 
 from __future__ import annotations
 
-import functools
-
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
-from redisson_tpu.ops.pallas_kernels import use_pallas
+from redisson_tpu.ops import bitset
+from redisson_tpu.ops.pallas_kernels import (accumulate_changed, row_block,
+                                             use_pallas)
 
 # Tape op codes (table column 0). PAD rows carry length == 0 and merge as
 # identity (zero delta under max).
@@ -69,40 +68,33 @@ COL_SHARD = 4
 #: per cell); everything else is a packed bit plane the kernel unpacks.
 DENSE_OPS = (OP_HLL,)
 
-_DEFAULT_BLOCK = 1 << 15
+_DEFAULT_BLOCK = 1 << 11
 
 
 def _window_tape_kernel(tab_ref, old_ref, dense_ref, packed_ref,
-                        out_ref, changed_ref, *, interp: bool):
-    """One grid step: entry t, cell block j — decode this entry's wire
-    block per its op_code and max-merge into the old row."""
-    t = pl.program_id(0)
+                        out_ref, changed_ref):
+    """One grid step: a (rows, block) tile of entries x cells — decode
+    each entry's wire block per its op_code and max-merge into the old
+    rows."""
     j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _():
-        changed_ref[0, 0] = 0
-
-    op_code = tab_ref[t, 0]
-    length = tab_ref[t, 3]
-    block = out_ref.shape[1]
+    rows, block = out_ref.shape
+    tab = tab_ref[:]
+    op_code = tab[:, 0:1]
+    length = tab[:, 3:4]
     old = old_ref[:].astype(jnp.int32)
     dense = dense_ref[:].astype(jnp.int32)
     # Packed decode: cell c lives in wire byte c >> 3 at bit 7 - (c & 7)
     # (numpy packbits order — matches engine.bitset_pack/delta_unpack).
-    # Element-repeat semantics ([a,a,...x8,b,b,...]) per repeat_p's own
-    # reference lowering (jnp.repeat); jnp.repeat is used directly in
-    # interpret mode, where repeat_p has no TPU lowering.
-    rep8 = (jnp.repeat if interp else pltpu.repeat)(
-        packed_ref[:].astype(jnp.int32), 8, axis=1)
-    pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (1, block), 1)
+    # jnp.repeat is the element repeat ([a x8, b x8, ...]); pltpu.repeat
+    # is a tile and would decode the wrong bytes.
+    rep8 = jnp.repeat(packed_ref[:].astype(jnp.int32), 8, axis=1)
+    pos = j * block + jax.lax.broadcasted_iota(jnp.int32, (rows, block), 1)
     unpacked = (rep8 >> (7 - (pos & 7))) & 1
     delta = jnp.where(op_code == OP_HLL, dense, unpacked)
     delta = jnp.where(pos < length, delta, 0)
     merged = jnp.maximum(old, delta)
     out_ref[:] = merged.astype(out_ref.dtype)
-    changed_ref[0, 0] = changed_ref[0, 0] | jnp.any(
-        merged != old).astype(jnp.int32)
+    accumulate_changed(changed_ref, j, merged, old)
 
 
 def _normalize(old, wire, block):
@@ -122,32 +114,31 @@ def window_merge_pallas(old, wire, table, block: int = _DEFAULT_BLOCK,
     """The Pallas window megakernel. `old` [T, L] uint8 aliases the
     merged output (in-place against the donated arena); `wire` is passed
     twice so the same buffer is windowed at cell granularity (dense
-    entries) AND byte granularity (packed entries)."""
+    entries) AND byte granularity (packed entries). Lanes are a power of
+    two >= 1024, so a packed block of ``block // 8`` bytes stays a
+    multiple of 128."""
     if interpret is None:
         interpret = not use_pallas()
     wire, block = _normalize(old, wire, block)
     t2, lanes = old.shape
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(t2, lanes // block),
-        in_specs=[
-            pl.BlockSpec((1, block), lambda t, j, tab: (t, j)),
-            pl.BlockSpec((1, block), lambda t, j, tab: (t, j)),
-            pl.BlockSpec((1, block // 8), lambda t, j, tab: (t, j)),
-        ],
-        out_specs=(
-            pl.BlockSpec((1, block), lambda t, j, tab: (t, j)),
-            pl.BlockSpec((1, 1), lambda t, j, tab: (t, 0),
-                         memory_space=pltpu.SMEM),
-        ),
-    )
+    rows = row_block(t2)
     merged, changed = pl.pallas_call(
-        functools.partial(_window_tape_kernel, interp=bool(interpret)),
+        _window_tape_kernel,
         out_shape=(
             jax.ShapeDtypeStruct((t2, lanes), old.dtype),
             jax.ShapeDtypeStruct((t2, 1), jnp.int32),
         ),
-        grid_spec=grid_spec,
+        grid=(t2 // rows, lanes // block),
+        in_specs=[
+            pl.BlockSpec((rows, table.shape[1]), lambda t, j: (t, 0)),
+            pl.BlockSpec((rows, block), lambda t, j: (t, j)),
+            pl.BlockSpec((rows, block), lambda t, j: (t, j)),
+            pl.BlockSpec((rows, block // 8), lambda t, j: (t, j)),
+        ],
+        out_specs=(
+            pl.BlockSpec((rows, block), lambda t, j: (t, j)),
+            pl.BlockSpec((rows, 1), lambda t, j: (t, 0)),
+        ),
         input_output_aliases={1: 0},  # old -> merged, in place
         interpret=interpret,
     )(table, old, wire, wire)
@@ -162,9 +153,8 @@ def window_merge_lax(old, wire, table, block: int = _DEFAULT_BLOCK):
     op_code = table[:, 0:1]
     length = table[:, 3:4]
     pos = jnp.arange(lanes, dtype=jnp.int32)[None, :]
-    sh = jnp.asarray([7, 6, 5, 4, 3, 2, 1, 0], jnp.uint8)
-    unpacked = ((wire[:, : lanes // 8, None] >> sh[None, None, :]) & 1
-                ).reshape(t2, lanes)
+    unpacked = bitset.unpack(wire[:, : lanes // 8].reshape(-1),
+                             t2 * lanes).reshape(t2, lanes)
     delta = jnp.where(op_code == OP_HLL, wire, unpacked)
     delta = jnp.where(pos < length, delta, 0).astype(old.dtype)
     merged = jnp.maximum(old, delta)

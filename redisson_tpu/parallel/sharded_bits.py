@@ -38,10 +38,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:  # jax >= 0.5 re-exports shard_map at top level
-    from jax import shard_map
-except ImportError:  # jax 0.4.x
-    from jax.experimental.shard_map import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from redisson_tpu.ops import bloom

@@ -228,8 +228,8 @@ def test_planner_measured_row_overrides_dominated_prior():
         return {"scatter": 1.0, "sort": 5.0, "segment": 2.0}
 
     p = IngestPlanner(platform="cpu", measure=fake_measure)
-    # A stale prior claims `sort` is 10x cheaper than it really is — the
-    # historical BENCH_r05 regression.
+    # A stale prior claims `sort` is 10x cheaper than it really is — a
+    # regression an early bench once hit.
     p.set_prior("hll", 1 << 16, {"sort": 0.1})
     plan = p.plan("hll", 1 << 16)
     assert plan.path == "scatter"  # measured winner, never the stale prior

@@ -875,7 +875,7 @@ def test_j001_flags_x64_leak():
 
     from tools.graftlint.jaxpr_audit import _check_one
 
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         closed = jax.make_jaxpr(lambda x: x.astype(jnp.int64) + 1)(
             jnp.zeros((4,), jnp.int32))
     findings = _check_one("synthetic", closed, {})

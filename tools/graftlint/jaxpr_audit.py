@@ -37,7 +37,7 @@ HOST_SIDE = {
     "hll": {"make"},
     "u64": {"const", "to_python", "full"},
     "hashing": {"REDIS_HLL_SEED"},
-    "pallas_kernels": {"use_pallas"},
+    "pallas_kernels": {"use_pallas", "row_block", "accumulate_changed"},
 }
 
 _DTYPES_64 = {"int64", "uint64", "float64"}
@@ -283,11 +283,11 @@ def build_registry():
         # -- ingest kernels --------------------------------------------------
         ("ingest.hll_insert_segmented",
          lambda: (lambda r, b, k: ik.hll_insert_segmented(
-             r, b, k, tile=256, chunk=256, interpret=True),
+             r, b, k, interpret=True),
              (regs, bucket, rank)), {}),
         ("ingest.bits_insert_segmented",
          lambda: (lambda c, i: ik.bits_insert_segmented(
-             c, i, tile=1024, chunk=256, interpret=True),
+             c, i, interpret=True),
              (small, jnp.zeros((16,), jnp.int32))), {}),
         ("ingest.hll_insert_segmented_lax",
          lambda: (ik.hll_insert_segmented_lax, (regs, bucket, rank)), {}),
