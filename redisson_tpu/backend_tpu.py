@@ -42,6 +42,7 @@ from redisson_tpu.ingest.planner import IngestPlanner, default_planner
 from redisson_tpu.ops import bitset as bitset_ops, bloom as bloom_ops
 from redisson_tpu.ops import bloom_math
 from redisson_tpu.store import ObjectType, SketchStore, WrongTypeError
+from redisson_tpu.trace.stages import stage
 
 
 class Completer:
@@ -61,9 +62,19 @@ class Completer:
     The queue is bounded so a free-running producer cannot pile up unbounded
     in-flight device work/host buffers (the dispatcher blocks on put() once
     `maxsize` completions are pending — soft backpressure).
+
+    A completion is a callable that materializes its run's device results
+    (the blocking D2H) and returns the callable that resolves the futures,
+    or None once it has resolved them itself (a failed materialization).
+    The two halves run under the `completer.d2h` / `completer.resolve`
+    stage spans and add their perf_counter ns to `counters`
+    (`d2h_wait_ns`, `resolve_ns`, `runs_materialized`: completions run).
     """
 
-    def __init__(self, maxsize: int = 64):
+    def __init__(self, maxsize: int = 64, counters: Optional[dict] = None):
+        self.counters = {} if counters is None else counters
+        for key in ("d2h_wait_ns", "resolve_ns", "runs_materialized"):
+            self.counters.setdefault(key, 0)
         self._q: "queue.Queue[Callable]" = queue.Queue(maxsize=maxsize)
         self._thread = threading.Thread(
             target=self._loop, name="redisson-tpu-completer", daemon=True
@@ -74,16 +85,29 @@ class Completer:
         self._q.put(fn)
 
     def _loop(self):
+        counters = self.counters
         while True:
             fn = self._q.get()
             if fn is None:
                 return
+            t0 = time.perf_counter_ns()
+            t1 = None
             try:
-                fn()
+                with stage("completer.d2h"):
+                    resolve = fn()
+                t1 = time.perf_counter_ns()
+                if resolve is not None:
+                    with stage("completer.resolve"):
+                        resolve()
             except Exception:
                 # graftlint: allow-bare(completion closures own their futures and classify internally; an escape here means the futures are already resolved — re-raising would only kill the completer thread)
                 pass
             finally:
+                t2 = time.perf_counter_ns()
+                t1 = t2 if t1 is None else t1
+                counters["d2h_wait_ns"] += t1 - t0
+                counters["resolve_ns"] += t2 - t1
+                counters["runs_materialized"] += 1
                 self._q.task_done()
 
     def drain(self):
@@ -157,7 +181,8 @@ def _trace_cache(ops, hit: bool) -> None:
 
 
 def _complete_all(ops: List[Op], materialize: Callable[[], object]) -> Callable:
-    """Closure completing every op with materialize()'s value (or error)."""
+    """Completion (see Completer) giving every op materialize()'s value
+    (or error)."""
 
     def run():
         try:
@@ -170,16 +195,23 @@ def _complete_all(ops: List[Op], materialize: Callable[[], object]) -> Callable:
             # transient error here means the commit state is unknown —
             # classify maps it to StateUncertainFault and the executor's
             # fault listener routes the targets to the rebuild path.
-            exc = classify(exc, seam="d2h_complete")
+            _fail_ops(ops, classify(exc, seam="d2h_complete"))
+            return None
+
+        def resolve():
             for op in ops:
                 if not op.future.done():
-                    op.future.set_exception(exc)
-            return
-        for op in ops:
-            if not op.future.done():
-                op.future.set_result(value)
+                    op.future.set_result(value)
+
+        return resolve
 
     return run
+
+
+def _fail_ops(ops, exc) -> None:
+    for op in ops:
+        if not op.future.done():
+            op.future.set_exception(exc)
 
 
 def complete_changed_rows(completer: "Completer", ops: List[Op],
@@ -200,14 +232,16 @@ def complete_changed_rows(completer: "Completer", ops: List[Op],
                               target=ops[0].target if ops else "")
             host = None if flag is None else np.asarray(flag)
         except Exception as exc:  # noqa: BLE001
-            exc = classify(exc, seam="d2h_complete")
-            for op in ops:
+            _fail_ops(ops, classify(exc, seam="d2h_complete"))
+            return None
+
+        def resolve():
+            for op, r in zip(ops, rows):
                 if not op.future.done():
-                    op.future.set_exception(exc)
-            return
-        for op, r in zip(ops, rows):
-            if not op.future.done():
-                op.future.set_result(False if host is None else bool(host[r]))
+                    op.future.set_result(
+                        False if host is None else bool(host[r]))
+
+        return resolve
 
     completer.submit(run)
 
@@ -579,7 +613,6 @@ class TpuBackend:
         # Host staging (pad + device_put) of chunk N+1 overlaps device
         # dispatch of chunk N for multi-chunk runs (ingest/pipeline).
         self._pipeline = StagingPipeline(depth=2)
-        self.completer = Completer()
         # HLL bank: lazy [S, m] int32 device array + shared row bookkeeping.
         self.bank = None
         self._alloc = RowAllocator(max(1, bank_capacity), self._grow_bank)
@@ -610,6 +643,8 @@ class TpuBackend:
             "collective_merges": 0,    # PFMERGE/count runs via mesh collectives
             "multi_shard_windows": 0,  # tape windows spanning > 1 shard
         }
+        # adds d2h_wait_ns / resolve_ns / runs_materialized to the counters
+        self.completer = Completer(counters=self.counters)
         # Executor window handoff: last window sequence seen by run().
         self.last_window = None
         self._scratch_lock = threading.Lock()
@@ -1414,10 +1449,11 @@ class TpuBackend:
             except Exception as exc:  # noqa: BLE001
                 exc = classify(exc, seam="d2h_complete")
                 for _i, _p, spec in chunk_specs:
-                    for op in spec["ops"]:
-                        if not op.future.done():
-                            op.future.set_exception(exc)
-                return
+                    _fail_ops(spec["ops"], exc)
+                return None
+            return functools.partial(resolve, host_changed, host_old)
+
+        def resolve(host_changed, host_old):
             for i, p, spec in chunk_specs:
                 if spec.get("geo"):
                     # Remote planes carry no per-key result contract: the
@@ -1454,7 +1490,7 @@ class TpuBackend:
 
         def run_and_release():
             try:
-                run()
+                return run()
             finally:
                 with self._scratch_lock:
                     self.counters["delta_scratch_bytes"] -= scratch_inflight
@@ -1547,10 +1583,11 @@ class TpuBackend:
             except Exception as exc:  # noqa: BLE001
                 exc = classify(exc, seam="d2h_complete")
                 for _i, _p, spec in entries:
-                    for op in spec["ops"]:
-                        if not op.future.done():
-                            op.future.set_exception(exc)
-                return
+                    _fail_ops(spec["ops"], exc)
+                return None
+            return functools.partial(resolve, host_changed, host_old)
+
+        def resolve(host_changed, host_old):
             for i, p, spec in entries:
                 if p.kind == "hll_add":
                     # Per-target PFADD bool: did ANY register of this row
@@ -1578,7 +1615,7 @@ class TpuBackend:
 
         def run_and_release():
             try:
-                run()
+                return run()
             finally:
                 with self._scratch_lock:
                     self.counters["delta_scratch_bytes"] -= scratch_inflight
@@ -1808,14 +1845,15 @@ class TpuBackend:
                                   target=ops[0].target if ops else "")
                 host = np.asarray(flag)
             except Exception as exc:  # noqa: BLE001
-                exc = classify(exc, seam="d2h_complete")
-                for op in ops:
+                _fail_ops(ops, classify(exc, seam="d2h_complete"))
+                return None
+
+            def resolve():
+                for op, lane in zip(ops, lanes):
                     if not op.future.done():
-                        op.future.set_exception(exc)
-                return
-            for op, lane in zip(ops, lanes):
-                if not op.future.done():
-                    op.future.set_result(bool(host[lane]))
+                        op.future.set_result(bool(host[lane]))
+
+            return resolve
 
         self.completer.submit(run)
 
@@ -1862,20 +1900,21 @@ class TpuBackend:
             if chunks:
                 import jax
 
-                def stage(item):
+                def put_chunk(item):
                     row, chunk = item
                     prows, count = engine.pad_rows(chunk)
                     return (row, self._put(prows), np.int32(count))
 
                 def dispatch(_i, staged):
                     row, prows, count = staged
-                    self.bank, changed = engine.hll_bank_add_packed(
-                        self._ensure_bank(), prows, count, np.int32(row),
-                        self.seed, self.family, impl
-                    )
+                    with stage("backend.launch", keys=int(count)):
+                        self.bank, changed = engine.hll_bank_add_packed(
+                            self._ensure_bank(), prows, count, np.int32(row),
+                            self.seed, self.family, impl
+                        )
                     return changed
 
-                parts.extend(self._pipeline.run(chunks, stage, dispatch))
+                parts.extend(self._pipeline.run(chunks, put_chunk, dispatch))
             if small:
                 packed = np.concatenate(
                     [op.payload["packed"] for op in small])
@@ -1885,10 +1924,11 @@ class TpuBackend:
                 for s, e in engine.chunk_spans(packed.shape[0]):
                     pk_, count = engine.pad_rows(packed[s:e])
                     prow, _ = engine.pad_ints(rowv[s:e])
-                    self.bank, changed = engine.hll_bank_add_packed_rows(
-                        self._ensure_bank(), pk_, prow, np.int32(count),
-                        self.seed, self.family
-                    )
+                    with stage("backend.launch", keys=e - s):
+                        self.bank, changed = engine.hll_bank_add_packed_rows(
+                            self._ensure_bank(), pk_, prow, np.int32(count),
+                            self.seed, self.family
+                        )
                     parts.append(changed)
         elif "hi" in ops[0].payload:
             one = self._one_row(ops)
@@ -1899,17 +1939,18 @@ class TpuBackend:
             for s, e in engine.chunk_spans(hi.shape[0]):
                 phi, valid = engine.pad_ints(hi[s:e])
                 plo, _ = engine.pad_ints(lo[s:e])
-                if one is not None:  # scalar row: no 4 B/key row transfer
-                    self.bank, changed = engine.hll_bank_add_u64(
-                        self._ensure_bank(), phi, plo, valid, one, self.seed,
-                        self.family, impl
-                    )
-                else:
-                    prow, _ = engine.pad_ints(rowv[s:e])
-                    self.bank, changed = engine.hll_bank_add_u64_rows(
-                        self._ensure_bank(), phi, plo, prow, valid, self.seed,
-                        self.family
-                    )
+                with stage("backend.launch", keys=e - s):
+                    if one is not None:  # scalar row: no 4 B/key row transfer
+                        self.bank, changed = engine.hll_bank_add_u64(
+                            self._ensure_bank(), phi, plo, valid, one,
+                            self.seed, self.family, impl
+                        )
+                    else:
+                        prow, _ = engine.pad_ints(rowv[s:e])
+                        self.bank, changed = engine.hll_bank_add_u64_rows(
+                            self._ensure_bank(), phi, plo, prow, valid,
+                            self.seed, self.family
+                        )
                 parts.append(changed)
         else:
             one = self._one_row(ops)
@@ -1921,17 +1962,18 @@ class TpuBackend:
                     rowv[s:e] = self._rows[op.target]
             for s, e in engine.chunk_spans(data.shape[0]):
                 pdata, plengths, valid = engine.pad_bytes(data[s:e], lengths[s:e])
-                if one is not None:
-                    self.bank, changed = engine.hll_bank_add_bytes(
-                        self._ensure_bank(), pdata, plengths, valid, one,
-                        self.seed, self.family, impl
-                    )
-                else:
-                    prow, _ = engine.pad_ints(rowv[s:e])
-                    self.bank, changed = engine.hll_bank_add_bytes_rows(
-                        self._ensure_bank(), pdata, plengths, prow, valid,
-                        self.seed, self.family
-                    )
+                with stage("backend.launch", keys=e - s):
+                    if one is not None:
+                        self.bank, changed = engine.hll_bank_add_bytes(
+                            self._ensure_bank(), pdata, plengths, valid, one,
+                            self.seed, self.family, impl
+                        )
+                    else:
+                        prow, _ = engine.pad_ints(rowv[s:e])
+                        self.bank, changed = engine.hll_bank_add_bytes_rows(
+                            self._ensure_bank(), pdata, plengths, prow, valid,
+                            self.seed, self.family
+                        )
                 parts.append(changed)
         for op in ops:
             self._bump(op.target)
@@ -1978,7 +2020,8 @@ class TpuBackend:
             return
         _trace_cache(ops, hit=False)
         # async dispatch; D2H starts now, sync happens off-thread
-        est = _start_d2h(engine.hll_bank_count(self.bank, np.int32(row)))
+        with stage("backend.launch", ops=len(ops)):
+            est = _start_d2h(engine.hll_bank_count(self.bank, np.int32(row)))
 
         def materialize(est=est, epoch=epoch):
             # graftlint: allow-sync(completer thread: blocking materialization is this thread's job)
@@ -2042,14 +2085,15 @@ class TpuBackend:
             if rows is None:
                 op.future.set_result(0)
                 continue
-            if self.mesh is not None:
-                self.counters["collective_merges"] += 1
-                est = _start_d2h(engine.hll_bank_count_rows_collective(
-                    self.bank, engine.pad_rows_repeat(rows),
-                    mesh=self.mesh))
-            else:
-                est = _start_d2h(engine.hll_bank_count_rows(
-                    self.bank, engine.pad_rows_repeat(rows)))
+            with stage("backend.launch", rows=len(rows)):
+                if self.mesh is not None:
+                    self.counters["collective_merges"] += 1
+                    est = _start_d2h(engine.hll_bank_count_rows_collective(
+                        self.bank, engine.pad_rows_repeat(rows),
+                        mesh=self.mesh))
+                else:
+                    est = _start_d2h(engine.hll_bank_count_rows(
+                        self.bank, engine.pad_rows_repeat(rows)))
             self.completer.submit(
                 # graftlint: allow-sync(completer thread: materializing the staged estimate is this thread's job)
                 _complete_all([op], lambda est=est: int(round(float(est))))
@@ -2166,7 +2210,8 @@ class TpuBackend:
         for s, e in engine.chunk_spans(idx.shape[0]):
             # uint32, not int32: positions past 2^31 wrap int32 negative
             pidx, valid = engine.pad_ints(idx[s:e].astype(np.uint32))
-            new, old = kernel(obj.state, pidx, valid)
+            with stage("backend.launch", keys=e - s):
+                new, old = kernel(obj.state, pidx, valid)
             self.store.swap(target, new)
             outs.append(old)  # device handles; materialized off-thread
             spans.append(e - s)
@@ -2194,11 +2239,11 @@ class TpuBackend:
                 if post is not None:
                     flat = post(flat)
             except Exception as exc:  # noqa: BLE001
-                exc = classify(exc, seam="d2h_complete")
-                for op in ops:
-                    if not op.future.done():
-                        op.future.set_exception(exc)
-                return
+                _fail_ops(ops, classify(exc, seam="d2h_complete"))
+                return None
+            return functools.partial(resolve, flat)
+
+        def resolve(flat):
             pos = 0
             for op in ops:
                 p = op.payload
@@ -2264,7 +2309,8 @@ class TpuBackend:
         _trace_cache(ops, hit=False)
         # Partials go D2H async; the 64-bit-exact combine happens at
         # completion (an int32 total wraps negative past 2^31 set bits).
-        v = _start_d2h(engine.bitset_cardinality_partials(obj.state))
+        with stage("backend.launch", ops=len(ops)):
+            v = _start_d2h(engine.bitset_cardinality_partials(obj.state))
 
         def materialize(v=v, epoch=epoch):
             out = bitset_ops.combine_partials(v)
@@ -2368,7 +2414,8 @@ class TpuBackend:
             if len(padded) == 1:
                 acc = padded[0]
             else:
-                acc = engine.bitset_bitop(jnp.stack(padded), kind)
+                with stage("backend.launch", sources=len(padded)):
+                    acc = engine.bitset_bitop(jnp.stack(padded), kind)
             obj.meta["nbits"] = width
             # BITOP dest width = max of the operands' written extents
             # (redis: STRLEN of the result equals the widest source). A

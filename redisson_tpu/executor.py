@@ -62,6 +62,7 @@ from redisson_tpu.concurrency import make_condition, make_lock
 from redisson_tpu.fault import inject as fault_inject
 from redisson_tpu.fault.taxonomy import StateUncertainFault, classify
 from redisson_tpu.serve.errors import DeadlineExceeded
+from redisson_tpu.trace.stages import stage
 
 # graftlint Tier C guarded-by audit (tools/graftlint/concurrency.py):
 # which shared attribute is protected by which lock. `token.*` entries use
@@ -82,6 +83,10 @@ GUARDED_BY = {
     "CommandExecutor._trace": "_lock:writes",
     "CommandExecutor._window_seq":
         "thread:dispatcher-confined — bumped and read only in _dispatch_one",
+    "CommandExecutor._stage_ns":
+        "racy:monotonic counter written only in _dispatch, torn read ok",
+    "CommandExecutor._runs_staged":
+        "racy:monotonic counter written only in _dispatch, torn read ok",
     "token.pending": "lock",
     "token.op_failed": "lock:writes",
     "token.fault_exc": "lock:writes",
@@ -274,6 +279,9 @@ class CommandExecutor:
         self._staging_bytes = 0  # in-flight payload bytes (memstat meter)
         self._runs_completed = 0
         self._runs_overlapped = 0
+        # Host staging time of every run (token.stage_s), always on.
+        self._stage_ns = 0
+        self._runs_staged = 0
         self._lock = make_lock("executor.CommandExecutor._lock")
         self._cv = make_condition("executor.CommandExecutor._lock",
                                   self._lock)
@@ -445,7 +453,8 @@ class CommandExecutor:
                         self._cv.wait()
                     kind, target, run = self._collect_run_locked(picked)
                     token = self._admit_locked(kind, target, run)
-                self._dispatch(token, run)
+                with stage("executor.dispatch", ops=len(run)):
+                    self._dispatch(token, run)
         finally:
             # The dispatcher is the only thread that resolves queued ops; if
             # it exits for ANY reason (clean shutdown drain or an unexpected
@@ -638,6 +647,8 @@ class CommandExecutor:
                 self._staging_bytes += staged
         t0 = token.t0 = self._clock()
         token.queue_delay_s = t0 - min(op.enqueued_at for op in live)
+        if m:
+            m.record_queue_waits(live, t0)
         # graftlint: allow-guarded(pre-publish init: done-callbacks that contend on token.lock are not armed yet)
         token.pending = len(live)
         # Sampled spans riding this run (usually empty). The run span links
@@ -702,10 +713,13 @@ class CommandExecutor:
             fault_inject.fire("kernel_launch", kind=kind, target=target)
             if self._window_handoff:
                 self._window_seq += 1
-                self._backend.run(kind, target, live,
-                                  window=self._window_seq)
+                with stage("backend.run", window=self._window_seq,
+                           ops=len(live)):
+                    self._backend.run(kind, target, live,
+                                      window=self._window_seq)
             else:
-                self._backend.run(kind, target, live)
+                with stage("backend.run", ops=len(live)):
+                    self._backend.run(kind, target, live)
             t_staged = self._clock()
             token.stage_s = t_staged - t0
             if spans:
@@ -747,6 +761,8 @@ class CommandExecutor:
             for op in live:
                 if not op.future.done():
                     op.future.set_exception(exc)
+        self._stage_ns += int(token.stage_s * 1e9)
+        self._runs_staged += 1
         if parked:
             # The waiter is parked (or was served/failed inline); drop the
             # gates and the window slot now — the fulfilling op must be able
@@ -868,10 +884,13 @@ class CommandExecutor:
     def pipeline_stats(self) -> Dict[str, Any]:
         """Live pipeline counters (suite --pipeline-smoke + serve snapshot):
         overlap_ratio is the fraction of completed runs that were dispatched
-        while at least one other run was still in flight."""
+        while at least one other run was still in flight; stage_ns sums
+        the host staging time of runs_staged runs; with metrics attached,
+        `{read,write}_wait_le_us.<bound>` count the ops whose enqueue to
+        dispatch wait fell in the histogram bucket ending at <bound> us."""
         with self._lock:
             done = self._runs_completed
-            return {
+            out = {
                 "window": self._window,
                 "eager_release": self._eager_release,
                 "inflight": len(self._inflight),
@@ -880,7 +899,12 @@ class CommandExecutor:
                 "overlap_ratio": (self._runs_overlapped / done) if done else 0.0,
                 "staging_bytes": self._staging_bytes,
                 "shard_tag": self.shard_tag,
+                "stage_ns": self._stage_ns,
+                "runs_staged": self._runs_staged,
             }
+        if self._metrics:
+            out.update(self._metrics.queue_wait_buckets())
+        return out
 
     def staging_bytes(self) -> int:
         """In-flight payload bytes (memstat 'staging' meter)."""

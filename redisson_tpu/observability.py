@@ -1,5 +1,5 @@
-"""Observability: metrics registry, executor instrumentation, profiler
-hooks, and the nodes/health API.
+"""Observability: metrics registry, executor instrumentation, and the
+nodes/health API.
 
 The reference has NO metrics or tracing (SURVEY.md §5 — slf4j logs only);
 its only health surface is `NodesGroup.pingAll`/`Node.ping`
@@ -11,9 +11,8 @@ metrics and an XLA profiler hook are required new design, not a port:
     prometheus-text renderer and a dict snapshot;
   * executor instrumentation — per-kind op counts, coalesced batch-size and
     dispatch-latency histograms, live queue depth (wired by the executor
-    when a registry is attached);
-  * profile() — context manager around jax.profiler.trace for capturing
-    device traces of a workload section;
+    when a registry is attached), and every op's enqueue -> dispatch wait
+    split into reads and writes;
   * NodesGroup — ping of every compute node (device micro-kernel
     round-trip) and the redis durability tier (RESP PING), plus
     connect/disconnect listener fan-out (the ConnectionEventsHub role).
@@ -22,11 +21,15 @@ metrics and an XLA profiler hook are required new design, not a port:
 from __future__ import annotations
 
 import bisect
-import contextlib
 import threading
-import time
 from dataclasses import dataclass, field
-from typing import Any, Callable, Dict, List, Optional
+from typing import Any, Callable, Dict, List, Optional, Sequence
+
+from redisson_tpu.commands import OP_TABLE
+from redisson_tpu.trace.hist import LatencyHistogram
+
+#: op kinds the command table marks as mutating (queue waits split on it)
+_WRITE_KINDS = frozenset(k for k, d in OP_TABLE.items() if d.write)
 
 # ---------------------------------------------------------------------------
 # Metrics
@@ -168,33 +171,6 @@ class MetricsRegistry:
 
 
 # ---------------------------------------------------------------------------
-# Profiler hook
-# ---------------------------------------------------------------------------
-
-
-@contextlib.contextmanager
-def profile(logdir: str):
-    """Capture an XLA device trace of the enclosed block (view with
-    tensorboard / xprof). No-op if the profiler is unavailable."""
-    import jax
-
-    started = False
-    try:
-        jax.profiler.start_trace(logdir)
-        started = True
-    except Exception:
-        pass
-    try:
-        yield
-    finally:
-        if started:
-            try:
-                jax.profiler.stop_trace()
-            except Exception:
-                pass
-
-
-# ---------------------------------------------------------------------------
 # Nodes / health (NodesGroup + ConnectionEventsHub analogue)
 # ---------------------------------------------------------------------------
 
@@ -296,6 +272,27 @@ class ExecutorMetrics:
 
     def __init__(self, registry: Optional[MetricsRegistry] = None):
         self.registry = registry or MetricsRegistry()
+        # Every dispatched op's enqueue -> dispatch wait, unsampled, at the
+        # histogram's 3 % resolution (written by the dispatcher only).
+        self.queue_wait = {"read": LatencyHistogram(),
+                           "write": LatencyHistogram()}
+
+    def record_queue_waits(self, ops: Sequence[Any], t_dispatch: float) -> None:
+        """One run dispatched at `t_dispatch` (executor clock): each op's
+        wait since its enqueue, by the op table's write flag."""
+        read, write = self.queue_wait["read"], self.queue_wait["write"]
+        for op in ops:
+            h = write if op.kind in _WRITE_KINDS else read
+            h.record(t_dispatch - op.enqueued_at)
+
+    def queue_wait_buckets(self) -> Dict[str, int]:
+        """The wait histograms flattened for counter snapshots:
+        `<read|write>_wait_le_us.<upper bound in us>` -> ops, for every
+        non-empty bucket (counts only grow, so deltas of two snapshots are
+        the waits between them)."""
+        return {f"{side}_wait_le_us.{upper}": count
+                for side, h in self.queue_wait.items()
+                for upper, count in h.buckets()}
 
     def record_batch(self, kind: str, nops: int, nkeys: int,
                      latency_s: float, queue_delay_s: Optional[float] = None,

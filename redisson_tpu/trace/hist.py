@@ -138,6 +138,11 @@ class LatencyHistogram:
             result.append((b, cum))
         return result
 
+    def buckets(self) -> List[Tuple[int, int]]:
+        """[(inclusive upper bound in ticks, count)] of non-empty buckets."""
+        return [(bucket_upper_ticks(i), c)
+                for i, c in enumerate(list(self._counts)) if c]
+
     def snapshot(self) -> Dict[str, object]:
         out: Dict[str, object] = {
             "count": self.count,

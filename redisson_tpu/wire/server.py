@@ -45,6 +45,7 @@ from redisson_tpu.ops.crc16 import key_slot
 from redisson_tpu.serve.errors import (CircuitOpenError, DeadlineExceeded,
                                        RejectedError)
 from redisson_tpu.serve.windows import ConnectionWindow
+from redisson_tpu.trace.stages import stage
 from redisson_tpu.wire import commands as wire_commands
 from redisson_tpu.wire import proto
 from redisson_tpu.wire.commands import EngineCall, WireCommandError
@@ -78,6 +79,7 @@ GUARDED_BY = {
     "WireServer.ops_flushed": "racy:monotonic counter, torn read ok",
     "WireServer.last_window_depth": "racy:gauge sample, torn read ok",
     "WireServer.dropped_conns": "racy:monotonic counter, torn read ok",
+    "WireServer.loop_busy_ns": "racy:monotonic counter, torn read ok",
     "_WireConn.closing": "thread:event-loop confined",
     "_WireConn.proto_ver": "thread:event-loop confined",
     "_WireConn.authed": "thread:event-loop confined",
@@ -215,6 +217,9 @@ class WireServer:
         self.ops_flushed = 0
         self.last_window_depth = 0
         self.dropped_conns = 0
+        # perf_counter ns the loop thread spent in wire.read, wire.flush
+        # and wire.settle (always on; the stage spans cover the same code)
+        self.loop_busy_ns = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -313,19 +318,8 @@ class WireServer:
                     self.dropped_conns += 1
                     conn.kill()
                     break
-                # Network-queue attribution: admitted_at is the socket-read
-                # stamp, so SLOWLOG's admission stage covers wire queueing.
-                admitted_at = time.monotonic()
-                try:
-                    frames = parser.feed(data)
-                except proto.RespError as exc:
-                    conn.window.reserve_immediate(
-                        proto.err(f"Protocol error: {exc}"))
-                    self.bytes_out += conn.pump()
+                if not self._on_read(conn, parser, data):
                     break
-                for frame in frames:
-                    self._dispatch_frame(conn, frame, admitted_at)
-                self.bytes_out += conn.pump()
                 try:
                     await writer.drain()
                 except (ConnectionError, OSError):
@@ -343,6 +337,27 @@ class WireServer:
                 writer.close()
             except Exception:
                 pass
+
+    def _on_read(self, conn: _WireConn, parser, data: bytes) -> bool:
+        """Parse and dispatch the frames of one socket read, then pump the
+        replies already complete; False after a protocol error."""
+        t0 = time.perf_counter_ns()
+        with stage("wire.read", bytes=len(data)):
+            # Network-queue attribution: admitted_at is the socket-read
+            # stamp, so SLOWLOG's admission stage covers wire queueing.
+            admitted_at = time.monotonic()
+            try:
+                frames = parser.feed(data)
+            except proto.RespError as exc:
+                conn.window.reserve_immediate(
+                    proto.err(f"Protocol error: {exc}"))
+                frames = None
+            else:
+                for frame in frames:
+                    self._dispatch_frame(conn, frame, admitted_at)
+            self.bytes_out += conn.pump()
+        self.loop_busy_ns += time.perf_counter_ns() - t0
+        return frames is not None
 
     # -- per-frame dispatch --------------------------------------------------
 
@@ -413,6 +428,17 @@ class WireServer:
         self.windows_flushed += 1
         self.ops_flushed += len(staged)
         self.last_window_depth = len(staged)
+        busy0, t0 = self.loop_busy_ns, time.perf_counter_ns()
+        try:
+            with stage("wire.flush", window=self.windows_flushed,
+                       ops=len(staged)):
+                self._flush_window(staged, ats, targets)
+        finally:
+            # a failed window settles its replies inside this span: count
+            # that time once, here
+            self.loop_busy_ns = busy0 + time.perf_counter_ns() - t0
+
+    def _flush_window(self, staged, ats, targets) -> None:
         dispatch = self._get_dispatch()
         try:
             # execute_many runs synchronously on this thread, so the
@@ -474,16 +500,19 @@ class WireServer:
         if state.remaining > 0:
             return
         conn = state.conn
-        if state.exc is not None:
-            data = self._render_error(state)
-        else:
-            try:
-                data = state.call.render(state.results, conn.proto_ver)
-            except Exception as exc:
-                data = proto.err(str(exc) or repr(exc))
-        conn.window.complete(state.slot, data)
-        if not conn.closing:
-            self.bytes_out += conn.pump()
+        t0 = time.perf_counter_ns()
+        with stage("wire.settle"):
+            if state.exc is not None:
+                data = self._render_error(state)
+            else:
+                try:
+                    data = state.call.render(state.results, conn.proto_ver)
+                except Exception as exc:
+                    data = proto.err(str(exc) or repr(exc))
+            conn.window.complete(state.slot, data)
+            if not conn.closing:
+                self.bytes_out += conn.pump()
+        self.loop_busy_ns += time.perf_counter_ns() - t0
 
     def _render_error(self, state: _CallState) -> bytes:
         exc = state.exc
@@ -779,6 +808,7 @@ class WireServer:
             "avg_window_depth": (self.ops_flushed
                                  / max(1, self.windows_flushed)),
             "dropped_conns": self.dropped_conns,
+            "loop_busy_ns": self.loop_busy_ns,
             # zeros unless the loop-stall witness is watching this loop
             **loop_gauges(self._loop),
         }

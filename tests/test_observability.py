@@ -1,11 +1,10 @@
 """Observability tests: metrics registry, executor instrumentation,
-profiler hook, nodes/health API."""
+nodes/health API."""
 
 import pytest
 
 from redisson_tpu.client import RedissonTPU
-from redisson_tpu.observability import (Histogram, MetricsRegistry, NodesGroup,
-                                        profile)
+from redisson_tpu.observability import Histogram, MetricsRegistry, NodesGroup
 
 
 @pytest.fixture()
@@ -144,9 +143,3 @@ def test_connection_listener_fanout(client):
     ng.fire("connect", "node-1")
     ng.fire("disconnect", "node-1")
     assert events == [("connect", "node-1"), ("disconnect", "node-1")]
-
-
-def test_profile_context_manager(tmp_path, client):
-    # Must not raise whether or not the platform supports tracing.
-    with profile(str(tmp_path / "trace")):
-        client.get_hyper_log_log("obs:p").add_all([b"a", b"b"])
