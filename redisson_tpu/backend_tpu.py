@@ -642,6 +642,8 @@ class TpuBackend:
             "geo_classic": 0,     # remote planes absorbed via the fallback
             "collective_merges": 0,    # PFMERGE/count runs via mesh collectives
             "multi_shard_windows": 0,  # tape windows spanning > 1 shard
+            "bank_add_launches": 0,    # multi-target HLL bank inserts
+            "bank_add_rows": 0,        # distinct bank rows those touched
         }
         # adds d2h_wait_ns / resolve_ns / runs_materialized to the counters
         self.completer = Completer(counters=self.counters)
@@ -1739,7 +1741,7 @@ class TpuBackend:
     def _op_hll_add(self, target: str, ops: List[Op]) -> None:
         # A coalesced run may span formats AND targets (GLOBAL_COALESCE);
         # group by format — PFADD is a commutative max-fold, so regrouping
-        # is safe; per-key row vectors carry the target routing.
+        # is safe; per-key slot vectors carry the target routing.
         #
         # Targets are validated (and rows allocated, growing the bank) up
         # front: a WRONGTYPE name fails ONLY its own ops, never poisons the
@@ -1857,8 +1859,24 @@ class TpuBackend:
 
         self.completer.submit(run)
 
-    def _row_vec(self, op: Op, n: int) -> np.ndarray:
-        return np.full((n,), self._rows[op.target], np.int32)
+    def _run_slots(self, ops: List[Op], nkeys) -> tuple:
+        """Routing of a multi-target insert run (engine._bank_add): each
+        key's slot — its op's target's index among the run's distinct
+        bank rows — and those rows, padded to a power of two of at least
+        MIN_BLOCK_ROWS and at most the bank's row count. Returns
+        (slots[N], urows[R], distinct row count)."""
+        slot_of: dict = {}
+        slots = [slot_of.setdefault(self._rows[op.target], len(slot_of))
+                 for op in ops]
+        urows = engine.pad_rows_repeat(
+            np.fromiter(slot_of, np.int32, len(slot_of)),
+            floor=engine.MIN_BLOCK_ROWS, cap=self._ensure_bank().shape[0])
+        return (np.repeat(np.asarray(slots, np.int32), nkeys), urows,
+                len(slot_of))
+
+    def _bank_add_launched(self, rows: int) -> None:
+        self.counters["bank_add_launches"] += 1
+        self.counters["bank_add_rows"] += rows
 
     def _one_row(self, ops: List[Op]):
         """np.int32 row when every op targets one sketch (the scalar-row
@@ -1873,16 +1891,17 @@ class TpuBackend:
         # resolve on the completer thread so the dispatcher is never
         # device-bound. Single-target runs use the scalar-row kernels (no
         # per-key row vector ships over the link); multi-target coalesced
-        # runs carry a row vector — one SPMD-style call for many sketches.
+        # runs carry a per-key slot vector into the run's distinct rows —
+        # one call for many sketches that touches only those rows.
         # `impl` is the planner's device insert choice for this run; it
-        # reaches the scalar-row kernels (the multi-target row-vector
-        # kernels stay on the flat scatter, see engine._bank_add_row).
+        # reaches the scalar-row kernels (the multi-target kernels stay on
+        # the XLA scatter, see engine._bank_add_row).
         parts = []
         if "packed" in ops[0].payload:
             # Concatenating copies 8 B/key on the dispatcher, so a LARGE
             # op's buffer ships to the device as-is through the scalar-row
             # kernel (zero host copies end-to-end, no 4 B/key row vector);
-            # only small ops gather into shared buckets with a row vector.
+            # only small ops gather into shared buckets with a slot vector.
             # Large multi-chunk runs go through the staging pipeline: a
             # worker thread pads + device_puts chunk N+1 while this thread
             # dispatches chunk N (the bank carry keeps dispatch serial).
@@ -1918,62 +1937,65 @@ class TpuBackend:
             if small:
                 packed = np.concatenate(
                     [op.payload["packed"] for op in small])
-                rowv = np.concatenate(
-                    [self._row_vec(op, op.payload["packed"].shape[0])
-                     for op in small])
+                slotv, urows, nrows = self._run_slots(
+                    small, [op.payload["packed"].shape[0] for op in small])
                 for s, e in engine.chunk_spans(packed.shape[0]):
                     pk_, count = engine.pad_rows(packed[s:e])
-                    prow, _ = engine.pad_ints(rowv[s:e])
-                    with stage("backend.launch", keys=e - s):
+                    pslot, _ = engine.pad_ints(slotv[s:e])
+                    with stage("backend.launch", keys=e - s, rows=nrows):
                         self.bank, changed = engine.hll_bank_add_packed_rows(
-                            self._ensure_bank(), pk_, prow, np.int32(count),
-                            self.seed, self.family
+                            self._ensure_bank(), pk_, pslot, urows,
+                            np.int32(count), self.seed, self.family
                         )
+                    self._bank_add_launched(nrows)
                     parts.append(changed)
         elif "hi" in ops[0].payload:
             one = self._one_row(ops)
             hi = np.concatenate([op.payload["hi"] for op in ops])
             lo = np.concatenate([op.payload["lo"] for op in ops])
-            rowv = None if one is not None else np.concatenate(
-                [self._row_vec(op, op.payload["hi"].shape[0]) for op in ops])
+            if one is None:
+                slotv, urows, nrows = self._run_slots(
+                    ops, [op.payload["hi"].shape[0] for op in ops])
             for s, e in engine.chunk_spans(hi.shape[0]):
                 phi, valid = engine.pad_ints(hi[s:e])
                 plo, _ = engine.pad_ints(lo[s:e])
-                with stage("backend.launch", keys=e - s):
-                    if one is not None:  # scalar row: no 4 B/key row transfer
+                if one is not None:  # scalar row: no 4 B/key row transfer
+                    with stage("backend.launch", keys=e - s):
                         self.bank, changed = engine.hll_bank_add_u64(
                             self._ensure_bank(), phi, plo, valid, one,
                             self.seed, self.family, impl
                         )
-                    else:
-                        prow, _ = engine.pad_ints(rowv[s:e])
+                else:
+                    pslot, _ = engine.pad_ints(slotv[s:e])
+                    with stage("backend.launch", keys=e - s, rows=nrows):
                         self.bank, changed = engine.hll_bank_add_u64_rows(
-                            self._ensure_bank(), phi, plo, prow, valid,
-                            self.seed, self.family
+                            self._ensure_bank(), phi, plo, pslot, urows,
+                            valid, self.seed, self.family
                         )
+                    self._bank_add_launched(nrows)
                 parts.append(changed)
         else:
             one = self._one_row(ops)
             data, lengths, spans = self._coalesce_bytes(ops)
-            rowv = None
             if one is None:
-                rowv = np.zeros((data.shape[0],), np.int32)
-                for op, (s, e) in zip(ops, spans):
-                    rowv[s:e] = self._rows[op.target]
+                slotv, urows, nrows = self._run_slots(
+                    ops, [e - s for s, e in spans])
             for s, e in engine.chunk_spans(data.shape[0]):
                 pdata, plengths, valid = engine.pad_bytes(data[s:e], lengths[s:e])
-                with stage("backend.launch", keys=e - s):
-                    if one is not None:
+                if one is not None:
+                    with stage("backend.launch", keys=e - s):
                         self.bank, changed = engine.hll_bank_add_bytes(
                             self._ensure_bank(), pdata, plengths, valid, one,
                             self.seed, self.family, impl
                         )
-                    else:
-                        prow, _ = engine.pad_ints(rowv[s:e])
+                else:
+                    pslot, _ = engine.pad_ints(slotv[s:e])
+                    with stage("backend.launch", keys=e - s, rows=nrows):
                         self.bank, changed = engine.hll_bank_add_bytes_rows(
-                            self._ensure_bank(), pdata, plengths, prow, valid,
-                            self.seed, self.family
+                            self._ensure_bank(), pdata, plengths, pslot,
+                            urows, valid, self.seed, self.family
                         )
+                    self._bank_add_launched(nrows)
                 parts.append(changed)
         for op in ops:
             self._bump(op.target)

@@ -227,10 +227,10 @@ def hll_count_merged(stack):
 # mergeWith/countWith API, RedissonHyperLogLog.java:40-97) compiles to one
 # gather + row-max kernel over an index vector instead of a python-side
 # jnp.stack of S separate handles (r3: 183 ms for 256 sketches — almost all
-# jit argument-flattening overhead, not compute). Inserts scatter-max at flat
-# index row*m + bucket; the `rows` variants carry a per-key target row so a
-# single device call can serve keys for many different sketches (the
-# pipelined-PFADD-across-256-sketches shape).
+# jit argument-flattening overhead, not compute). Inserts scatter-max into
+# the rows they touch; the `rows` variants carry the run's distinct rows and
+# a per-key slot into them, so a single device call can serve keys for many
+# different sketches (the pipelined-PFADD-across-256-sketches shape).
 # ---------------------------------------------------------------------------
 
 
@@ -240,34 +240,41 @@ def hll_bank_make(capacity: int, m: int = None) -> jnp.ndarray:
     return jnp.zeros((capacity, m), jnp.int32)
 
 
-def _bank_add(bank, h1, rows, valid):
+def _bank_add(bank, h1, slots, urows, valid):
     """Multi-target insert. Returns (new_bank, changed_rows[S]) — changed
     is PER ROW, so a cross-sketch coalesced run can give every op its own
     PFADD bool (Redis semantics: did THIS key's sketch change) instead of
     leaking one run-wide flag across targets.
 
-    changed comes from a whole-bank row compare, NOT a per-key gather of
-    the old registers: XLA lowers random 1-D gathers on TPU near-serially
-    (the gather formulation measured 2.6x slower end to end)."""
+    `urows[R]` are the run's distinct bank rows (padded by repeating the
+    first, see pad_rows_repeat) and `slots[N]` each key's index into them.
+    The R touched rows are gathered into an [R, m] block, the keys
+    scatter-max into the block, and changed is a compare of the block
+    before and after — so the work scales with the rows the run touches,
+    not with the bank. The block goes back with a MAX scatter: padding
+    repeats carry an unmodified copy of their row, which max leaves
+    harmless. Nothing reads `bank` after that write-back, so XLA updates
+    the donated buffer in place (no bank-sized copy, relayout or
+    compare)."""
     s, m = bank.shape
     p = m.bit_length() - 1
     bucket, rank = hll.bucket_rank(h1, p)
     rank = jnp.where(valid, rank, 0)  # padded lanes: rank 0 never raises
-    idx = jnp.where(valid, rows, 0) * m + bucket
-    new = bank.reshape(-1).at[idx].max(rank).reshape(s, m)
-    changed_rows = jnp.any(new != bank, axis=1)
+    slots = jnp.where(valid, slots, 0)
+    block = bank[urows]
+    new_block = block.at[slots, bucket].max(rank)
+    changed_slot = jnp.any(new_block != block, axis=1)
+    new = bank.at[urows].max(new_block)
+    changed_rows = jnp.zeros((s,), bool).at[urows].max(changed_slot)
     return new, changed_rows
 
 
 def _bank_add_row(bank, h1, row, valid, impl: str = "scatter"):
     """Single-target insert (scalar `row`): slice the row out, insert into
     the 16K row (the flat single-sketch kernel's cost profile), write
-    it back with a dynamic update — ~2.7x the throughput of routing a
-    scalar row through the multi-target path (91M vs 34M inserts/s/chip
-    measured at 1M-key batches, S=256). `impl` picks the row insert
-    (see _insert_impl); the multi-target _bank_add stays on the flat
-    scatter — its row*m+bucket codes would overflow the segmented
-    kernel's int32 code space for large banks."""
+    it back with a dynamic update. `impl` picks the row insert (see
+    _insert_impl); the multi-target _bank_add stays on the XLA scatter
+    into its [R, m] block of touched rows."""
     s, m = bank.shape
     p = m.bit_length() - 1
     bucket, rank = hll.bucket_rank(h1, p)
@@ -294,20 +301,21 @@ def hll_bank_add_packed(bank, packed, count, row, seed: int = 0,
 
 @functools.partial(
     jax.jit, donate_argnums=(0,), static_argnames=("seed", "family"))
-def hll_bank_add_packed_rows(bank, packed, rows, count, seed: int = 0,
-                             family: str = "m3"):
-    """Multi-target PFADD: per-key target row (cross-sketch coalesced run)."""
+def hll_bank_add_packed_rows(bank, packed, slots, urows, count,
+                             seed: int = 0, family: str = "m3"):
+    """Multi-target PFADD (cross-sketch coalesced run): key i goes to bank
+    row urows[slots[i]] (see _bank_add)."""
     valid = jnp.arange(packed.shape[0], dtype=jnp.int32) < count
     h1 = _hll_h1_u64(U64(packed[:, 1], packed[:, 0]), seed, family)
-    return _bank_add(bank, h1, rows, valid)
+    return _bank_add(bank, h1, slots, urows, valid)
 
 
 @functools.partial(
     jax.jit, donate_argnums=(0,), static_argnames=("seed", "family"))
-def hll_bank_add_u64_rows(bank, hi, lo, rows, valid, seed: int = 0,
+def hll_bank_add_u64_rows(bank, hi, lo, slots, urows, valid, seed: int = 0,
                           family: str = "m3"):
     h1 = _hll_h1_u64(U64(hi, lo), seed, family)
-    return _bank_add(bank, h1, rows, valid)
+    return _bank_add(bank, h1, slots, urows, valid)
 
 
 @functools.partial(
@@ -322,10 +330,10 @@ def hll_bank_add_u64(bank, hi, lo, valid, row, seed: int = 0,
 
 @functools.partial(
     jax.jit, donate_argnums=(0,), static_argnames=("seed", "family"))
-def hll_bank_add_bytes_rows(bank, data, lengths, rows, valid, seed: int = 0,
-                            family: str = "m3"):
+def hll_bank_add_bytes_rows(bank, data, lengths, slots, urows, valid,
+                            seed: int = 0, family: str = "m3"):
     h1 = _hll_h1_bytes(data, lengths, seed, family)
-    return _bank_add(bank, h1, rows, valid)
+    return _bank_add(bank, h1, slots, urows, valid)
 
 
 @functools.partial(
@@ -427,15 +435,22 @@ def hll_bank_grow(bank, new_cap: int):
     return jnp.zeros((new_cap, m), bank.dtype).at[:s].set(bank)
 
 
-def pad_rows_repeat(rows):
-    """Pad a row-index vector to the next power of two by repeating the
-    first element (gather+max targets: repeats are idempotent, shapes stay
-    static per size class — no MIN_BUCKET floor; a 2-name countWith must
-    not gather 1024 rows)."""
+#: Fewest rows of a multi-target insert's block (_bank_add): runs over up
+#: to this many sketches share one compiled shape per key bucket.
+MIN_BLOCK_ROWS = 64
+
+
+def pad_rows_repeat(rows, floor: int = 1, cap: int = None):
+    """Pad a row-index vector to the next power of two (at least `floor`,
+    at most `cap`) by repeating the first element (gather+max targets:
+    repeats are idempotent, shapes stay static per size class — no
+    MIN_BUCKET floor; a 2-name countWith must not gather 1024 rows)."""
     import numpy as np
 
     n = rows.shape[0]
-    b = 1 << max(0, int(n - 1).bit_length())
+    b = max(floor, 1 << max(0, int(n - 1).bit_length()))
+    if cap is not None:
+        b = min(b, cap)
     if n == b:
         return rows
     out = np.full((b,), rows[0], rows.dtype)
