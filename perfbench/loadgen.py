@@ -14,6 +14,10 @@ flight, as an application server's connection pool does (memtier_benchmark,
 redis-benchmark -P). Readers run an open loop: read i is due at
 t_start + i / rate on connection i % connections, whatever the replies do,
 and is timed from when it was due.
+
+With `cluster` in the job, each connection acts as a Redis Cluster client
+(perfbench/slots.py): a command's frames go to the nodes that own their
+keys, and the command completes at its last reply.
 """
 
 from __future__ import annotations
@@ -34,7 +38,7 @@ if __package__ in (None, ""):
     sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
         __file__))))
 
-from perfbench import resp, traffic  # noqa: E402
+from perfbench import resp, slots, traffic  # noqa: E402
 
 BATCH = 256  # commands made per connection at a time
 
@@ -65,17 +69,18 @@ class _Stdin:
 STDIN = _Stdin()
 
 
-class _Conn:
-    def __init__(self, idx: int, host: str, port: int, sel):
-        self.idx = idx
-        self.sock = socket.create_connection((host, port))
+class _Node:
+    """One socket to one server: the bytes not yet sent, the reply parser,
+    and the frames sent on it that await replies, in order."""
+
+    def __init__(self, conn: int, addr, sel, sock=None):
+        self.conn = conn
+        self.sock = sock or socket.create_connection(addr)
         self.sock.setsockopt(socket.IPPROTO_TCP, socket.TCP_NODELAY, 1)
         self.sock.setblocking(False)
         self.out = bytearray()
         self.parser = resp.Parser()
-        self.waiting = collections.deque()  # (tag, frames left)
-        self.made = collections.deque()  # encoded commands not yet sent
-        self.next_j = 0
+        self.waiting = collections.deque()  # (command, frame position)
         self.sel = sel
         sel.register(self.sock, selectors.EVENT_READ, self)
 
@@ -89,6 +94,78 @@ class _Conn:
         want = selectors.EVENT_READ | (selectors.EVENT_WRITE if self.out
                                        else 0)
         self.sel.modify(self.sock, want, self)
+
+    def done(self, data: bytes):
+        """The commands whose last reply is in `data`."""
+        out = []
+        for reply in self.parser.feed(data):
+            cmd, pos = self.waiting.popleft()
+            cmd.replies[pos] = reply
+            cmd.left -= 1
+            if not cmd.left:
+                out.append(cmd)
+        return out
+
+
+class _Cmd:
+    """One command in flight on a logical connection; its frames' replies
+    are kept in frame order."""
+
+    __slots__ = ("conn", "j", "t_send", "i", "due", "left", "replies")
+
+    def __init__(self, conn: "_Conn", j: int, i: int = 0, due: float = 0.0):
+        self.conn, self.j, self.i, self.due = conn, j, i, due
+        self.t_send = time.monotonic()
+
+
+class _Conn:
+    """One logical connection: its commands, made in batches, and one
+    socket per node. Without a cluster that is one socket to the server;
+    in a cluster the connection reads the slot map once (CLUSTER SLOTS)
+    and sends each frame to the node that owns its key's slot. No
+    redirect is followed: -MOVED and -ASK are error replies."""
+
+    def __init__(self, idx: int, host: str, port: int, cluster: bool, sel):
+        self.idx = idx
+        self.made = collections.deque()  # (j, [(node, frame bytes)])
+        self.next_j = 0
+        self.slot_map = None
+        if not cluster:
+            self.nodes = [_Node(idx, (host, port), sel)]
+            return
+        seed = socket.create_connection((host, port))
+        try:
+            self.slot_map = slots.SlotMap(resp.call(seed, resp.Parser(),
+                                                    ["CLUSTER", "SLOTS"]))
+        except BaseException:
+            seed.close()
+            raise
+        self.nodes = [_Node(idx, a, sel, seed if a == (host, port) else None)
+                      for a in self.slot_map.nodes]
+        if (host, port) not in self.slot_map.nodes:
+            seed.close()
+
+    def node_of(self, args) -> int:
+        return 0 if self.slot_map is None else self.slot_map.node_of(
+            slots.command_key(args))
+
+    def send(self, cmd: _Cmd, frames) -> None:
+        """Queue each frame on its node's socket, then send."""
+        cmd.left = len(frames)
+        cmd.replies = [None] * len(frames)
+        touched = []
+        for pos, (n, data) in enumerate(frames):
+            node = self.nodes[n]
+            node.out += data
+            node.waiting.append((cmd, pos))
+            if node not in touched:
+                touched.append(node)
+        for node in touched:
+            node.flush()
+
+    def close(self) -> None:
+        for node in self.nodes:
+            node.sock.close()
 
 
 ERRORS: dict = {}  # error reply -> how many (first few kinds)
@@ -111,12 +188,13 @@ def _value(reply):
 def _refill(role: traffic.Role, conn: _Conn) -> None:
     js = np.arange(conn.next_j, conn.next_j + BATCH)
     for j, cmds in zip(js, role.make(conn.idx, js)):
-        conn.made.append((int(j), [resp.encode(a) for a in cmds]))
+        conn.made.append((int(j), [(conn.node_of(a), resp.encode(a))
+                                   for a in cmds]))
     conn.next_j += BATCH
 
 
 def _encoded(role: traffic.Role, conn: _Conn):
-    """Next command of this connection: (j, [frame bytes, ...])."""
+    """Next command of this connection: (j, [(node, frame bytes), ...])."""
     if not conn.made:
         _refill(role, conn)
     return conn.made.popleft()
@@ -127,16 +205,33 @@ def _window(clock) -> None:
     clock.update(STDIN.readline())
 
 
+def _completed(sel, timeout: float, clock, who: str):
+    """(command, time of its last reply) for each command completed by what
+    the sockets hold after waiting up to `timeout`; the parent's window
+    line is read as it comes."""
+    for key, mask in sel.select(timeout=timeout):
+        node = key.data
+        if node is STDIN:
+            _window(clock)
+            continue
+        if mask & selectors.EVENT_WRITE:
+            node.flush()
+        if not mask & selectors.EVENT_READ:
+            continue
+        data = node.sock.recv(1 << 20)
+        if not data:
+            raise ConnectionError(f"{who} {node.conn}: server closed")
+        now = time.monotonic()
+        for cmd in node.done(data):
+            yield cmd, now
+
+
 def run_writers(role, conns, sel, clock, rec):
     depth = int(role.spec["pipeline"])
-    sent = {}
 
     def send(c):
         j, frames = _encoded(role, c)
-        c.out += b"".join(frames)
-        c.waiting.append((j, len(frames), []))
-        sent[(c.idx, j)] = time.monotonic()
-        c.flush()
+        c.send(_Cmd(c, j), frames)
 
     _sleep_until(clock["t_start"])
     for c in conns:
@@ -145,33 +240,14 @@ def run_writers(role, conns, sel, clock, rec):
     inflight = depth * len(conns)
     while inflight and ("t_end" not in clock or time.monotonic()
                         < clock["t_end"] + clock["drain_s"]):
-        for key, mask in sel.select(timeout=0.05):
-            c = key.data
-            if c is STDIN:
-                _window(clock)
-                continue
-            if mask & selectors.EVENT_WRITE:
-                c.flush()
-            if not mask & selectors.EVENT_READ:
-                continue
-            data = c.sock.recv(1 << 20)
-            if not data:
-                raise ConnectionError(f"writer {c.idx}: server closed")
-            now = time.monotonic()
-            for reply in c.parser.feed(data):
-                j, left, vals = c.waiting[0]
-                vals.append(reply)
-                if left > 1:
-                    c.waiting[0] = (j, left - 1, vals)
-                    continue
-                c.waiting.popleft()
-                got_vals = [_value(x) for x in vals]
-                v, err = got_vals[-1][0], any(e for _, e in got_vals)
-                rec.append((c.idx, j, sent.pop((c.idx, j)), now, v, err))
-                inflight -= 1
-                if now < clock.get("t_end", np.inf):
-                    send(c)
-                    inflight += 1
+        for cmd, now in _completed(sel, 0.05, clock, "writer"):
+            got_vals = [_value(x) for x in cmd.replies]
+            v, err = got_vals[-1][0], any(e for _, e in got_vals)
+            rec.append((cmd.conn.idx, cmd.j, cmd.t_send, now, v, err))
+            inflight -= 1
+            if now < clock.get("t_end", np.inf):
+                send(cmd.conn)
+                inflight += 1
     return {"unanswered": inflight}
 
 
@@ -189,38 +265,17 @@ def run_readers(role, conns, sel, clock, rec):
         while i < n_due and t0 + i / rate <= now:
             c = conns[i % len(conns)]
             j, frames = _encoded(role, c)
-            c.out += b"".join(frames)
-            c.waiting.append((i, j, t0 + i / rate, time.monotonic(),
-                              len(frames), []))
-            c.flush()
+            c.send(_Cmd(c, j, i, t0 + i / rate), frames)
             i += 1
             pending += 1
         timeout = (t0 + i / rate - time.monotonic()) if i < n_due else 0.05
-        for key, mask in sel.select(timeout=max(0.0, min(timeout, 0.05))):
-            c = key.data
-            if c is STDIN:
-                _window(clock)
-                continue
-            if mask & selectors.EVENT_WRITE:
-                c.flush()
-            if not mask & selectors.EVENT_READ:
-                continue
-            data = c.sock.recv(1 << 20)
-            if not data:
-                raise ConnectionError(f"reader {c.idx}: server closed")
-            got = time.monotonic()
-            for reply in c.parser.feed(data):
-                ri, j, due, t_send, left, vals = c.waiting[0]
-                vals.append(reply)
-                if left > 1:
-                    c.waiting[0] = (ri, j, due, t_send, left - 1, vals)
-                    continue
-                c.waiting.popleft()
-                got_vals = [_value(x) for x in vals]
-                rec.append((ri, c.idx, j, due, t_send, got,
-                            any(e for _, e in got_vals),
-                            [v for v, _ in got_vals]))
-                pending -= 1
+        for cmd, got in _completed(sel, max(0.0, min(timeout, 0.05)), clock,
+                                   "reader"):
+            got_vals = [_value(x) for x in cmd.replies]
+            rec.append((cmd.i, cmd.conn.idx, cmd.j, cmd.due, cmd.t_send, got,
+                        any(e for _, e in got_vals),
+                        [v for v, _ in got_vals]))
+            pending -= 1
     return {"unanswered": pending, "due": int(n_due)}
 
 
@@ -261,8 +316,8 @@ def main() -> int:
     job = STDIN.readline()
     role = traffic.Role(job["role"], job["spec"], job["space"], job["seed"])
     sel = selectors.DefaultSelector()
-    conns = [_Conn(i, job["host"], job["port"], sel)
-             for i in range(role.connections)]
+    conns = [_Conn(i, job["host"], job["port"], job["cluster"],
+                   sel) for i in range(role.connections)]
     for c in conns:  # make the first batch before the clock starts
         _refill(role, c)
     out = sys.stdout.buffer
@@ -274,7 +329,7 @@ def main() -> int:
     run = run_writers if role.name == "writers" else run_readers
     info = run(role, conns, sel, clock, rec)
     for c in conns:
-        c.sock.close()
+        c.close()
     assert "jax" not in sys.modules, "the load generator imported jax"
     info["jax_imported"] = False
     info["errors"] = ERRORS

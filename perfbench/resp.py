@@ -36,6 +36,18 @@ def encode(args) -> bytes:
     return b"*%d\r\n" % n + b"".join(parts)
 
 
+def call(sock, parser: "Parser", args):
+    """Send one command on a blocking socket and return its reply."""
+    sock.sendall(encode(args))
+    while True:
+        data = sock.recv(1 << 16)
+        if not data:
+            raise ConnectionError("the server closed the connection")
+        got = parser.feed(data)
+        if got:
+            return got[0]
+
+
 class Parser:
     """Incremental parser of replies: integers, simple strings, errors,
     bulk strings and arrays of those."""

@@ -116,13 +116,14 @@ def peak_bytes(devices) -> int:
 
 
 class Child:
-    def __init__(self, role: traffic.Role, host: str, port: int, seed: int):
+    def __init__(self, role: traffic.Role, host: str, port: int, seed: int,
+                 cluster: bool):
         self.role = role
         self.proc = subprocess.Popen(
             [sys.executable, os.path.join(HERE, "loadgen.py")],
             stdin=subprocess.PIPE, stdout=subprocess.PIPE, cwd=ROOT)
         job = {"role": role.name, "spec": role.spec, "space": role.space,
-               "seed": seed, "host": host, "port": port}
+               "seed": seed, "host": host, "port": port, "cluster": cluster}
         self._send(job)
 
     def _send(self, obj) -> None:
@@ -160,15 +161,6 @@ class Child:
 def _sleep_until(t: float) -> None:
     while (left := t - time.monotonic()) > 0:
         time.sleep(min(left, 0.1))
-
-
-def _delta(c0, c1):
-    out = {}
-    for part, d1 in c1.items():
-        d0 = c0.get(part, {})
-        out[part] = {k: v - d0.get(k, 0) for k, v in d1.items()
-                     if isinstance(v, (int, float)) and not isinstance(v, bool)}
-    return out
 
 
 def _p(values: np.ndarray, q: float) -> float:
@@ -220,7 +212,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     `patch(system)` breaks the program for the harness's own tests;
     `control` adds `control_checks`, the numbers compared when the
     control (CONTROL) answers in the program's place."""
-    from perfbench.system import System
+    from perfbench.system import System, window_delta
 
     t_process = T_PROCESS if t_process is None else t_process
     devices = devices_for(cell.chips)
@@ -240,7 +232,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         roles = {name: traffic.Role(name, cell.traffic[name], cell.space,
                                     seed)
                  for name in ("writers", "readers")}
-        children = [Child(r, system.host, system.port, seed)
+        children = [Child(r, system.host, system.port, seed, system.cluster)
                     for r in roles.values()]
         for ch in children:
             ch.ready()
@@ -342,7 +334,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         device["busy_s"] = red["busy_s"]
         device["window_s"] = red["window_s"]
         result["breakdown"] = red["breakdown"]
-        ctx = {"delta": _delta(c0, c1), "seconds": seconds,
+        ctx = {"delta": window_delta(c0, c1), "seconds": seconds,
                "writes": int(done_w.sum()), "reads": int(in_r.sum()),
                "spans": spans, "trace": red, "late_s": late,
                "device_kind": devices[0].device_kind}

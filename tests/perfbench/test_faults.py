@@ -88,3 +88,27 @@ def test_fault_makes_correct_false(monkeypatch, cell, command, fault):
     res = run.run_cell(tiny_cell(cell), 2**31 + 3, 1.5, False)
     assert not res["correct"], res["checks"]
     assert np.isfinite(list(res["metrics"].values())[0]["value"])
+
+
+def test_wrong_slot_map_makes_correct_false(monkeypatch, tmp_path):
+    """A cluster client given a slot map in which two shards' ranges are
+    swapped sends their keys to the wrong node: those commands get -MOVED
+    replies, which the load generator does not follow."""
+    from perfbench import spec
+    from redisson_tpu.cluster.manager import ClusterManager
+    from tests.perfbench.helpers import cluster_files
+
+    real = ClusterManager.cluster_slots
+
+    def swapped(self):
+        ranges = real(self)
+        (s0, e0, a, r0), (s1, e1, b, r1) = ranges[:2]
+        return [(s0, e0, b, r0), (s1, e1, a, r1)] + ranges[2:]
+
+    monkeypatch.setattr(ClusterManager, "cluster_slots", swapped)
+    cell = spec.load_cell(cluster_files(tmp_path, "stacks"),
+                          root=str(tmp_path))
+    res = run.run_cell(cell, 2**31 + 5, 1.5, False)
+    assert not res["correct"], res["checks"]
+    assert res["checks"]["failed_replies"]["value"] > 0
+    assert res["failed"] > 0
