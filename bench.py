@@ -977,13 +977,13 @@ def bench_mesh(platform, n, reps, roofline=0.0, sketches=1000, quick=False):
 
       * mesh_inserts_per_sec — client-path HLL ingest through the mesh
         cluster facade (slot guard + shared dispatcher + sharded bank).
-      * launches_per_window — observed launch count per multi-shard tape
-        window (acceptance: 1.0 — one fused launch retires ALL shards'
-        ops; the stacks plane pays one launch train per shard).
+      * launches_per_window — observed mesh write launches per executor
+        run (acceptance: 1.0 — one row-block launch retires ALL shards'
+        PFADDs of a run; the stacks plane pays one launch per shard).
       * cross_shard_pfmerge_ms — PFMERGE over `sketches` HLLs whose slots
         span every shard, retired by the shard_map/pmax collective (no
         host register export).
-      * pct_of_roofline — mesh ingest rate against the tape megakernel's
+      * pct_of_roofline — mesh ingest rate against the ingest
         roofline measured by bench_roofline on the active device. In a
         JAX_PLATFORMS=cpu rehearsal this is a proxy (CPU scatter bound,
         not TPU HBM), flagged by the `platform` tag.
@@ -1009,16 +1009,16 @@ def bench_mesh(platform, n, reps, roofline=0.0, sketches=1000, quick=False):
                 fu.result(timeout=120)
 
         burst()  # warmup: compile the window shapes
-        s0 = backend.ingest_stats()
+        executor = client.cluster.mesh_client._executor
+        r0 = executor.pipeline_stats()["runs_completed"]
+        l0 = backend.counters["mesh_add_launches"]
         t0 = time.perf_counter()
         for _ in range(max(reps - 1, 1)):
             burst()
         dt = time.perf_counter() - t0
-        s1 = backend.ingest_stats()
         rate = max(reps - 1, 1) * n / dt
-        windows = s1.get("tape_runs", 0) - s0.get("tape_runs", 0)
-        launches = (s1.get("window_launches", 0)
-                    - s0.get("window_launches", 0))
+        windows = executor.pipeline_stats()["runs_completed"] - r0
+        launches = backend.counters["mesh_add_launches"] - l0
         lpw = round(launches / windows, 2) if windows else 0.0
 
         # -- cross-shard PFMERGE over `sketches` HLLs ----------------------
@@ -1053,7 +1053,7 @@ def bench_mesh(platform, n, reps, roofline=0.0, sketches=1000, quick=False):
             "pct_of_roofline": round(pct, 1),
             "platform": platform,
             "collective_merges": backend.counters["collective_merges"],
-            "multi_shard_windows": backend.counters["multi_shard_windows"],
+            "mesh_add_rows": backend.counters["mesh_add_rows"],
         }
     finally:
         client.shutdown()

@@ -1792,9 +1792,10 @@ def mesh_smoke():
           hll_export + bitset/bloom cells via bits_export + bucket
           values) under data_plane="stacks" and data_plane="mesh";
       (b) ONE LAUNCH PER MULTI-SHARD WINDOW: a burst of concurrent adds
-          spanning all shards retires through the shard-axis tape —
-          observed window_launches == tape windows (1.0 launches per
-          window) and the multi-shard window counter moves;
+          spanning all shards retires through the sharded bank's
+          row-block write program — one mesh write launch per executor
+          run (1.0 launches per window), some launch covering several
+          targets;
       (c) COLLECTIVE PFMERGE: merging HLLs living on different shards
           runs as a shard_map/pmax collective — count matches a hashtag
           co-located single-shard oracle and the link_bytes gauge is FLAT
@@ -1805,7 +1806,6 @@ def mesh_smoke():
     import shutil
     import tempfile
 
-    from redisson_tpu import native as native_mod
     from redisson_tpu.client import RedissonTPU
     from redisson_tpu.config import Config
     from redisson_tpu.ops.crc16 import key_slot
@@ -1920,37 +1920,33 @@ def mesh_smoke():
         mgr = c_m.cluster
         backend = mgr.mesh_client._routing.sketch
 
-        # -- (b) one fused launch per multi-shard window -----------------
-        if native_mod.available():
-            hs = [c_m.get_hyper_log_log(f"ms:w{i}") for i in range(4)]
-            rng = np.random.default_rng(31)
+        # -- (b) one launch per multi-shard window -----------------------
+        hs = [c_m.get_hyper_log_log(f"ms:w{i}") for i in range(4)]
+        rng = np.random.default_rng(31)
 
-            def burst():
-                futs = [h.add_ints_async(rng.integers(
-                    0, 2**63, burst_n, dtype=np.uint64)) for h in hs]
-                for fu in futs:
-                    fu.result(timeout=120)
+        def burst():
+            futs = [h.add_ints_async(rng.integers(
+                0, 2**63, burst_n, dtype=np.uint64)) for h in hs]
+            for fu in futs:
+                fu.result(timeout=120)
 
-            burst()  # warmup: compile the window shapes
-            s0 = backend.ingest_stats()
-            m0 = backend.counters["multi_shard_windows"]
-            for _ in range(3):
-                burst()
-            s1 = backend.ingest_stats()
-            windows = s1["tape_runs"] - s0["tape_runs"]
-            launches = s1["window_launches"] - s0["window_launches"]
-            multi = backend.counters["multi_shard_windows"] - m0
-            lpw = launches / max(windows, 1)
-            print(f"# mesh-smoke[window]: {launches} launches / "
-                  f"{windows} windows = {lpw:.2f} per window "
-                  f"({multi} multi-shard)")
-            if windows < 1 or launches != windows or multi < 1:
-                print("#   single-launch window gate failed",
-                      file=sys.stderr)
-                ok = False
-        else:
-            print("# mesh-smoke[window]: native tape encoder unavailable; "
-                  "SKIP (device ingest path)", file=sys.stderr)
+        burst()  # warmup: compile the window shapes
+        executor = mgr.mesh_client._executor
+        r0 = executor.pipeline_stats()["runs_completed"]
+        l0 = backend.counters["mesh_add_launches"]
+        rows0 = backend.counters["mesh_add_rows"]
+        for _ in range(3):
+            burst()
+        windows = executor.pipeline_stats()["runs_completed"] - r0
+        launches = backend.counters["mesh_add_launches"] - l0
+        rows = backend.counters["mesh_add_rows"] - rows0
+        lpw = launches / max(windows, 1)
+        print(f"# mesh-smoke[window]: {launches} launches / "
+              f"{windows} windows = {lpw:.2f} per window "
+              f"({rows} rows)")
+        if windows < 1 or launches != windows or rows <= launches:
+            print("#   single-launch window gate failed", file=sys.stderr)
+            ok = False
 
         # -- (c) collective PFMERGE: no host register export -------------
         table = mgr.router.slot_table()
@@ -3352,8 +3348,8 @@ def main():
                     help="mesh data-plane acceptance: per-op results + "
                          "state digest bit-identical between "
                          "data_plane=stacks and data_plane=mesh (live "
-                         "migration included), exactly one fused launch "
-                         "per multi-shard tape window, and cross-shard "
+                         "migration included), one mesh write launch "
+                         "per multi-shard PFADD run, and cross-shard "
                          "PFMERGE via the shard_map collective with a "
                          "flat link_bytes gauge (no host register "
                          "export), then exit")

@@ -10,6 +10,7 @@ op futures.
 
 from __future__ import annotations
 
+import bisect
 import functools
 import queue
 import threading
@@ -41,6 +42,7 @@ from redisson_tpu.ingest.pipeline import StagingPipeline
 from redisson_tpu.ingest.planner import IngestPlanner, default_planner
 from redisson_tpu.ops import bitset as bitset_ops, bloom as bloom_ops
 from redisson_tpu.ops import bloom_math
+from redisson_tpu.ops import hll as hll_ops
 from redisson_tpu.store import ObjectType, SketchStore, WrongTypeError
 from redisson_tpu.trace.stages import stage
 
@@ -360,7 +362,8 @@ class RowAllocator:
     counters (durability/checkpoint dirty tracking). `grow` is the
     backend's capacity hook: called with the requested new capacity, it
     reallocates the bank and returns the (possibly rounded-up) actual
-    capacity."""
+    capacity. The free list is kept sorted, so a claim inside a row range
+    is one bisect, not a scan (the mesh plane claims every row so)."""
 
     __slots__ = ("rows", "free", "next", "versions", "capacity", "_grow")
 
@@ -395,16 +398,17 @@ class RowAllocator:
         """Allocate a free row inside [lo, hi), or None when the range is
         full (mesh plane row placement: a shard's preferred device-local
         block; callers fall back to anywhere-allocation on None)."""
-        for i, r in enumerate(self.free):
-            if lo <= r < hi:
-                return self.free.pop(i)
+        i = bisect.bisect_left(self.free, lo)
+        if i < len(self.free) and self.free[i] < hi:
+            return self.free.pop(i)
         if lo <= self.next < hi:
             row = self.next
             self.next += 1
             return row
         if self.next < lo <= hi - 1 and hi <= self.capacity:
             # Skip the watermark forward into the block; the skipped rows
-            # stay allocatable through the free list.
+            # stay allocatable through the free list (every free row lies
+            # below the watermark, so the list stays sorted).
             self.free.extend(range(self.next, lo))
             self.next = lo + 1
             return lo
@@ -414,9 +418,12 @@ class RowAllocator:
         """Free the name's row for reuse; returns it (None if absent)."""
         row = self.rows.pop(name, None)
         if row is not None:
-            self.free.append(row)
+            self.free_row(row)
             self.versions.pop(name, None)
         return row
+
+    def free_row(self, row: int) -> None:
+        bisect.insort(self.free, row)
 
     def bump(self, name: str) -> None:
         self.versions[name] = self.versions.get(name, 0) + 1
@@ -641,9 +648,11 @@ class TpuBackend:
             "geo_planes": 0,      # remote site planes through the fused path
             "geo_classic": 0,     # remote planes absorbed via the fallback
             "collective_merges": 0,    # PFMERGE/count runs via mesh collectives
-            "multi_shard_windows": 0,  # tape windows spanning > 1 shard
             "bank_add_launches": 0,    # multi-target HLL bank inserts
             "bank_add_rows": 0,        # distinct bank rows those touched
+            "mesh_add_launches": 0,    # HLL inserts into the mesh bank
+            "mesh_add_rows": 0,        # distinct rows those touched
+            "mesh_add_block_rows": 0,  # ... padded to their block size
         }
         # adds d2h_wait_ns / resolve_ns / runs_materialized to the counters
         self.completer = Completer(counters=self.counters)
@@ -681,7 +690,7 @@ class TpuBackend:
         sharded across `mesh` via NamedSharding(mesh, P("slots")), with
         per-logical-shard preferred row blocks so a shard's sketches stay
         device-local. `shard_of` maps a target name to its logical shard
-        (tape shard column + per-shard memstat attribution). Must be
+        (per-shard row placement and memstat attribution). Must be
         called before the first bank-touching op (the client/manager wire
         it right after construction, before the executor starts)."""
         from redisson_tpu.parallel.mesh import ShardedBank
@@ -730,7 +739,7 @@ class TpuBackend:
                 self.bank, regs, np.int32(new))
             self.bank = engine.hll_bank_zero_row(self.bank, np.int32(row))
             self._alloc.rows[name] = new
-            self._alloc.free.append(row)
+            self._alloc.free_row(row)
             self._bump(name)
             moved += 1
         if moved:
@@ -751,9 +760,9 @@ class TpuBackend:
         sb = self._sharded_bank
         if sb is not None:
             new_cap = sb.round_capacity(new_cap)
+            bank = self._ensure_bank()
             sb.capacity = new_cap
-            self.bank = sb.place(
-                engine.hll_bank_grow(self._ensure_bank(), new_cap))
+            self.bank = engine.hll_bank_grow_mesh(bank, new_cap, mesh=sb.mesh)
         else:
             self.bank = engine.hll_bank_grow(self._ensure_bank(), new_cap)
         self._account_bank()
@@ -1514,19 +1523,13 @@ class TpuBackend:
                           target=planes[0].target if planes else "")
         t0 = time.perf_counter()
         spec_by = {id(p): s for p, s in zip(planes, specs)}
-        tp = tape_mod.encode_window(planes, self._hll_row, self._shard_of)
+        tp = tape_mod.encode_window(planes, self._hll_row)
         self.counters["link_bytes"] += tp.link_bytes
-        if tp.n_shards > 1:
-            # Mesh data plane: this single launch retires a window whose
-            # entries span multiple logical shards (the tape's shard axis).
-            self.counters["multi_shard_windows"] += 1
         n_hll = tp.n_hll
-        # A window with no HLL entries never reads the bank — its dummy
-        # bank stays on the store device, so the operands must too (a jit
-        # may not mix mesh-replicated and single-device committed inputs).
-        mesh_mode = self._sharded_bank is not None and bool(n_hll)
-        put = (self._put if mesh_mode
-               else (lambda a: jax.device_put(a, self.store.device)))
+
+        def put(a):
+            return jax.device_put(a, self.store.device)
+
         wire = put(tp.wire)
         table = put(tp.table)
         if n_hll:
@@ -1536,17 +1539,12 @@ class TpuBackend:
             rows_pad = put(np.zeros((1,), np.int32))
             bank = jnp.zeros((1, 1), jnp.int32)  # dummy, never read
         store_planes = tp.planes[n_hll:]
-        store_old = tuple(
-            # Mixed mesh window: store-backed old rows must share the
-            # bank's mesh placement inside the fused jit (replicated).
-            put(self.store.get(p.target).state) if mesh_mode
-            else self.store.get(p.target).state
-            for p in store_planes)
+        store_old = tuple(self.store.get(p.target).state
+                          for p in store_planes)
         want_old = any(p.kind == "bitset_set" for p in store_planes)
         new_bank, merged, changed, old_packed = engine.tape_apply(
             bank, wire, table, rows_pad, store_old,
-            n_hll=n_hll, lanes=tp.lanes, want_old=want_old,
-            mesh=self._sharded_bank.mesh if mesh_mode else None)
+            n_hll=n_hll, lanes=tp.lanes, want_old=want_old)
         self.counters["merge_launches"] += 1
         self.counters["window_launches"] += 1
         # Writeback — dispatch-time state, same contract as the chunked
@@ -1557,12 +1555,7 @@ class TpuBackend:
                 self._bump(p.target)
         for j, p in enumerate(store_planes):
             row = n_hll + j
-            new_state = merged[row, : p.cells]
-            if mesh_mode:
-                # Store objects live on the single store device; re-commit
-                # the mesh-placed merged row before the swap.
-                new_state = jax.device_put(new_state, self.store.device)
-            self.store.swap(p.target, new_state)
+            self.store.swap(p.target, merged[row, : p.cells])
             self._touch(p.target)
             if p.kind == "bloom_add":
                 # device == mirror + this batch == scratch, by construction
@@ -1660,7 +1653,7 @@ class TpuBackend:
 
             sb = self._sharded_bank
             if sb is not None:
-                self.bank = sb.place(engine.hll_bank_make(sb.capacity))
+                self.bank = sb.make(hll_ops.M)
             else:
                 self.bank = jax.device_put(
                     engine.hll_bank_make(self.bank_capacity),
@@ -1763,8 +1756,8 @@ class TpuBackend:
         device_ops = [op for op in ops if "device_packed" in op.payload]
         host_ops = packed_ops + int_ops + byte_ops
         if host_ops:
-            path = self._plan_ingest(sum(op.nkeys or self._payload_nkeys(op)
-                                         for op in host_ops))
+            path = "device" if self.mesh is not None else self._plan_ingest(
+                sum(op.nkeys or self._payload_nkeys(op) for op in host_ops))
             if path == "hostfold":
                 self._hll_add_hostfold(host_ops)
             else:
@@ -1859,24 +1852,49 @@ class TpuBackend:
 
         self.completer.submit(run)
 
-    def _run_slots(self, ops: List[Op], nkeys) -> tuple:
-        """Routing of a multi-target insert run (engine._bank_add): each
-        key's slot — its op's target's index among the run's distinct
-        bank rows — and those rows, padded to a power of two of at least
-        MIN_BLOCK_ROWS and at most the bank's row count. Returns
-        (slots[N], urows[R], distinct row count)."""
+    def _add_rows(self, ops: List[Op], nkeys, kernel, operands) -> tuple:
+        """A multi-target insert run (engine._bank_add): each op's slot —
+        its target's index among the run's distinct bank rows — and those
+        rows, padded to a power of two of at least MIN_BLOCK_ROWS and at
+        most the bank's row count; then one `kernel` launch per chunk of
+        keys. `nkeys` is each op's key count and operands(s, e) the
+        kernel's padded key operands for keys [s, e) and their validity.
+        On the mesh plane the launches update the sharded bank, at most
+        MESH_CHUNK_KEYS keys each (a fill builds one program per bank
+        size). Returns (changed parts, op slots)."""
         slot_of: dict = {}
-        slots = [slot_of.setdefault(self._rows[op.target], len(slot_of))
-                 for op in ops]
+        slots = np.asarray([slot_of.setdefault(self._rows[op.target],
+                                               len(slot_of)) for op in ops],
+                           np.int32)
+        nrows = len(slot_of)
         urows = engine.pad_rows_repeat(
-            np.fromiter(slot_of, np.int32, len(slot_of)),
+            np.fromiter(slot_of, np.int32, nrows),
             floor=engine.MIN_BLOCK_ROWS, cap=self._ensure_bank().shape[0])
-        return (np.repeat(np.asarray(slots, np.int32), nkeys), urows,
-                len(slot_of))
-
-    def _bank_add_launched(self, rows: int) -> None:
-        self.counters["bank_add_launches"] += 1
-        self.counters["bank_add_rows"] += rows
+        slotv = np.repeat(slots, nkeys)
+        mesh = self.mesh
+        ids = {"rows": nrows}
+        chunk = None
+        if mesh is not None:
+            ids["chips"] = int(mesh.devices.size)
+            chunk = engine.MESH_CHUNK_KEYS
+        c = self.counters
+        parts = []
+        for s, e in engine.chunk_spans(slotv.shape[0], chunk):
+            keys, valid = operands(s, e)
+            pslot, _ = engine.pad_ints(slotv[s:e])
+            with stage("backend.launch", keys=e - s, **ids):
+                self.bank, changed = kernel(
+                    self._ensure_bank(), *keys, pslot, urows, valid,
+                    self.seed, self.family, mesh=mesh)
+            if mesh is None:
+                c["bank_add_launches"] += 1
+                c["bank_add_rows"] += nrows
+            else:
+                c["mesh_add_launches"] += 1
+                c["mesh_add_rows"] += nrows
+                c["mesh_add_block_rows"] += int(urows.shape[0])
+            parts.append(changed)
+        return parts, slots
 
     def _one_row(self, ops: List[Op]):
         """np.int32 row when every op targets one sketch (the scalar-row
@@ -1895,8 +1913,13 @@ class TpuBackend:
         # one call for many sketches that touches only those rows.
         # `impl` is the planner's device insert choice for this run; it
         # reaches the scalar-row kernels (the multi-target kernels stay on
-        # the XLA scatter, see engine._bank_add_row).
+        # the XLA scatter, see engine._bank_add_row). The mesh plane's
+        # sharded bank takes only the multi-target kernels, whose changed
+        # flags come back per slot of the run's rows.
+        mesh = self.mesh
+        one = self._one_row(ops) if mesh is None else None
         parts = []
+        slots = None
         if "packed" in ops[0].payload:
             # Concatenating copies 8 B/key on the dispatcher, so a LARGE
             # op's buffer ships to the device as-is through the scalar-row
@@ -1909,7 +1932,7 @@ class TpuBackend:
             chunks = []
             for op in ops:
                 arr = op.payload["packed"]
-                if arr.shape[0] < engine.MIN_BUCKET:
+                if mesh is not None or arr.shape[0] < engine.MIN_BUCKET:
                     small.append(op)
                     continue
                 row = self._rows[op.target]
@@ -1917,8 +1940,6 @@ class TpuBackend:
                     (row, arr[s:e])
                     for s, e in engine.chunk_spans(arr.shape[0]))
             if chunks:
-                import jax
-
                 def put_chunk(item):
                     row, chunk = item
                     prows, count = engine.pad_rows(chunk)
@@ -1937,75 +1958,89 @@ class TpuBackend:
             if small:
                 packed = np.concatenate(
                     [op.payload["packed"] for op in small])
-                slotv, urows, nrows = self._run_slots(
-                    small, [op.payload["packed"].shape[0] for op in small])
-                for s, e in engine.chunk_spans(packed.shape[0]):
+
+                def operands(s, e):
                     pk_, count = engine.pad_rows(packed[s:e])
-                    pslot, _ = engine.pad_ints(slotv[s:e])
-                    with stage("backend.launch", keys=e - s, rows=nrows):
-                        self.bank, changed = engine.hll_bank_add_packed_rows(
-                            self._ensure_bank(), pk_, pslot, urows,
-                            np.int32(count), self.seed, self.family
-                        )
-                    self._bank_add_launched(nrows)
-                    parts.append(changed)
+                    return (pk_,), np.int32(count)
+
+                got, slots = self._add_rows(
+                    small, [op.payload["packed"].shape[0] for op in small],
+                    engine.hll_bank_add_packed_rows, operands)
+                parts.extend(got)
         elif "hi" in ops[0].payload:
-            one = self._one_row(ops)
             hi = np.concatenate([op.payload["hi"] for op in ops])
             lo = np.concatenate([op.payload["lo"] for op in ops])
             if one is None:
-                slotv, urows, nrows = self._run_slots(
-                    ops, [op.payload["hi"].shape[0] for op in ops])
-            for s, e in engine.chunk_spans(hi.shape[0]):
-                phi, valid = engine.pad_ints(hi[s:e])
-                plo, _ = engine.pad_ints(lo[s:e])
-                if one is not None:  # scalar row: no 4 B/key row transfer
+                def operands(s, e):
+                    phi, valid = engine.pad_ints(hi[s:e])
+                    plo, _ = engine.pad_ints(lo[s:e])
+                    return (phi, plo), valid
+
+                parts, slots = self._add_rows(
+                    ops, [op.payload["hi"].shape[0] for op in ops],
+                    engine.hll_bank_add_u64_rows, operands)
+            else:  # scalar row: no 4 B/key row transfer
+                for s, e in engine.chunk_spans(hi.shape[0]):
+                    phi, valid = engine.pad_ints(hi[s:e])
+                    plo, _ = engine.pad_ints(lo[s:e])
                     with stage("backend.launch", keys=e - s):
                         self.bank, changed = engine.hll_bank_add_u64(
                             self._ensure_bank(), phi, plo, valid, one,
                             self.seed, self.family, impl
                         )
-                else:
-                    pslot, _ = engine.pad_ints(slotv[s:e])
-                    with stage("backend.launch", keys=e - s, rows=nrows):
-                        self.bank, changed = engine.hll_bank_add_u64_rows(
-                            self._ensure_bank(), phi, plo, pslot, urows,
-                            valid, self.seed, self.family
-                        )
-                    self._bank_add_launched(nrows)
-                parts.append(changed)
+                    parts.append(changed)
         else:
-            one = self._one_row(ops)
             data, lengths, spans = self._coalesce_bytes(ops)
             if one is None:
-                slotv, urows, nrows = self._run_slots(
-                    ops, [e - s for s, e in spans])
-            for s, e in engine.chunk_spans(data.shape[0]):
-                pdata, plengths, valid = engine.pad_bytes(data[s:e], lengths[s:e])
-                if one is not None:
+                def operands(s, e):
+                    pdata, plengths, valid = engine.pad_bytes(data[s:e],
+                                                              lengths[s:e])
+                    return (pdata, plengths), valid
+
+                parts, slots = self._add_rows(
+                    ops, [e - s for s, e in spans],
+                    engine.hll_bank_add_bytes_rows, operands)
+            else:
+                for s, e in engine.chunk_spans(data.shape[0]):
+                    pdata, plengths, valid = engine.pad_bytes(data[s:e],
+                                                              lengths[s:e])
                     with stage("backend.launch", keys=e - s):
                         self.bank, changed = engine.hll_bank_add_bytes(
                             self._ensure_bank(), pdata, plengths, valid, one,
                             self.seed, self.family, impl
                         )
-                else:
-                    pslot, _ = engine.pad_ints(slotv[s:e])
-                    with stage("backend.launch", keys=e - s, rows=nrows):
-                        self.bank, changed = engine.hll_bank_add_bytes_rows(
-                            self._ensure_bank(), pdata, plengths, pslot,
-                            urows, valid, self.seed, self.family
-                        )
-                    self._bank_add_launched(nrows)
-                parts.append(changed)
+                    parts.append(changed)
         for op in ops:
             self._bump(op.target)
-        self._complete_changed(ops, parts)
+        if mesh is None:
+            self._complete_changed(ops, parts)
+        else:
+            complete_changed_rows(self.completer, ops, list(slots), parts)
 
     def _hll_add_device(self, ops: List[Op]) -> None:
         """Device-resident ingest: the payload array is already on the
         chip, so each op is one kernel dispatch at its own (padded) shape —
         no host copy, no transfer, no concatenation. Row is a traced
-        scalar: no per-key row vector materializes on device either."""
+        scalar: no per-key row vector materializes on device either. On
+        the mesh plane the keys are replicated and go through the
+        multi-target kernel into the sharded bank."""
+        if self.mesh is not None:
+            arr = jnp.concatenate([op.payload["device_packed"] for op in ops])
+
+            def operands(s, e):
+                b = engine.bucket_size(e - s)
+                packed = jnp.zeros((b, 2), jnp.uint32).at[:e - s].set(
+                    arr[s:e])
+                return (self._put(packed),), np.int32(e - s)
+
+            parts, slots = self._add_rows(
+                ops, [int(op.payload["device_packed"].shape[0])
+                      for op in ops],
+                engine.hll_bank_add_packed_rows, operands)
+            for op in ops:
+                self._bump(op.target)
+            complete_changed_rows(self.completer, ops, list(slots), parts)
+            return
         parts = []
         for op in ops:
             row = self._rows[op.target]
@@ -2043,7 +2078,13 @@ class TpuBackend:
         _trace_cache(ops, hit=False)
         # async dispatch; D2H starts now, sync happens off-thread
         with stage("backend.launch", ops=len(ops)):
-            est = _start_d2h(engine.hll_bank_count(self.bank, np.int32(row)))
+            if self.mesh is not None:
+                # a row of the sharded bank: read where it lies, no gather
+                est = _start_d2h(engine.hll_bank_count_rows_collective(
+                    self.bank, np.array([row], np.int32), mesh=self.mesh))
+            else:
+                est = _start_d2h(engine.hll_bank_count(self.bank,
+                                                       np.int32(row)))
 
         def materialize(est=est, epoch=epoch):
             # graftlint: allow-sync(completer thread: blocking materialization is this thread's job)

@@ -151,11 +151,8 @@ class ClusterManager:
         """Config for the mesh plane's ONE shared engine stack. shard_id
         == -2 makes the client install the MeshOwnershipBackend guard and
         attach the sharded bank (never the cluster facade). The ingest
-        path is pinned, not 'auto': the tape megakernel is what retires a
-        multi-shard window in one launch, and the planner's 'delta' path
-        must never be picked here (its fused multi-target stacks assume a
-        single-device bank)."""
-        from redisson_tpu import native as native_mod
+        path is pinned to 'device': a window's PFADDs retire through the
+        sharded bank's row-block program (engine._mesh_bank_add)."""
         from redisson_tpu.config import Config, PersistConfig, TpuConfig
 
         parent = self.config
@@ -166,8 +163,7 @@ class ClusterManager:
             inflight_runs=parent.inflight_runs,
         )
         tcfg = parent.tpu or TpuConfig()
-        cfg.tpu = dataclasses.replace(
-            tcfg, ingest="tape" if native_mod.available() else "device")
+        cfg.tpu = dataclasses.replace(tcfg, ingest="device")
         if cluster.dir:
             cfg.persist = PersistConfig(
                 dir=os.path.join(cluster.dir, "mesh"),
@@ -474,8 +470,7 @@ class ClusterManager:
             counters = getattr(self.mesh_client._routing.sketch,
                                "counters", {})
             info["collective_merges"] = counters.get("collective_merges", 0)
-            info["multi_shard_windows"] = counters.get(
-                "multi_shard_windows", 0)
+            info["mesh_add_launches"] = counters.get("mesh_add_launches", 0)
         return info
 
     def stats(self) -> Dict[str, Any]:

@@ -371,8 +371,8 @@ class MeshOwnershipBackend:
 
     def shard_of_key(self, name: str) -> int:
         """Authoritative owner of a key's slot (0 while the table is
-        open) — the backend's `shard_of` hook: tape shard column,
-        per-shard bank-row placement, memstat attribution."""
+        open) — the backend's `shard_of` hook: per-shard bank-row
+        placement, memstat attribution."""
         slot = key_slot(name)
         with self._lock:
             if self._owner is None:

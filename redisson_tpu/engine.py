@@ -240,33 +240,61 @@ def hll_bank_make(capacity: int, m: int = None) -> jnp.ndarray:
     return jnp.zeros((capacity, m), jnp.int32)
 
 
-def _bank_add(bank, h1, slots, urows, valid):
-    """Multi-target insert. Returns (new_bank, changed_rows[S]) — changed
-    is PER ROW, so a cross-sketch coalesced run can give every op its own
-    PFADD bool (Redis semantics: did THIS key's sketch change) instead of
-    leaking one run-wide flag across targets.
-
-    `urows[R]` are the run's distinct bank rows (padded by repeating the
-    first, see pad_rows_repeat) and `slots[N]` each key's index into them.
-    The R touched rows are gathered into an [R, m] block, the keys
-    scatter-max into the block, and changed is a compare of the block
-    before and after — so the work scales with the rows the run touches,
-    not with the bank. The block goes back with a MAX scatter: padding
-    repeats carry an unmodified copy of their row, which max leaves
-    harmless. Nothing reads `bank` after that write-back, so XLA updates
-    the donated buffer in place (no bank-sized copy, relayout or
-    compare)."""
-    s, m = bank.shape
-    p = m.bit_length() - 1
-    bucket, rank = hll.bucket_rank(h1, p)
-    rank = jnp.where(valid, rank, 0)  # padded lanes: rank 0 never raises
+def _block_add(bank, h1, slots, rows, valid):
+    """Insert keys into the [R, m] block of bank `rows`, key i into row
+    rows[slots[i]]. The block is gathered, the keys scatter-max into it,
+    and it goes back with a MAX scatter: a padding repeat of a row
+    carries an unmodified copy, which max leaves harmless, and keys not
+    `valid` carry rank 0, which never raises a register. Returns
+    (new_bank, changed[R]), per slot of `rows`: a compare of the block
+    before and after."""
+    m = bank.shape[1]
+    bucket, rank = hll.bucket_rank(h1, m.bit_length() - 1)
+    rank = jnp.where(valid, rank, 0)
     slots = jnp.where(valid, slots, 0)
-    block = bank[urows]
+    block = bank[rows]
     new_block = block.at[slots, bucket].max(rank)
-    changed_slot = jnp.any(new_block != block, axis=1)
-    new = bank.at[urows].max(new_block)
-    changed_rows = jnp.zeros((s,), bool).at[urows].max(changed_slot)
-    return new, changed_rows
+    return bank.at[rows].max(new_block), jnp.any(new_block != block, axis=1)
+
+
+def _bank_add(bank, h1_of, keys, slots, urows, valid, mesh=None):
+    """Multi-target insert of `keys` (hashed by h1_of(*keys)): key i into
+    bank row urows[slots[i]]. `urows[R]` are the run's distinct bank rows
+    (padded by repeating the first, see pad_rows_repeat). Only the R
+    touched rows are gathered, updated and written back (_block_add), so
+    the work scales with the rows the run touches, not with the bank;
+    nothing reads `bank` after the write-back, so XLA updates the donated
+    buffer in place (no bank-sized copy, relayout or compare).
+
+    Returns (new_bank, changed) — changed per target, so a cross-sketch
+    coalesced run can give every op its own PFADD bool (Redis semantics:
+    did THIS key's sketch change): per bank row [S] on one device.
+
+    With `mesh` the bank is row-sharded over it (the mesh data plane).
+    The keys, slots and rows are replicated; each device takes the rows
+    it holds by the local index row - axis_index * s_local (masked and
+    clipped as _local_fold does) and inserts only the keys of those rows,
+    so another device's row is a clipped stand-in written back unchanged.
+    No index spans the whole bank, and nothing gathers it; changed comes
+    back per slot of `urows` [R], from one pmax across the mesh."""
+    if mesh is None:
+        new, changed = _block_add(bank, h1_of(*keys), slots, urows, valid)
+        return new, jnp.zeros((bank.shape[0],), bool).at[urows].max(changed)
+    axis = mesh.axis_names[0]
+
+    def local(bank_local, keys, slots, urows, valid):
+        s_local = bank_local.shape[0]
+        lrows = urows - jax.lax.axis_index(axis) * s_local
+        own = (lrows >= 0) & (lrows < s_local)
+        new, changed = _block_add(
+            bank_local, h1_of(*keys), slots,
+            jnp.clip(lrows, 0, s_local - 1), valid & own[slots])
+        return new, jax.lax.pmax(changed.astype(jnp.int32), axis) > 0
+
+    rep = _P()
+    return _shard_map(
+        local, mesh=mesh, in_specs=(_P(axis, None), rep, rep, rep, rep),
+        out_specs=(_P(axis, None), rep))(bank, keys, slots, urows, valid)
 
 
 def _bank_add_row(bank, h1, row, valid, impl: str = "scatter"):
@@ -300,22 +328,25 @@ def hll_bank_add_packed(bank, packed, count, row, seed: int = 0,
 
 
 @functools.partial(
-    jax.jit, donate_argnums=(0,), static_argnames=("seed", "family"))
+    jax.jit, donate_argnums=(0,), static_argnames=("seed", "family", "mesh"))
 def hll_bank_add_packed_rows(bank, packed, slots, urows, count,
-                             seed: int = 0, family: str = "m3"):
+                             seed: int = 0, family: str = "m3", mesh=None):
     """Multi-target PFADD (cross-sketch coalesced run): key i goes to bank
-    row urows[slots[i]] (see _bank_add)."""
+    row urows[slots[i]]; with `mesh`, into the bank row-sharded over it
+    (see _bank_add)."""
     valid = jnp.arange(packed.shape[0], dtype=jnp.int32) < count
-    h1 = _hll_h1_u64(U64(packed[:, 1], packed[:, 0]), seed, family)
-    return _bank_add(bank, h1, slots, urows, valid)
+    return _bank_add(
+        bank, lambda p: _hll_h1_u64(U64(p[:, 1], p[:, 0]), seed, family),
+        (packed,), slots, urows, valid, mesh)
 
 
 @functools.partial(
-    jax.jit, donate_argnums=(0,), static_argnames=("seed", "family"))
+    jax.jit, donate_argnums=(0,), static_argnames=("seed", "family", "mesh"))
 def hll_bank_add_u64_rows(bank, hi, lo, slots, urows, valid, seed: int = 0,
-                          family: str = "m3"):
-    h1 = _hll_h1_u64(U64(hi, lo), seed, family)
-    return _bank_add(bank, h1, slots, urows, valid)
+                          family: str = "m3", mesh=None):
+    return _bank_add(
+        bank, lambda h, lo_: _hll_h1_u64(U64(h, lo_), seed, family),
+        (hi, lo), slots, urows, valid, mesh)
 
 
 @functools.partial(
@@ -329,11 +360,12 @@ def hll_bank_add_u64(bank, hi, lo, valid, row, seed: int = 0,
 
 
 @functools.partial(
-    jax.jit, donate_argnums=(0,), static_argnames=("seed", "family"))
+    jax.jit, donate_argnums=(0,), static_argnames=("seed", "family", "mesh"))
 def hll_bank_add_bytes_rows(bank, data, lengths, slots, urows, valid,
-                            seed: int = 0, family: str = "m3"):
-    h1 = _hll_h1_bytes(data, lengths, seed, family)
-    return _bank_add(bank, h1, slots, urows, valid)
+                            seed: int = 0, family: str = "m3", mesh=None):
+    return _bank_add(
+        bank, lambda d, n: _hll_h1_bytes(d, n, seed, family),
+        (data, lengths), slots, urows, valid, mesh)
 
 
 @functools.partial(
@@ -389,12 +421,9 @@ def hll_bank_absorb_rows(bank, regs_u8, rows):
     half of the transfer-adaptive ingest (one kernel absorbs a whole
     cross-sketch hostfold run). Returns (new_bank, changed[R]) with a
     per-source changed flag (the PFADD bool for that source's target)."""
-    s, m = bank.shape
     f = regs_u8.astype(jnp.int32)
-    flat = bank.reshape(-1)
-    idx = (rows[:, None] * m + jnp.arange(m, dtype=rows.dtype)[None, :])
-    changed = jnp.any(f > flat[idx.reshape(-1)].reshape(f.shape), axis=1)
-    return flat.at[idx.reshape(-1)].max(f.reshape(-1)).reshape(s, m), changed
+    changed = jnp.any(f > bank[rows], axis=1)
+    return bank.at[rows].max(f), changed
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -415,12 +444,8 @@ def hll_bank_rows_u8(bank, rows):
 def hll_bank_set_rows(bank, regs_u8, rows):
     """Overwrite bank rows [R] with merged [R, m] uint8 register images —
     the writeback half of the fused delta merge (rows are unique within a
-    run, so a flat set scatter is race-free)."""
-    s, m = bank.shape
-    flat = bank.reshape(-1)
-    idx = rows[:, None] * m + jnp.arange(m, dtype=rows.dtype)[None, :]
-    return flat.at[idx.reshape(-1)].set(
-        regs_u8.astype(jnp.int32).reshape(-1)).reshape(s, m)
+    run, so a row set scatter is race-free)."""
+    return bank.at[rows].set(regs_u8.astype(jnp.int32))
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -438,6 +463,11 @@ def hll_bank_grow(bank, new_cap: int):
 #: Fewest rows of a multi-target insert's block (_bank_add): runs over up
 #: to this many sketches share one compiled shape per key bucket.
 MIN_BLOCK_ROWS = 64
+
+#: Most keys of one mesh write launch (_bank_add with a mesh). v5e's compiler
+#: builds the program in ~2 s up to 2^15 keys and in ~7 s past it, and a
+#: fill builds one for every bank size it grows through.
+MESH_CHUNK_KEYS = 1 << 15
 
 
 def pad_rows_repeat(rows, floor: int = 1, cap: int = None):
@@ -512,7 +542,8 @@ def _mesh_collectives(mesh):
         upd = jnp.where(t_own, merged, bank_local[ti])
         return bank_local.at[ti].set(upd), hll.count(merged)
 
-    def _count_body(bank_local, rows):
+    def hll_bank_count_mesh(bank_local, rows):
+        # named: the device trace finds the union count's program by it
         merged, _, _ = _local_fold(bank_local, rows)
         return hll.count(merged)
 
@@ -538,7 +569,7 @@ def _mesh_collectives(mesh):
         out_specs=(bank_spec, rep)), donate_argnums=(0,))
     # graftlint: allow-recompile(constructed once per mesh via lru_cache)
     count = jax.jit(_shard_map(
-        _count_body, mesh=mesh, in_specs=(bank_spec, rep),
+        hll_bank_count_mesh, mesh=mesh, in_specs=(bank_spec, rep),
         out_specs=rep))
     # graftlint: allow-recompile(constructed once per mesh via lru_cache)
     occupancy = jax.jit(_shard_map(
@@ -571,6 +602,47 @@ def hll_bank_count_rows_collective(bank, rows, *, mesh):
 def hll_bank_occupancy_collective(bank, *, mesh):
     """Mesh-wide non-empty row count (DBSIZE analogue) via one psum."""
     return _mesh_collectives(mesh)["occupancy"](bank)
+
+
+@functools.partial(
+    jax.jit, donate_argnums=(0,), static_argnames=("new_cap", "mesh"))
+def hll_bank_grow_mesh(bank, new_cap: int, *, mesh):
+    """Elastic capacity of the mesh-sharded bank, [S, m] -> [S', m] with
+    row indices stable, built sharded. Growing moves rows between devices
+    (device d's new block holds the old blocks of devices d*k .. d*k+k-1,
+    k = S'/S); they travel as ppermutes of 1024-row pieces, one piece at
+    a time, so a device holds its old block, its new block and one piece,
+    never the bank (an XLA-partitioned pad gathers it)."""
+    import math
+
+    axis = mesh.axis_names[0]
+    n = int(mesh.devices.size)
+    s, m = bank.shape
+    old, new = s // n, new_cap // n
+    if new % old:
+        raise ValueError(f"mesh bank grow {s} -> {new_cap}: the new "
+                         f"per-device rows must be a multiple of the old")
+    k = new // old
+    piece = math.gcd(old, 1024)
+
+    def body(local):
+        out = jnp.zeros((new, m), local.dtype)
+        for j in range(k):
+            perm = [(d * k + j, d) for d in range(n) if d * k + j < n]
+            if not perm:
+                continue
+
+            def step(c, out, j=j, perm=perm):
+                part = jax.lax.dynamic_slice_in_dim(local, c * piece, piece)
+                return jax.lax.dynamic_update_slice_in_dim(
+                    out, jax.lax.ppermute(part, axis, perm),
+                    j * old + c * piece, 0)
+
+            out = jax.lax.fori_loop(0, old // piece, step, out)
+        return out
+
+    return _shard_map(body, mesh=mesh, in_specs=_P(axis, None),
+                      out_specs=_P(axis, None), check_vma=False)(bank)
 
 
 @jax.jit
@@ -627,9 +699,9 @@ def delta_merge_stack(old, delta):
 
 
 @functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=("n_hll", "lanes", "want_old", "mesh"))
+                   static_argnames=("n_hll", "lanes", "want_old"))
 def tape_apply(bank, wire, table, hll_rows, store_old, *,
-               n_hll: int, lanes: int, want_old: bool = False, mesh=None):
+               n_hll: int, lanes: int, want_old: bool = False):
     """Consume one encoded window tape in a SINGLE device call.
 
     The whole window retire — bank-row gather, old-state assembly,
@@ -648,9 +720,7 @@ def tape_apply(bank, wire, table, hll_rows, store_old, *,
     the host swaps in the merged rows). Returns ``(bank, merged [T2, L],
     changed [T2] bool, old_packed [T2, L//8] | None)`` where
     ``old_packed`` holds the PRE-merge bits of every row (big-endian
-    packbits order) for bitset old-bit reads. With ``mesh`` (the mesh
-    data plane's sharded bank) every device merges the replicated window:
-    a Mosaic kernel cannot be partitioned automatically."""
+    packbits order) for bitset old-bit reads."""
     from redisson_tpu.ops import window_kernel as wk
 
     t2 = table.shape[0]
@@ -673,21 +743,12 @@ def tape_apply(bank, wire, table, hll_rows, store_old, *,
     old_packed = None
     if want_old:
         old_packed = bitset.pack(old.reshape(-1)).reshape(t2, lanes // 8)
-    merge = wk.window_merge
-    if mesh is not None:
-        merge = _shard_map(merge, mesh=mesh, in_specs=(_P(),) * 3,
-                           out_specs=(_P(), _P()), check_vma=False)
-    merged, changed = merge(old, wire, table)
+    merged, changed = wk.window_merge(old, wire, table)
     if n_hll:
         h2 = hll_rows.shape[0]
         sel = jnp.where(jnp.arange(h2) < n_hll, jnp.arange(h2), 0)
         regs = merged[sel][:, :m]
-        s_cap = bank.shape[0]
-        flat = bank.reshape(-1)
-        idx = (hll_rows[:, None] * m
-               + jnp.arange(m, dtype=hll_rows.dtype)[None, :])
-        bank = flat.at[idx.reshape(-1)].set(
-            regs.astype(jnp.int32).reshape(-1)).reshape(s_cap, m)
+        bank = bank.at[hll_rows].set(regs.astype(jnp.int32))
     return bank, merged, changed, old_packed
 
 

@@ -7,14 +7,11 @@ plane of a pipeline window — mixed ``hll_add`` / ``bloom_add`` /
 ``bitset_set``, many targets — and lays them out as one flat command
 tape the ``ops/window_kernel`` megakernel consumes in a single launch:
 
-* ``table`` int32 ``[T2, 5]``: ``(op_code, target_row, offset, length,
-  shard)`` per arena row. ``target_row`` is the HLL bank row for HLL
+* ``table`` int32 ``[T2, 4]``: ``(op_code, target_row, offset,
+  length)`` per arena row. ``target_row`` is the HLL bank row for HLL
   entries (-1 for store-backed entries — the host keeps the row ->
   object map); ``offset`` is the row's byte offset into the flattened
-  wire buffer; ``length`` the valid cell count; ``shard`` the logical
-  cluster shard the entry belongs to (0 outside the mesh data plane) —
-  the shard axis that lets ONE launch retire a multi-shard window while
-  per-shard attribution survives into the tape.
+  wire buffer; ``length`` the valid cell count.
 * ``wire`` uint8 ``[T2, W]``: one operand segment per row — dense
   register bytes for HLL entries, packed big-endian bits for bloom /
   bitset. Sparse planes are re-materialized into their segment here
@@ -30,13 +27,13 @@ rows (length 0 merges as a zero delta under max).
 from __future__ import annotations
 
 from dataclasses import dataclass, field
-from typing import Callable, List, Optional
+from typing import Callable, List
 
 import numpy as np
 
 from redisson_tpu.ingest.delta import DeltaPlane
 from redisson_tpu.ops.window_kernel import (
-    COL_SHARD, OP_BITSET, OP_BLOOM, OP_HLL, OP_PAD, TABLE_COLS)
+    OP_BITSET, OP_BLOOM, OP_HLL, OP_PAD, TABLE_COLS)
 
 _OP_OF = {"hll_add": OP_HLL, "bloom_add": OP_BLOOM, "bitset_set": OP_BITSET}
 
@@ -69,14 +66,6 @@ class WindowTape:
     def n_entries(self) -> int:
         return len(self.planes)
 
-    @property
-    def n_shards(self) -> int:
-        """Distinct logical shards this window retires (>= 1)."""
-        if not len(self.planes):
-            return 1
-        return len(set(
-            int(self.table[i, COL_SHARD]) for i in range(len(self.planes))))
-
 
 def _wire_row(p: DeltaPlane) -> np.ndarray:
     """A plane's operand segment: the folded byte plane, re-densified
@@ -92,16 +81,12 @@ def _wire_row(p: DeltaPlane) -> np.ndarray:
 
 
 def encode_window(planes: List[DeltaPlane],
-                  hll_row: Callable[[str], int],
-                  shard_of: Optional[Callable[[str], int]] = None
-                  ) -> WindowTape:
+                  hll_row: Callable[[str], int]) -> WindowTape:
     """Encode a window's folded planes into one command tape.
 
     ``hll_row`` maps an hll_add target name to its bank row (the caller
-    owns target->row placement); ``shard_of`` maps a target name to its
-    logical cluster shard for the tape's shard column (mesh data plane —
-    None stamps shard 0 everywhere). Raises ValueError on a kind the
-    tape has no op code for — eligibility is the caller's job.
+    owns target->row placement). Raises ValueError on a kind the tape has
+    no op code for — eligibility is the caller's job.
     """
     ordered = ([p for p in planes if p.kind == "hll_add"]
                + [p for p in planes if p.kind != "hll_add"])
@@ -126,8 +111,7 @@ def encode_window(planes: List[DeltaPlane],
         row = hll_row(p.target) if op == OP_HLL else -1
         if op == OP_HLL:
             rows[i] = row
-        shard = int(shard_of(p.target)) if shard_of is not None else 0
-        table[i] = (op, row, i * width, p.cells, shard)
+        table[i] = (op, row, i * width, p.cells)
     for i, p in enumerate(ordered):
         wire[i, : p.plane_bytes] = _wire_row(p)
     return WindowTape(

@@ -13,17 +13,13 @@ kernel consumes the whole tape in a single grid-iterated launch.
 Tape layout (one arena row per folded delta plane):
 
 * ``table`` — int32 ``[T, TABLE_COLS]`` rows ``(op_code, target_row,
-  offset, length, shard)``.  ``op_code`` selects the merge semantics per
+  offset, length)``.  ``op_code`` selects the merge semantics per
   entry (``OP_HLL``: register max-merge on dense uint8 registers;
   ``OP_BLOOM`` / ``OP_BITSET``: bit-OR on a packed big-endian bit plane);
   ``target_row`` is the HLL bank row (-1 for store-backed rows — the host
   keeps the arena-row -> store-object map); ``offset`` is the entry's
   byte offset into the flat wire buffer; ``length`` is its valid cell
-  count; ``shard`` is the logical cluster shard the entry belongs to
-  (column ``COL_SHARD`` — the tape's shard axis: a mesh data-plane
-  window mixes entries from many logical shards and still retires in
-  ONE launch; the kernel itself merges by ``op_code``/``length`` only,
-  the shard column carries attribution through the fused dispatch).
+  count.
 * ``wire`` — uint8 ``[T, W]`` operand buffer, one row per entry: dense
   register bytes for HLL rows, packed bits for bloom/bitset rows.
 * ``old`` — uint8 ``[T, L]`` the matching current-state rows
@@ -57,12 +53,9 @@ OP_HLL = 1      # dense uint8 register plane, elementwise max
 OP_BLOOM = 2    # packed big-endian bit plane, bit-OR
 OP_BITSET = 3   # packed big-endian bit plane, bit-OR (old bits read back)
 
-# Table geometry: (op_code, target_row, offset, length, shard). The shard
-# column rides along for multi-shard windows (mesh data plane); both the
-# Pallas kernel and the lax fallback read only op_code and length, so the
-# merge function is invariant to it by construction.
-TABLE_COLS = 5
-COL_SHARD = 4
+# Table geometry: (op_code, target_row, offset, length). Both the Pallas
+# kernel and the lax fallback read only op_code and length.
+TABLE_COLS = 4
 
 #: op codes whose wire segment is already in the cell domain (one byte
 #: per cell); everything else is a packed bit plane the kernel unpacks.

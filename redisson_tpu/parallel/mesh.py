@@ -22,6 +22,7 @@ import threading
 from typing import Dict, Optional, Tuple
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -138,9 +139,11 @@ class ShardedBank:
             capacity += ndev - capacity % ndev
         return capacity
 
-    def place(self, bank):
-        """Commit a bank array onto the mesh with slot-range sharding."""
-        return jax.device_put(bank, self.sharding)
+    def make(self, m: int):
+        """A zeroed [capacity, m] int32 bank, created sharded: each device
+        allocates only its own rows (engine.hll_bank_grow_mesh grows it
+        the same way)."""
+        return jnp.zeros((self.capacity, m), jnp.int32, device=self.sharding)
 
     def replicate(self, arr):
         """Commit an operand (wire/table/rows) replicated across the mesh

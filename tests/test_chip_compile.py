@@ -1,5 +1,8 @@
 """Ahead-of-time compiles of every Pallas kernel the chip path reaches, for
-a described TPU v5e, at the widths the served path uses.
+a described TPU v5e, at the widths the served path uses; and of the mesh
+data plane's bank programs on a described four-chip v5e at the served
+bank size (262,144 rows, 4 GiB a chip), whose per-chip memory and
+collectives the compiler reports.
 
 Nothing runs on a chip here: each test lowers and compiles one kernel for
 a `v5e:2x2` topology that is described, not attached, and checks that
@@ -22,10 +25,9 @@ import jax.numpy as jnp
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache as cc
-    from jax.sharding import SingleDeviceSharding
 
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     try:
@@ -38,9 +40,27 @@ def one_chip():
     prev = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", prev)
     cc.reset_cache()
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    from jax.sharding import SingleDeviceSharding
+
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def mesh4(topo):
+    """The cluster mesh data plane's four-chip slot mesh."""
+    import numpy as np
+    from jax.sharding import Mesh
+
+    from redisson_tpu.parallel.mesh import SLOT_AXIS
+
+    return Mesh(np.array(topo.devices), (SLOT_AXIS,))
 
 
 def _compile(one_chip, fn, *shapes):
@@ -112,3 +132,65 @@ def test_bits_insert_segmented(one_chip):
                                              interpret=False),
              ((1 << 28,), jnp.uint8), (((1 << 20) + 1024,), jnp.int32),
              (((1 << 28) // tile + 1,), jnp.int32))
+
+
+# The mesh data plane's bank programs at the served size: a 262,144-row
+# bank, 4 GiB a chip. No chip ever holds more than its quarter of the
+# bank plus one doubling, and no program gathers the bank.
+
+GIB = 1 << 30
+BANK_ROWS = 262144
+
+
+def _mesh_compile(mesh4, fn, *shapes, **static):
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from redisson_tpu.parallel.mesh import SLOT_AXIS
+
+    shards = {"bank": NamedSharding(mesh4, P(SLOT_AXIS, None)),
+              "rep": NamedSharding(mesh4, P())}
+    args = [jax.ShapeDtypeStruct(s, d, sharding=shards[k])
+            for s, d, k in shapes]
+    compiled = fn.lower(*args, **static).compile()
+    text = compiled.as_text()
+    assert "all-gather" not in text and "all-to-all" not in text
+    return compiled.memory_analysis(), text
+
+
+def test_mesh_bank_add_at_served_size(mesh4):
+    from redisson_tpu import engine
+
+    n, r = 1024, 256
+    mem, text = _mesh_compile(
+        mesh4, engine.hll_bank_add_bytes_rows,
+        ((BANK_ROWS, 16384), jnp.int32, "bank"), ((n, 8), jnp.uint8, "rep"),
+        ((n,), jnp.int32, "rep"), ((n,), jnp.int32, "rep"),
+        ((r,), jnp.int32, "rep"), ((n,), jnp.bool_, "rep"), mesh=mesh4)
+    # the chip's quarter of the bank is updated in place
+    assert mem.alias_size_in_bytes == 4 * GIB
+    assert mem.temp_size_in_bytes < 64 << 20
+    assert "all-reduce" in text  # the one pmax of the changed flags
+
+
+def test_mesh_bank_grow_to_served_size(mesh4):
+    from redisson_tpu import engine
+
+    mem, text = _mesh_compile(
+        mesh4, engine.hll_bank_grow_mesh,
+        ((BANK_ROWS // 2, 16384), jnp.int32, "bank"), new_cap=BANK_ROWS,
+        mesh=mesh4)
+    assert mem.argument_size_in_bytes == 2 * GIB
+    assert mem.output_size_in_bytes == 4 * GIB
+    assert mem.temp_size_in_bytes < 128 << 20  # one 64 MiB piece in flight
+    assert "collective-permute" in text
+
+
+def test_mesh_union_count_at_served_size(mesh4):
+    from redisson_tpu import engine
+
+    count = engine._mesh_collectives(mesh4)["count"]
+    mem, text = _mesh_compile(
+        mesh4, count, ((BANK_ROWS, 16384), jnp.int32, "bank"),
+        ((8,), jnp.int32, "rep"))
+    assert mem.temp_size_in_bytes < 16 << 20
+    assert "all-reduce" in text

@@ -53,7 +53,7 @@ def test_collective_merge_matches_host_fold_oracle():
     mesh, sb, host = _mesh_bank()
     rows = [3, 17, 33, 60, 9]  # span several device blocks; includes target
     target = 9
-    bank = sb.place(jnp.asarray(host))
+    bank = jax.device_put(host, sb.sharding)
     got = np.asarray(engine.hll_bank_merge_rows_collective(
         bank, jnp.asarray(rows, jnp.int32), jnp.int32(target), mesh=mesh))
     oracle = host.copy()
@@ -65,7 +65,7 @@ def test_collective_merge_count_matches_stacks_kernel():
     mesh, sb, host = _mesh_bank(seed=5)
     rows = [0, 21, 42, 63, 11]
     target = 11
-    bank = sb.place(jnp.asarray(host))
+    bank = jax.device_put(host, sb.sharding)
     new_bank, cnt = engine.hll_bank_merge_count_rows_collective(
         bank, jnp.asarray(rows, jnp.int32), jnp.int32(target), mesh=mesh)
     # Stacks oracle: the same merge+count through the single-device kernel.
@@ -80,7 +80,7 @@ def test_collective_merge_count_matches_stacks_kernel():
 def test_collective_count_and_occupancy_match_oracles():
     mesh, sb, host = _mesh_bank(seed=7)
     rows = [1, 30, 55]
-    bank = sb.place(jnp.asarray(host))
+    bank = jax.device_put(host, sb.sharding)
     cnt = int(engine.hll_bank_count_rows_collective(
         bank, jnp.asarray(rows, jnp.int32), mesh=mesh))
     dev = jax.devices("cpu")[0]
@@ -93,7 +93,7 @@ def test_collective_count_and_occupancy_match_oracles():
     host2[5] = 0
     host2[40] = 0
     occ = int(engine.hll_bank_occupancy_collective(
-        sb.place(jnp.asarray(host2)), mesh=mesh))
+        jax.device_put(host2, sb.sharding), mesh=mesh))
     assert occ == int(np.sum(np.any(host2 != 0, axis=1)))
 
 

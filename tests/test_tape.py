@@ -82,7 +82,7 @@ def _random_tape(rng, t2, lanes):
             length = 0
         else:
             length = int(rng.integers(1, lanes + 1))
-        table[t] = (op, -1, 0, length, 0)
+        table[t] = (op, -1, 0, length)
         if op == wk.OP_HLL:
             old[t] = rng.integers(0, 65, lanes, np.uint8)
             wire[t, :length] = rng.integers(0, 65, length, np.uint8)

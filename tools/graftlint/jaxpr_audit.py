@@ -162,13 +162,12 @@ def build_registry():
     stack = jnp.zeros((3, 2048), jnp.uint8)
     bank = jnp.zeros((100, 128), jnp.int32)
     # one tape row per op kind (hll / bloom / bitset) plus a pad row, so
-    # the audit traces every switch arm of the window megakernel; the
-    # fifth column is the tape's shard axis (wk.COL_SHARD, mesh plane)
+    # the audit traces every switch arm of the window megakernel
     tape_old = jnp.zeros((4, 256), jnp.uint8)
     tape_wire = jnp.zeros((4, 256), jnp.uint8)
     tape_tab = jnp.asarray(
-        [[wk.OP_HLL, 0, 0, 256, 0], [wk.OP_BLOOM, 1, 256, 256, 1],
-         [wk.OP_BITSET, 2, 512, 256, 0], [wk.OP_PAD, 0, 0, 0, 0]],
+        [[wk.OP_HLL, 0, 0, 256], [wk.OP_BLOOM, 1, 256, 256],
+         [wk.OP_BITSET, 2, 512, 256], [wk.OP_PAD, 0, 0, 0]],
         jnp.int32)
     pred = jnp.zeros((8,), bool)
 
@@ -312,6 +311,18 @@ def build_registry():
                    jnp.zeros((4,), jnp.int32))), {}),
         ("engine.hll_bank_occupancy_collective",
          lambda: (pc(eng.hll_bank_occupancy_collective,
+                     mesh=get_mesh(1, SLOT_AXIS)),
+                  (jnp.zeros((8, hll.M), jnp.int32),)), {}),
+        ("engine.hll_bank_add_bytes_rows[mesh]",
+         lambda: (pc(eng.hll_bank_add_bytes_rows,
+                     mesh=get_mesh(1, SLOT_AXIS)),
+                  (jnp.zeros((8, hll.M), jnp.int32),
+                   jnp.zeros((16, 8), jnp.uint8),
+                   jnp.full((16,), 8, jnp.int32),
+                   jnp.zeros((16,), jnp.int32), jnp.zeros((4,), jnp.int32),
+                   jnp.ones((16,), bool))), {}),
+        ("engine.hll_bank_grow_mesh",
+         lambda: (pc(eng.hll_bank_grow_mesh, new_cap=16,
                      mesh=get_mesh(1, SLOT_AXIS)),
                   (jnp.zeros((8, hll.M), jnp.int32),)), {}),
     ]
