@@ -2465,21 +2465,16 @@ class TpuBackend:
                 op.future.set_result(None)
                 continue
             obj = self._bitset(target, nbits=1024)
-            arrays = [obj.state] + arrays
-            width = max(a.shape[0] for a in arrays)
-            padded = []
-            for a in arrays:
-                if a.shape[0] < width:
-                    a = jnp.zeros((width,), jnp.uint8).at[: a.shape[0]].set(a)
-                padded.append(a)
+            arrays = (obj.state, *arrays)
             # No existing sources: BITOP with only the destination leaves it
             # unchanged (never wipe the destination).
-            if len(padded) == 1:
-                acc = padded[0]
+            if len(arrays) == 1:
+                acc = obj.state
             else:
-                with stage("backend.launch", sources=len(padded)):
-                    acc = engine.bitset_bitop(jnp.stack(padded), kind)
-            obj.meta["nbits"] = width
+                # One program pads and stacks the operands and folds them.
+                with stage("backend.launch", sources=len(arrays)):
+                    acc = engine.bitset_bitop(arrays, kind)
+            obj.meta["nbits"] = acc.shape[0]
             # BITOP dest width = max of the operands' written extents
             # (redis: STRLEN of the result equals the widest source). A
             # fresh dest defaults to 0 — its pow2 allocation must not leak

@@ -795,15 +795,26 @@ def bitset_cardinality(bits) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("op",))
-def bitset_bitop(stack, op: str):
-    """BITOP AND|OR|XOR over [K, n] stacked operands -> [n]."""
+def bitset_bitop(sources, op: str):
+    """BITOP AND|OR|XOR over a tuple of 1-D uint8 cell operands of any
+    widths -> [max width]; a shorter operand reads as zeros past its end
+    (redis pads the shorter strings). Each combination of widths is its
+    own program.
+
+    On TPU the kernel's [K, width] stack is built here, one select per
+    row, which the compiler fuses into a single pass writing each operand
+    once. A stack of `[None]` rows would first relayout each operand into
+    a 4x-padded [1, width] buffer of its own."""
+    width = max(s.shape[0] for s in sources)
+    padded = [jnp.pad(s, (0, width - s.shape[0])) for s in sources]
     if pk.use_pallas():
+        rows = jax.lax.broadcasted_iota(jnp.int32, (len(padded), width), 0)
+        stack = jnp.zeros((len(padded), width), jnp.uint8)
+        for i, s in enumerate(padded):
+            stack = jnp.where(rows == i, s[None, :], stack)
         return pk.bitop_cells(stack, op)
     fn = {"and": jnp.bitwise_and, "or": jnp.bitwise_or, "xor": jnp.bitwise_xor}[op]
-    acc = stack[0]
-    for k in range(1, stack.shape[0]):
-        acc = fn(acc, stack[k])
-    return acc
+    return functools.reduce(fn, padded)
 
 
 @jax.jit

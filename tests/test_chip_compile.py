@@ -23,6 +23,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
+GIB = 1 << 30
+
 
 @pytest.fixture(scope="module")
 def topo():
@@ -83,6 +85,27 @@ def test_bitop_cells(one_chip):
              ((4, 1 << 26), jnp.uint8))
 
 
+def test_bitset_bitop_at_served_shape(one_chip, monkeypatch):
+    """BITOP OR of 7 daily 2^27-cell bitmaps into a fresh 1,024-cell key,
+    as one program: the select that builds the [8, 2^27] stack writes
+    each operand once, with no padded [1, 2^27] relayout of its own."""
+    from redisson_tpu import engine
+    from redisson_tpu.ops import pallas_kernels as pk
+
+    monkeypatch.setattr(pk, "use_pallas", lambda: True)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    n = 1 << 27
+    srcs = tuple(jax.ShapeDtypeStruct((w,), jnp.uint8, sharding=one_chip)
+                 for w in (1024,) + (n,) * 7)
+    fn = jax.jit(engine.bitset_bitop.__wrapped__, static_argnames=("op",))
+    compiled = fn.lower(srcs, "or").compile()
+    text = compiled.as_text()
+    assert "tpu_custom_call" in text
+    # the 1 GiB [8, 2^27] stack and little else
+    assert compiled.memory_analysis().temp_size_in_bytes <= 1.25 * GIB
+    assert f"[1,{n}]" not in text and f"[1,1,{n}]" not in text
+
+
 @pytest.mark.parametrize("t,l", [(64, 16384), (2, 1024)])
 def test_delta_merge(one_chip, t, l):
     from redisson_tpu.ops import pallas_kernels as pk
@@ -138,7 +161,6 @@ def test_bits_insert_segmented(one_chip):
 # bank, 4 GiB a chip. No chip ever holds more than its quarter of the
 # bank plus one doubling, and no program gathers the bank.
 
-GIB = 1 << 30
 BANK_ROWS = 262144
 
 

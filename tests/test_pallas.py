@@ -1,6 +1,9 @@
 """Pallas kernels vs their XLA/numpy references (interpret mode on CPU)."""
 
+import functools
+
 import numpy as np
+import jax
 import jax.numpy as jnp
 import pytest
 
@@ -86,8 +89,73 @@ class TestEngineWiring:
         want = np.max(np.stack([np.asarray(a) for a in arrays]), axis=0)
         np.testing.assert_array_equal(got, want)
 
-    def test_bitset_bitop(self):
+    # (op, operand widths): the first operand is BITOP's destination. No
+    # width is a multiple of the kernel's block (2^18 cells).
+    @pytest.mark.parametrize("pallas", [False, True], ids=["lax", "kernel"])
+    @pytest.mark.parametrize("op,widths", [
+        ("or", (300, 300, 300)),
+        ("and", (1024, 5000, 5000, 4000)),   # a fresh destination, shorter
+        ("or", (1024,) + (7001,) * 7),       # the served shape, scaled down
+        ("xor", (8192, 3000, 7001)),         # a destination wider than all
+        ("and", (7001,)),                    # a single source
+    ])
+    def test_bitset_bitop(self, monkeypatch, pallas, op, widths):
         rng = np.random.default_rng(8)
-        stack = (rng.random((3, 300)) < 0.5).astype(np.uint8)
-        got = np.asarray(engine.bitset_bitop(jnp.asarray(stack), "or"))
-        np.testing.assert_array_equal(got, stack[0] | stack[1] | stack[2])
+        srcs = [(rng.random(w) < 0.5).astype(np.uint8) for w in widths]
+        # The kernel path, in interpret mode, under a program of its own:
+        # the engine's jit cache holds the lax fold for the same shapes.
+        fn = engine.bitset_bitop
+        if pallas:
+            monkeypatch.setattr(pk, "use_pallas", lambda: True)
+            fn = jax.jit(fn.__wrapped__, static_argnames=("op",))
+        got = np.asarray(fn(tuple(jnp.asarray(s) for s in srcs), op))
+        width = max(widths)
+        want = functools.reduce(
+            {"and": np.bitwise_and, "or": np.bitwise_or,
+             "xor": np.bitwise_xor}[op],
+            [np.pad(s, (0, width - s.size)) for s in srcs])
+        np.testing.assert_array_equal(got, want)
+
+
+def test_bitop_of_seven_days_into_a_fresh_key_is_one_program(monkeypatch):
+    """The served read: BITOP OR of 7 daily bitmaps into a fresh key. The
+    backend hands the stored states themselves to one `bitset_bitop`
+    call, so no pad or stack runs outside that program, and the result
+    is stored as it comes."""
+    from perfbench.ref import bits
+    from redisson_tpu.client import RedissonTPU
+    from redisson_tpu.config import Config
+    from redisson_tpu.store import ObjectType
+
+    calls = []
+    real = engine.bitset_bitop
+
+    def spy(sources, op):
+        out = real(sources, op)
+        calls.append((sources, op, out))
+        return out
+
+    monkeypatch.setattr(engine, "bitset_bitop", spy)
+    rng = np.random.default_rng(9)
+    c = RedissonTPU.create(Config())
+    try:
+        store = c._routing.sketch.store
+        days, ref = [f"d{i}" for i in range(7)], []
+        for i, day in enumerate(days):
+            top = 1500 << (i % 3)  # bitmaps 2048, 4096 and 8192 cells wide
+            idx = rng.choice(top, size=top // 10, replace=False)
+            c.get_bit_set(day).set_bits(idx)
+            ref.append(bits.set_bits(bits.make(int(idx.max()) + 1), idx))
+        wau = c.get_bit_set("wau")
+        wau.or_(*days)
+        assert len(calls) == 1
+        sources, op, out = calls[0]
+        assert op == "or" and sources[0].shape == (1024,)
+        for src, day in zip(sources[1:], days):
+            assert src is store.get(day, ObjectType.BITSET).state
+        assert store.get("wau", ObjectType.BITSET).state is out
+        want = bits.bitop("OR", *ref)
+        assert wau.cardinality() == bits.bitcount(want)
+        assert wau.size() == 8 * want.size
+    finally:
+        c.shutdown()
