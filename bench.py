@@ -21,9 +21,8 @@ batch, a figure from before this round's chip runs; not measured since);
 the batch through jnp.sort (bitonic on TPU) and lands ~2x slower; 'segment'
 is the Pallas segmented-scatter (sort + VMEM-tiled segment-max,
 redisson_tpu/ingest/kernels.py). Which path a production batch takes is
-decided per batch size by the measured cost table in
-redisson_tpu/ingest/planner.py — the ingest[auto] report below prints the
-planner's pick for this bench's batch size.
+decided per batch size by the link rule (backend_tpu.ingest_rule) — the
+ingest[auto] report below prints its pick for this bench's batch size.
 
 `--quick` shrinks every section to smoke-test size (2^14-key batches, tiny
 roofline buffers) so the CPU run finishes in seconds — the test suite runs
@@ -111,39 +110,24 @@ INGEST_CHOICE = {}
 
 
 def _report_ingest_choice(n):
-    """Print (and record for the JSON line) which ingest path the planner
-    picks for this bench's batch size — the SAME inputs TpuBackend's
-    _plan_ingest feeds it (measured device-kernel cost table, 8 B/key link
-    overhead on device paths, a hostfold candidate priced from the link
-    profile), so the recorded path is the one the measured batches
-    actually took."""
+    """Print (and record for the JSON line) which ingest path the backend's
+    link rule (backend_tpu.ingest_rule) takes under "auto" for this
+    bench's batch size, and the measured link and fold rates it reads."""
     try:
         import jax
 
-        from redisson_tpu import backend_tpu, native
-        from redisson_tpu.ingest.planner import default_planner
+        from redisson_tpu import backend_tpu
 
         dev = jax.devices()[0]
         prof = backend_tpu.link_profile(dev)
-        extra = None
-        overhead = 0.0
-        if native.available() and n >= backend_tpu.HOSTFOLD_MIN_KEYS:
-            overhead = prof.transfer_ns_per_byte * 8
-            extra = {"hostfold": prof.fold_ns_per_key
-                     + prof.transfer_ns_per_byte * 16384 / max(n, 1)}
-        plan = default_planner().plan(
-            "hll", n, extra_costs=extra, device_overhead=overhead)
         INGEST_CHOICE.update(
-            path=plan.path,
-            costs_ns_per_key={k: round(v, 2) for k, v in plan.costs.items()},
+            path=backend_tpu.ingest_rule("auto", n, dev),
+            fold_min_keys=backend_tpu.HOSTFOLD_MIN_KEYS,
             transfer_mb_per_s=round(1e3 / prof.transfer_ns_per_byte, 1),
             fold_mkeys_per_s=round(1e3 / prof.fold_ns_per_key, 1),
         )
-        costs = ", ".join(
-            f"{k} {v}" for k, v in INGEST_CHOICE["costs_ns_per_key"].items())
         print(
-            f"# ingest[auto] -> {INGEST_CHOICE['path']} "
-            f"(ns/key: {costs}): link "
+            f"# ingest[auto] -> {INGEST_CHOICE['path']} at {n} keys: link "
             f"{INGEST_CHOICE['transfer_mb_per_s']} MB/s, native fold "
             f"{INGEST_CHOICE['fold_mkeys_per_s']} M keys/s",
             file=sys.stderr,
@@ -352,79 +336,6 @@ def bench_delta_ingest(n, reps):
         return out
     finally:
         client.shutdown()
-
-
-def bench_tape_window(n, reps):
-    """Window megakernel vs chunked delta: per-window DISPATCH cost.
-
-    The roofline section pins the ingest ceiling at scatter-ISSUE —
-    per-launch overhead, not HBM bandwidth. The tape path attacks that
-    term directly: the whole mixed hll+bloom+bitset window is encoded
-    into one command tape and retired by ONE fused launch. This bench
-    runs the same mixed-window burst under ingest="delta" (gather +
-    per-plane decode + merge + writeback launch train) and
-    ingest="tape", and reports the OBSERVED `launches_per_window` /
-    `launch_us_per_window` for both (acceptance: tape == 1 launch and
-    >= 4x lower issue time per window)."""
-    from redisson_tpu.client import RedissonTPU
-    from redisson_tpu.config import Config, TpuConfig
-
-    def run(ingest):
-        client = RedissonTPU.create(Config(tpu=TpuConfig(ingest=ingest)))
-        try:
-            sketch = client._routing.sketch
-            h = client.get_hyper_log_log("bench:tape:hll")
-            f = client.get_bloom_filter("bench:tape:bloom")
-            f.try_init(expected_insertions=200_000, false_probability=0.01)
-            bs = client.get_bit_set("bench:tape:bits")
-            rng = np.random.default_rng(17)
-
-            def burst():
-                futs = [
-                    h.add_ints_async(
-                        rng.integers(0, 2**63, n, dtype=np.uint64)),
-                    f.add_ints_async(rng.integers(
-                        0, 2**62, max(n // 4, 1), dtype=np.uint64)),
-                    bs.set_bits_async(
-                        rng.integers(0, 1 << 20, 256, dtype=np.int64)),
-                ]
-                for fu in futs:
-                    fu.result(timeout=120)
-
-            burst()  # warmup: compile every shape the window will see
-            s0 = sketch.ingest_stats()
-            t0 = time.perf_counter()
-            for _ in range(max(reps - 1, 1)):
-                burst()
-            dt = time.perf_counter() - t0
-            s1 = sketch.ingest_stats()
-            windows = (s1["delta_runs"] + s1["tape_runs"]
-                       - s0["delta_runs"] - s0["tape_runs"])
-            launches = s1["window_launches"] - s0["window_launches"]
-            us = s1["launch_us"] - s0["launch_us"]
-            return {
-                "launches_per_window": round(launches / max(windows, 1), 2),
-                "launch_us_per_window": round(us / max(windows, 1), 1),
-                "windows": windows,
-                "inserts_per_sec": round(max(reps - 1, 1) * n / dt, 1),
-            }
-        finally:
-            client.shutdown()
-
-    delta = run("delta")
-    tape = run("tape")
-    speedup = (delta["launch_us_per_window"]
-               / max(tape["launch_us_per_window"], 1e-9))
-    print(
-        f"# tape window: {tape['launches_per_window']} launches/window "
-        f"@ {tape['launch_us_per_window']} us (delta: "
-        f"{delta['launches_per_window']} @ "
-        f"{delta['launch_us_per_window']} us) -> "
-        f"{speedup:.1f}x lower issue cost",
-        file=sys.stderr,
-    )
-    return {"delta": delta, "tape": tape,
-            "launch_us_speedup": round(speedup, 2)}
 
 
 def bench_roofline(jax, dev, n, kernel_rate, segment_rate=0.0, quick=False):
@@ -1459,17 +1370,6 @@ def main():
     except Exception as exc:  # noqa: BLE001
         print(f"# delta ingest bench failed: {exc!r}", file=sys.stderr)
     try:
-        from redisson_tpu import native as _native
-
-        if _native.available():
-            result["tape_window"] = bench_tape_window(
-                1 << 12 if quick else 1 << 16, 3 if quick else 12)
-        else:
-            print("# tape window bench skipped: native lib unavailable",
-                  file=sys.stderr)
-    except Exception as exc:  # noqa: BLE001
-        print(f"# tape window bench failed: {exc!r}", file=sys.stderr)
-    try:
         result["hll_count_cached"] = bench_read_cache(
             1 << 12 if quick else 1 << 18, reps=5 if quick else 20)
     except Exception as exc:  # noqa: BLE001
@@ -1533,16 +1433,6 @@ def main():
         result["memstat"] = mem
     except Exception as exc:  # noqa: BLE001
         print(f"# memstat bench failed: {exc!r}", file=sys.stderr)
-    try:
-        from redisson_tpu.ingest.planner import default_planner
-
-        table = default_planner().table()
-        if table:
-            result["ingest_cost_table_ns_per_key"] = {
-                k: {p: round(v, 2) for p, v in costs.items()}
-                for k, costs in table.items()}
-    except Exception as exc:  # noqa: BLE001
-        print(f"# planner table dump failed: {exc!r}", file=sys.stderr)
     # HEADLINE = the chip: device-resident client-path ingest (VERDICT r3
     # weak #2 — the hostfold rate conflates host silicon with the TPU; it
     # stays reported as the link-starved adaptive path). Fallbacks keep a

@@ -35,8 +35,8 @@ sys.path.insert(0, REPO)
 
 _TINY = bool(os.environ.get("RTPU_BENCH_TINY"))
 
-# Ingest path for the sketch backends ("auto" = the measured planner,
-# redisson_tpu/ingest/planner.py); set once from --ingest in main().
+# Ingest path for the sketch backends ("auto" = the link rule,
+# backend_tpu.ingest_rule); set once from --ingest in main().
 _INGEST = "auto"
 
 
@@ -904,97 +904,6 @@ def delta_smoke():
             ok = False
     finally:
         _close(c)
-    return ok
-
-
-def tape_smoke():
-    """Window-megakernel acceptance smoke (the CPU-only CI contract for
-    the tape tentpole):
-
-      1. a mixed hll/bloom/bitset window run with ingest="tape" must
-         retire in EXACTLY one fused launch per window
-         (launches_per_window == 1.0, every window a tape run);
-      2. the full workload's engine digest and per-op results must be
-         bit-identical to ingest="scatter" (serial device scatter);
-      3. --pipeline-smoke's serial-identity contract must still hold —
-         re-run it here so the tape PR cannot green while regressing the
-         pipeline (the tape window handoff threads through the same
-         executor seam).
-    """
-    from redisson_tpu import native as native_mod
-
-    if not native_mod.available():
-        print("# tape-smoke: native library unavailable; SKIP",
-              file=sys.stderr)
-        return True
-    from redisson_tpu.client import RedissonTPU
-    from redisson_tpu.config import Config, TpuConfig
-
-    n = 1 << (13 if _TINY else 16)
-    rng = np.random.default_rng(23)
-    hll_batches = [rng.integers(0, 2**63, n, np.uint64) for _ in range(3)]
-    bloom_batches = [rng.integers(0, 2**63, 1 << 12, np.uint64)
-                     for _ in range(2)]
-    bloom_batches.append(bloom_batches[0])  # re-adds: try_add must say False
-    bits_batches = [rng.integers(0, 1 << 16, 1 << 11, np.int64)
-                    for _ in range(2)]
-    bits_batches.append(bits_batches[0])  # re-sets: old bits must say True
-
-    def play(ingest):
-        c = RedissonTPU.create(Config(tpu=TpuConfig(ingest=ingest)))
-        try:
-            results = []
-            hs = [c.get_hyper_log_log(f"ts:h{i}") for i in range(2)]
-            bf = c.get_bloom_filter("ts:bloom")
-            bf.try_init(expected_insertions=100_000, false_probability=0.01)
-            bs = c.get_bit_set("ts:bits")
-            # Mixed async bursts: each burst stacks all three kinds into
-            # one pipeline window (the tape arena), then serial re-adds
-            # pin the per-op result contract exactly.
-            for i in range(3):
-                futs = [
-                    hs[i % 2].add_ints_async(hll_batches[i]),
-                    bf.add_ints_async(bloom_batches[i]),
-                    bs.set_bits_async(bits_batches[i]),
-                ]
-                results.extend(np.asarray(f.result(timeout=120)).tolist()
-                               for f in futs)
-            be = c._routing.sketch
-            be._bloom_device_sync("ts:bloom")  # host-mirror path parity
-            stats = be.ingest_stats()
-            digest = _engine_digest(c)
-            return results, digest, stats
-        finally:
-            _close(c)
-
-    ok = True
-    res_t, dig_t, stats_t = play("tape")
-    res_s, dig_s, _ = play("scatter")
-
-    windows = stats_t["delta_runs"] + stats_t["tape_runs"]
-    lpw = stats_t["launches_per_window"]
-    print(f"# tape-smoke: {stats_t['tape_runs']} tape runs / "
-          f"{windows} windows, {lpw:.2f} launches/window "
-          f"({stats_t['launch_us_per_window']:.0f} us/window)")
-    if stats_t["tape_runs"] < 1 or stats_t["delta_runs"] != 0:
-        print("#   not every window retired through the tape",
-              file=sys.stderr)
-        ok = False
-    if lpw != 1.0:
-        print(f"#   launches_per_window {lpw} != 1.0", file=sys.stderr)
-        ok = False
-
-    identical = res_t == res_s and dig_t == dig_s
-    print(f"# tape-smoke: tape vs scatter — results "
-          f"{'identical' if res_t == res_s else 'DIVERGED'}, digest "
-          f"{'bit-identical' if dig_t == dig_s else 'MISMATCH'}")
-    if not identical:
-        ok = False
-
-    print("# tape-smoke: re-running pipeline smoke under the tape PR")
-    if not pipeline_smoke():
-        print("#   pipeline smoke regressed", file=sys.stderr)
-        ok = False
     return ok
 
 
@@ -3299,7 +3208,7 @@ def main():
     ap.add_argument("--ingest", default="auto",
                     choices=("auto", "device", "hostfold",
                              "scatter", "sort", "segment", "delta"),
-                    help="sketch ingest path (auto = measured planner)")
+                    help="sketch ingest path (auto = the link rule)")
     ap.add_argument("--trace-smoke", action="store_true",
                     help="trace acceptance: < 1% wall overhead at default "
                          "sampling vs tracing off, and a fault-injected "
@@ -3320,12 +3229,6 @@ def main():
                     help="delta-ingest acceptance: bit-identical state vs "
                          "scatter, link bytes/key < 1/8 raw at the 1M-key "
                          "batch, fold/merge overlap at window 2, then exit")
-    ap.add_argument("--tape-smoke", action="store_true",
-                    help="window-megakernel acceptance: exactly ONE fused "
-                         "launch per mixed hll/bloom/bitset window "
-                         "(launches_per_window == 1), engine digest + "
-                         "per-op results bit-identical to ingest=scatter, "
-                         "and the pipeline smoke still green, then exit")
     ap.add_argument("--persist-smoke", action="store_true",
                     help="fsync-policy sweep {none,off,everysec,always}: "
                          "journal overhead per policy + kill-and-recover "
@@ -3424,9 +3327,6 @@ def main():
 
     if args.delta_smoke:
         sys.exit(0 if delta_smoke() else 1)
-
-    if args.tape_smoke:
-        sys.exit(0 if tape_smoke() else 1)
 
     if args.persist_smoke:
         sys.exit(0 if persist_smoke() else 1)
@@ -3560,16 +3460,6 @@ def _publish(results, failures, full: bool):
         **_provenance_meta(),
         **({"failed_configs": failures} if failures else {}),
     }
-    try:
-        from redisson_tpu.ingest.planner import default_planner
-
-        table = default_planner().table()
-        if table:
-            doc["published"]["_meta"]["ingest_cost_table_ns_per_key"] = {
-                k: {p: round(v, 2) for p, v in costs.items()}
-                for k, costs in table.items()}
-    except Exception as exc:  # noqa: BLE001 — table dump must not block publish
-        print(f"# planner table dump failed: {exc!r}", file=sys.stderr)
     tmp = path + ".tmp"
     with open(tmp, "w") as f:
         json.dump(doc, f, indent=2)

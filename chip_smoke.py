@@ -144,7 +144,7 @@ def _add_in_parts(obj, keys, parts: int = 4):
 # Phases
 
 #: Ingest values that reach a distinct device path.
-INGESTS = ("auto", "device", "segment", "hostfold", "delta", "tape")
+INGESTS = ("auto", "device", "segment", "hostfold", "delta")
 
 
 def phase_hll(n_keys: int = 1 << 20, ingests=INGESTS, seed: int = 0):
@@ -204,11 +204,11 @@ def phase_merge(n_sketches: int = 256, per_sketch: int = 40_000,
 
 
 def phase_bloom(n_keys: int = 1 << 20, m: int = 1 << 28, k: int = 7,
-                ingests=("auto", "tape"), seed: int = 2):
+                ingests=("auto", "delta"), seed: int = 2):
     """c) Bloom filter of m bits and k hashes (BASELINE config 2): no
     false negatives on inserted keys, a false-positive rate on fresh keys
     within theory plus 5 sigma (and 5 keys), and identical bits under
-    each ingest path (tape decodes packed planes in its kernel)."""
+    each ingest path (delta unpacks its packed planes on the device)."""
     keys = distinct_keys(n_keys, seed)
     fresh = distinct_keys(n_keys, seed, offset=n_keys)
     expected, fpp = bloom_params(m, k)

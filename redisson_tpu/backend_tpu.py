@@ -25,21 +25,19 @@ from redisson_tpu import engine
 from redisson_tpu.executor import Op
 
 # graftlint Tier C guarded-by audit: the backend runs entirely on the
-# executor's dispatcher thread — run(), the allocator grow hook, and the
-# tape-encode callbacks are all invoked from inside backend.run.
+# executor's dispatcher thread — run() and the allocator grow hook are
+# invoked from inside backend.run.
 GUARDED_BY = {
     "TpuBackend.bank":
         "thread:dispatcher-confined — every writer (_ensure_bank, "
-        "_grow_bank via RowAllocator, _hll_row via tape encode) runs "
+        "_grow_bank via RowAllocator, _hll_row) runs "
         "inside backend.run on the dispatcher; checkpoint load replaces "
         "it only through an executor barrier",
 }
 from redisson_tpu.fault import inject as fault_inject
 from redisson_tpu.fault.taxonomy import classify
 from redisson_tpu.ingest import delta as delta_mod
-from redisson_tpu.ingest import tape as tape_mod
 from redisson_tpu.ingest.pipeline import StagingPipeline
-from redisson_tpu.ingest.planner import IngestPlanner, default_planner
 from redisson_tpu.ops import bitset as bitset_ops, bloom as bloom_ops
 from redisson_tpu.ops import bloom_math
 from redisson_tpu.ops import hll as hll_ops
@@ -509,20 +507,74 @@ def link_profile(device) -> LinkProfile:
 HOSTFOLD_MIN_KEYS = 1 << 16
 
 
-def hostfold_policy(ingest: str, nkeys: int, device) -> bool:
+def ingest_rule(ingest: str, nkeys: int, device, *, bits: bool = False,
+                group: bool = False, family: str = "m3",
+                hll_impl: str = "scatter", plane_bytes: int = 0,
+                raw_per_key: int = 8) -> str:
     """THE ingest decision, shared by the backend and any reporter (bench):
-    duplicating these gates drifts."""
-    if ingest == "device":
-        return False
+    duplicating these gates drifts.
+
+    A forced `ingest` value picks its path. Under "auto" a batch of
+    `nkeys` folds on the host only when the native library is loaded,
+    the batch holds at least HOSTFOLD_MIN_KEYS keys and the measured link
+    makes shipping a folded plane cheaper than shipping the raw keys:
+    - an HLL batch (murmur3 `family` only) folds when the native fold
+      beats 8 B/key on the link: 'delta' for a per-target `group`
+      (retired through the fused window merge), 'hostfold' for a classic
+      run; otherwise the device insert `hll_impl`;
+    - a bloom/bitset `group` (`bits`) folds into a 'delta' plane when the
+      fold plus its upload (`plane_bytes`, or its sparse encoding if
+      smaller) beats `raw_per_key` bytes a key; otherwise 'scatter'."""
+    if bits:
+        if ingest == "segment":
+            return "segment"
+        if ingest == "delta":
+            return "delta" if group else "scatter"
+        if ingest != "auto":
+            return "scatter"
+        fallback = "scatter"
+    elif ingest == "auto":
+        fallback = hll_impl
+    elif ingest == "delta":
+        return "delta" if group else hll_impl
+    elif ingest == "device":
+        return hll_impl
+    else:  # hostfold, or a forced device insert kernel
+        return ingest
     from redisson_tpu import native as native_mod
 
-    if not native_mod.available():
-        return False
-    if ingest == "hostfold":
-        return True
-    if nkeys < HOSTFOLD_MIN_KEYS:
-        return False
-    return link_profile(device).prefer_hostfold
+    if (nkeys < HOSTFOLD_MIN_KEYS or family == "redis"
+            or not native_mod.available()):
+        return fallback
+    prof = link_profile(device)
+    if bits:
+        if not (group and plane_bytes):
+            return "scatter"
+        ship = min(plane_bytes, nkeys * delta_mod.SPARSE_ENTRY_BYTES)
+        fold_ns = prof.fold_ns_per_key + prof.transfer_ns_per_byte * ship / nkeys
+        return ("delta" if fold_ns < prof.transfer_ns_per_byte * raw_per_key
+                else "scatter")
+    if not prof.prefer_hostfold:
+        return hll_impl
+    return "delta" if group else "hostfold"
+
+
+class RuleInputs:
+    """The measured inputs of `ingest_rule` for one device, for reports:
+    {} until something probed the link (this never probes it)."""
+
+    def __init__(self, device):
+        self.device = device
+
+    def table(self) -> dict:
+        with _LINK_LOCK:
+            prof = _LINK_PROFILES.get(self.device)
+        if prof is None:
+            return {}
+        fold = prof.fold_ns_per_key
+        return {"transfer_ns_per_byte": prof.transfer_ns_per_byte,
+                "fold_ns_per_key": fold if fold != float("inf") else None,
+                "fold_min_keys": HOSTFOLD_MIN_KEYS}
 
 
 class TpuBackend:
@@ -560,20 +612,13 @@ class TpuBackend:
     #: power-of-two m — models fail bloom sizing fast when this tier backs them
     BLOOM_STRICT_MOD = True
 
-    #: accepted `ingest` config values — "auto" plans per batch; "device"
-    #: forces the device path with the configured hll_impl; the kernel
-    #: names force that device insert; "hostfold" forces the native fold;
-    #: "delta" forces the host-folded delta-plane path for the three
-    #: foldable write kinds (hll_add/bloom_add/bitset_set); "tape" forces
-    #: the same folds but retires the whole window through the fused
-    #: window megakernel (one launch per window, ingest/tape.py).
+    #: accepted `ingest` config values — "auto" follows `ingest_rule`;
+    #: "device" forces the device path with the configured hll_impl; the
+    #: kernel names force that device insert; "hostfold" forces the native
+    #: fold; "delta" forces the host-folded delta-plane path for the three
+    #: foldable write kinds (hll_add/bloom_add/bitset_set).
     INGEST_CHOICES = ("auto", "device", "hostfold", "delta", "scatter",
-                      "sort", "segment", "tape")
-
-    #: run() accepts the executor's per-window sequence number, so the
-    #: dispatch-cost counters (window_launches / launch_us) attribute to
-    #: pipeline windows without guessing at run boundaries.
-    WINDOW_HANDOFF = True
+                      "sort", "segment")
 
     def __init__(
         self,
@@ -583,7 +628,6 @@ class TpuBackend:
         ingest: str = "auto",
         bank_capacity: int = 256,
         hll_hash: str = "murmur3",
-        planner: Optional[IngestPlanner] = None,
         read_cache_entries: int = 1024,
     ):
         if ingest not in self.INGEST_CHOICES:
@@ -594,12 +638,12 @@ class TpuBackend:
         # or 'redis' (MurmurHash64A 0xadc83b19 — registers a real server can
         # keep PFADDing into; VERDICT r4 missing #3).
         self.family = "m3" if hll_hash == "murmur3" else "redis"
-        if self.family == "redis" and ingest in ("hostfold", "delta", "tape"):
+        if self.family == "redis" and ingest in ("hostfold", "delta"):
             raise ValueError(
                 f"hll_hash='redis' is incompatible with ingest={ingest!r} "
                 "(the native fold kernel implements the murmur3 family); "
                 "use ingest='device' or 'auto'")
-        if ingest in ("hostfold", "delta", "tape"):
+        if ingest in ("hostfold", "delta"):
             from redisson_tpu import native as native_mod
 
             if not native_mod.available():
@@ -616,7 +660,8 @@ class TpuBackend:
         self.hll_impl = hll_impl
         self.seed = seed
         self.ingest = ingest
-        self.planner = planner or default_planner()
+        # the benchmark logs this table as its `# planner:` line
+        self.planner = RuleInputs(store.device)
         # Host staging (pad + device_put) of chunk N+1 overlaps device
         # dispatch of chunk N for multi-chunk runs (ingest/pipeline).
         self._pipeline = StagingPipeline(depth=2)
@@ -642,7 +687,6 @@ class TpuBackend:
             "delta_runs": 0,      # executor runs retired via the delta path
             "delta_keys": 0,      # keys folded into delta planes
             "delta_scratch_bytes": 0,  # in-flight delta plane bytes (meter)
-            "tape_runs": 0,       # windows retired via the tape megakernel
             "window_launches": 0,  # device dispatches issued retiring those
             "launch_us": 0.0,     # host wall time spent issuing them
             "geo_planes": 0,      # remote site planes through the fused path
@@ -656,8 +700,6 @@ class TpuBackend:
         }
         # adds d2h_wait_ns / resolve_ns / runs_materialized to the counters
         self.completer = Completer(counters=self.counters)
-        # Executor window handoff: last window sequence seen by run().
-        self.last_window = None
         self._scratch_lock = threading.Lock()
         # memstat ledger (MemLedger-shaped); bank lifecycle hooks feed it.
         self.accounting = None
@@ -798,120 +840,28 @@ class TpuBackend:
         set_shard(by_shard, unassigned=nbytes - assigned)
 
     def _plan_ingest(self, nkeys: int, allow_delta: bool = False) -> str:
-        """Resolve one run's HLL insert path: 'delta', 'hostfold' or a
-        device insert impl ('scatter' | 'sort' | 'segment').
-
-        Forced config values short-circuit; 'auto' asks the planner,
-        whose measured device-kernel costs are offset by the link's
-        8 B/key transfer cost and compared against a host-fold candidate
-        priced from the same LinkProfile (native fold ns/key + the
-        amortized 16 KB plane upload) — the old hostfold_policy gates
-        (native lib present, murmur3 family, batch big enough to
-        amortize per-run costs) decide whether it competes at all.
+        """One run's HLL insert path: 'delta', 'hostfold' or a device
+        insert impl ('scatter' | 'sort' | 'segment'), by `ingest_rule`.
         `allow_delta` marks calls from the delta dispatch (ops already
-        proven host-foldable): there the plane candidate is named
-        'delta' and retires through the fused multi-target merge;
-        classic callers keep the per-target 'hostfold' absorb."""
-        if self.ingest == "hostfold":
-            return "hostfold"
-        if self.ingest == "delta":
-            return "delta" if allow_delta else self.hll_impl
-        if self.ingest == "tape":
-            return "tape" if allow_delta else self.hll_impl
-        if self.ingest in ("scatter", "sort", "segment"):
-            return self.ingest
-        if self.ingest == "device":
-            return self.hll_impl
-        from redisson_tpu import native as native_mod
-
-        extra = None
-        overhead = 0.0
-        if (self.family != "redis" and native_mod.available()
-                and nkeys >= HOSTFOLD_MIN_KEYS):
-            prof = link_profile(self.store.device)
-            overhead = prof.transfer_ns_per_byte * 8
-            plane = (prof.fold_ns_per_key
-                     + prof.transfer_ns_per_byte * 16384 / max(nkeys, 1))
-            extra = {"delta" if allow_delta else "hostfold": plane}
-            if allow_delta:
-                # Tape candidate: same fold + plane transfer (HLL planes
-                # are dense either way) minus the OBSERVED launch-train
-                # saving — zero until the delta path has produced real
-                # per-launch measurements, so auto never flips on faith.
-                credit = self._tape_credit_ns()
-                if credit > 0.0:
-                    extra["tape"] = max(plane - credit, 0.0)
-        return self.planner.plan(
-            "hll", nkeys, extra_costs=extra, device_overhead=overhead).path
+        proven host-foldable), which retire a fold through the fused
+        multi-target merge; classic callers keep the per-target
+        'hostfold' absorb."""
+        return ingest_rule(self.ingest, nkeys, self.store.device,
+                           group=allow_delta, family=self.family,
+                           hll_impl=self.hll_impl)
 
     def _plan_bits(self, nkeys: int, plane_bytes: int = 0,
                    raw_per_key: int = 8, allow_delta: bool = False) -> str:
         """Set-bits strategy for bloom/bitset device inserts ('scatter' |
-        'segment' | 'delta'). Forced 'segment' carries over from the
-        config knob; every other forced mode keeps the classic scatter
-        (hostfold for blooms is decided separately by the host-mirror
-        policy). Under 'auto' with `allow_delta`, a delta candidate is
-        priced from the LinkProfile: the host fold (the native HLL fold
-        rate stands in for the bloom/bitset folds — all stream the key
-        batch once) plus the amortized plane upload, bounded by the
-        sparse encoding's 5 B/touched-byte, against device paths that
-        each pay `raw_per_key` transfer bytes."""
-        if self.ingest == "segment":
-            return "segment"
-        if self.ingest == "delta":
-            return "delta" if allow_delta else "scatter"
-        if self.ingest == "tape":
-            return "tape" if allow_delta else "scatter"
-        if self.ingest != "auto":
-            return "scatter"
-        extra = None
-        overhead = 0.0
-        if allow_delta and plane_bytes and nkeys >= HOSTFOLD_MIN_KEYS:
-            prof = link_profile(self.store.device)
-            overhead = prof.transfer_ns_per_byte * raw_per_key
-            ship = min(plane_bytes,
-                       nkeys * delta_mod.SPARSE_ENTRY_BYTES)
-            extra = {"delta": prof.fold_ns_per_key
-                     + prof.transfer_ns_per_byte * ship / max(nkeys, 1)}
-            # Tape candidate pays the FULL pow2-padded plane on the wire
-            # (no sparse re-encode in the arena) but saves the delta
-            # launch train; priced only from observed launch costs.
-            credit = self._tape_credit_ns()
-            if credit > 0.0:
-                pad = 1 << max(0, int(plane_bytes - 1).bit_length())
-                extra["tape"] = max(
-                    prof.fold_ns_per_key
-                    + prof.transfer_ns_per_byte * pad / max(nkeys, 1)
-                    - credit, 0.0)
-        return self.planner.plan(
-            "bits", nkeys, extra_costs=extra, device_overhead=overhead).path
-
-    def _tape_credit_ns(self) -> float:
-        """Observed per-key dispatch saving of the tape path: (delta
-        launches per window - 1) x the measured mean per-launch host cost,
-        amortized over the mean folded keys per window. Zero until the
-        delta path has produced real measurements — the auto planner must
-        never prefer 'tape' on an unmeasured promise."""
-        c = self.counters
-        runs = c["delta_runs"]
-        if not runs or not c["window_launches"] or not c["delta_keys"]:
-            return 0.0
-        per_launch_us = c["launch_us"] / c["window_launches"]
-        # Tape windows contribute exactly one launch each; subtract them so
-        # the train length reflects the chunked delta path alone.
-        delta_launches = c["window_launches"] - c["tape_runs"]
-        extra_launches = delta_launches / runs - 1.0
-        if extra_launches <= 0.0:
-            return 0.0
-        keys_per_window = c["delta_keys"] / max(runs + c["tape_runs"], 1)
-        return extra_launches * per_launch_us * 1e3 / max(keys_per_window, 1.0)
+        'segment' | 'delta'), by `ingest_rule`; hostfold for blooms is
+        decided separately by the host-mirror policy."""
+        return ingest_rule(self.ingest, nkeys, self.store.device, bits=True,
+                           group=allow_delta, plane_bytes=plane_bytes,
+                           raw_per_key=raw_per_key)
 
     # -- dispatch -----------------------------------------------------------
 
-    def run(self, kind: str, target: str, ops: List[Op],
-            window: Optional[int] = None) -> None:
-        if window is not None:
-            self.last_window = window
+    def run(self, kind: str, target: str, ops: List[Op]) -> None:
         if kind in self.COALESCE_GROUPS:
             # Group-coalesced runs may span kinds AND targets (the executor
             # steals same-group queue heads); the delta dispatch splits the
@@ -962,80 +912,62 @@ class TpuBackend:
     DELTA_STACK_CELLS = 1 << 26
 
     def _delta_eligible(self, op: Op) -> bool:
-        if self.ingest not in ("auto", "delta", "tape"):
+        if self.ingest not in ("auto", "delta"):
             return False
         if op.kind == "hll_add" and self.family == "redis":
             return False  # native fold kernels implement the murmur3 family
         return delta_mod.foldable(op.kind, op.payload)
 
-    #: planner results that retire through the fused delta window (the
-    #: chunked merge stack or, for "tape", the window megakernel).
-    _DELTA_PATHS = frozenset({"delta", "tape"})
-
-    def _delta_planned(self, kind: str, tname: str,
-                       tops: List[Op]) -> Optional[str]:
+    def _delta_planned(self, kind: str, tname: str, tops: List[Op]) -> bool:
         """Per-target delta gate: the target must be type-clean for the
         delta path (WRONGTYPE / uninitialized-filter errors surface
         through the classic handlers, which isolate them per target) and
-        the planner must pick 'delta' or 'tape' for this batch size.
-        Returns the planned path name, or None for the classic path."""
+        the ingest rule must pick 'delta' for this batch size."""
         if kind == "geo_merge":
             # Remote planes arrive pre-folded: shipping them is sunk cost,
-            # so no planner consult — only the per-target type gates. Never
-            # 'tape' (the megakernel's per-op completion contract — newly
-            # bits, pre-merge SETBIT reads — doesn't apply to remote
-            # planes); _delta_dispatch keeps geo groups off tape windows.
+            # so no rule consult — only the per-target type gates.
             inner = tops[0].payload["inner"]
             if inner == "hll_add":
-                if (tname not in self._rows
-                        and self.store.get(tname) is not None):
-                    return None  # name holds a bitset/bloom: WRONGTYPE
-                return "delta"
+                # a name that holds a bitset/bloom is WRONGTYPE
+                return tname in self._rows or self.store.get(tname) is None
             if tname in self._rows:
-                return None  # name holds an hll: WRONGTYPE
+                return False  # name holds an hll: WRONGTYPE
             obj = self.store.get(tname)
             if inner == "bloom_add":
-                if obj is not None and (obj.otype != ObjectType.BLOOM
-                                        or obj.meta.get("blocked")):
-                    return None
-                return "delta"
-            if obj is not None and obj.otype != ObjectType.BITSET:
-                return None
-            return "delta"
+                return obj is None or (obj.otype == ObjectType.BLOOM
+                                       and not obj.meta.get("blocked"))
+            return obj is None or obj.otype == ObjectType.BITSET
         nkeys = sum(op.nkeys or delta_mod.payload_nkeys(kind, op.payload)
                     for op in tops)
         if kind == "hll_add":
             if tname not in self._rows and self.store.get(tname) is not None:
-                return None  # name holds a bitset/bloom: WRONGTYPE
-            path = self._plan_ingest(nkeys, allow_delta=True)
-            return path if path in self._DELTA_PATHS else None
+                return False  # name holds a bitset/bloom: WRONGTYPE
+            return self._plan_ingest(nkeys, allow_delta=True) == "delta"
         if tname in self._rows:
-            return None  # name holds an hll: WRONGTYPE
+            return False  # name holds an hll: WRONGTYPE
         obj = self.store.get(tname)
         if kind == "bloom_add":
             if (obj is None or obj.otype != ObjectType.BLOOM
                     or obj.meta.get("blocked")):
-                return None
+                return False
             # A valid host mirror folds with ZERO link traffic — under
-            # auto that dominates shipping any plane; forced delta/tape
-            # keeps the device copy current instead.
-            if self.ingest not in ("delta", "tape") and self._bloom_use_host(
+            # auto that dominates shipping any plane; forced delta keeps
+            # the device copy current instead.
+            if self.ingest != "delta" and self._bloom_use_host(
                     tname, obj, nkeys):
-                return None
+                return False
             m = obj.meta["size"]
-            path = self._plan_bits(nkeys, plane_bytes=(m + 7) // 8,
-                                   raw_per_key=8, allow_delta=True)
-            return path if path in self._DELTA_PATHS else None
+            return self._plan_bits(nkeys, plane_bytes=(m + 7) // 8,
+                                   raw_per_key=8, allow_delta=True) == "delta"
         # bitset_set — plane size is the post-growth allocation
         if obj is not None and obj.otype != ObjectType.BITSET:
-            return None
+            return False
         nbits = obj.state.shape[0] if obj is not None else 1024
         mx = self._max_index(tops)
         if mx >= nbits:
             nbits = max(1024, 1 << int(mx).bit_length())
-        path = self._plan_bits(nkeys, plane_bytes=(nbits + 7) // 8,
-                               raw_per_key=4, allow_delta=True)
-        return path if path in self._DELTA_PATHS else None
+        return self._plan_bits(nkeys, plane_bytes=(nbits + 7) // 8,
+                               raw_per_key=4, allow_delta=True) == "delta"
 
     def _delta_dispatch(self, target: str, ops: List[Op]) -> None:
         """Split a (possibly cross-kind, cross-target) coalesced run into
@@ -1047,27 +979,14 @@ class TpuBackend:
         for op in ops:
             groups.setdefault((op.target, op.kind), []).append(op)
         delta_groups, classic = [], []
-        use_tape = self.ingest == "tape"
         for (tname, kind), tops in groups.items():
-            path = (self._delta_planned(kind, tname, tops)
-                    if all(self._delta_eligible(op) for op in tops) else None)
-            if path:
+            if (all(self._delta_eligible(op) for op in tops)
+                    and self._delta_planned(kind, tname, tops)):
                 delta_groups.append((tname, kind, tops))
-                use_tape = use_tape or path == "tape"
             else:
                 classic.extend(tops)
         if delta_groups:
-            geo = [g for g in delta_groups if g[1] == "geo_merge"]
-            rest = [g for g in delta_groups if g[1] != "geo_merge"]
-            if use_tape and geo:
-                # Geo planes never ride the tape arena (see _delta_planned):
-                # local groups keep the megakernel, remote planes retire in
-                # their own single fused merge launch for the window.
-                if rest:
-                    self._delta_window(rest, tape=True)
-                self._delta_window(geo, tape=False)
-            else:
-                self._delta_window(delta_groups, tape=use_tape)
+            self._delta_window(delta_groups)
         if classic:
             self._classic_group_run(classic)
 
@@ -1098,18 +1017,15 @@ class TpuBackend:
                     if not op.future.done():
                         op.future.set_exception(exc)
 
-    def _delta_window(self, groups, tape: bool = False) -> None:
+    def _delta_window(self, groups) -> None:
         """Fold every (target, kind) group into its delta plane, then
-        retire the window: through the tape megakernel in ONE fused
-        launch when `tape` (falling back to chunking only when the
-        window overflows the arena budget), else through as few chunked
-        merge launches as the stack budget allows (normally one)."""
+        retire the window through as few chunked merge launches as the
+        stack budget allows (normally one)."""
         t0 = time.perf_counter()
         planes, specs = [], []
         for tname, kind, tops in groups:
             try:
-                plane, spec = self._delta_fold_group(tname, kind, tops,
-                                                     tape=tape)
+                plane, spec = self._delta_fold_group(tname, kind, tops)
             except Exception as exc:  # noqa: BLE001 — per-target isolation
                 # Host fold failure: nothing reached the device — retryable.
                 exc = classify(exc, seam="stage_h2d")
@@ -1125,33 +1041,6 @@ class TpuBackend:
         for p in planes:
             self.counters["raw_bytes"] += p.raw_bytes
             self.counters["delta_keys"] += p.nkeys
-        if tape:
-            t2 = 1 << max(0, (len(planes) - 1).bit_length())
-            lanes = max(self._pad_cells(p.cells) for p in planes)
-            if t2 * lanes <= self.DELTA_STACK_CELLS:
-                self.counters["tape_runs"] += 1
-                try:
-                    self._tape_retire(planes, specs)
-                except Exception as exc:  # noqa: BLE001
-                    # Whole-window isolation: the single launch is the
-                    # unit of failure, nothing committed before it.
-                    exc = classify(exc, seam="kernel_launch")
-                    for spec in specs:
-                        for op in spec["ops"]:
-                            if not op.future.done():
-                                op.future.set_exception(exc)
-                return
-            # Window overflows one tape arena: retire through the chunked
-            # path. The folds skipped the bitset pre-merge packs (the tape
-            # output plane would have carried them) — issue them now.
-            tape = False
-            for p, spec in zip(planes, specs):
-                if p.kind == "bitset_set" and spec.get("old_packed") is None:
-                    obj = self.store.get(p.target)
-                    spec["old_packed"] = _start_d2h(
-                        engine.bitset_pack(obj.state))
-                    self.counters["window_launches"] += 1
-        for p in planes:
             self.counters["link_bytes"] += p.link_bytes
         self.counters["delta_runs"] += 1
         # Partition into merge chunks under the cell budget; sorting by
@@ -1189,13 +1078,10 @@ class TpuBackend:
         return max(engine.MIN_BUCKET,
                    1 << max(0, int(cells - 1).bit_length()))
 
-    def _delta_fold_group(self, tname: str, kind: str, tops: List[Op],
-                          tape: bool = False):
+    def _delta_fold_group(self, tname: str, kind: str, tops: List[Op]):
         """Fold one (target, kind) group into its DeltaPlane + completion
         spec. Runs entirely on the host (native folds / numpy); any
-        device work it queues (bitset pre-merge pack) is async. Under
-        `tape` the bitset pack is skipped — the megakernel emits every
-        row's pre-merge bits in its own packed output plane."""
+        device work it queues (bitset pre-merge pack) is async."""
         from redisson_tpu import native as native_mod
 
         if kind == "geo_merge":
@@ -1245,12 +1131,9 @@ class TpuBackend:
         plane = delta_mod.fold_bitset(payloads, nbits)
         # Per-key SETBIT results are the PRE-merge bits: pack the current
         # state on device and start the D2H now; the completer slices per
-        # key from the packed snapshot. (Tape windows skip this launch —
-        # the megakernel's old_packed output plane carries the same bits.)
-        old_packed = None
-        if not tape:
-            old_packed = _start_d2h(engine.bitset_pack(obj.state))
-            self.counters["window_launches"] += 1
+        # key from the packed snapshot.
+        old_packed = _start_d2h(engine.bitset_pack(obj.state))
+        self.counters["window_launches"] += 1
         dp = delta_mod.encode(kind, tname, plane, cells=nbits, packed=True,
                               nkeys=nkeys, raw_bytes=raw)
         return dp, {"kind": kind, "ops": tops, "old_packed": old_packed}
@@ -1508,126 +1391,16 @@ class TpuBackend:
 
         self.completer.submit(run_and_release)
 
-    def _tape_retire(self, planes, specs) -> None:
-        """Retire one whole window through the tape megakernel: encode
-        every folded plane into the flat command tape (ingest/tape.py)
-        and issue ONE fused device call (engine.tape_apply) that gathers
-        the old rows, decodes + merges every entry by op_code, packs the
-        pre-merge bits for SETBIT results, and scatters the HLL rows
-        back into the bank. The kernel_launch seam fires before anything
-        is encoded or committed, so an injected fault fails the window
-        whole with no partial state."""
-        import jax
-
-        fault_inject.fire("kernel_launch", kind="tape",
-                          target=planes[0].target if planes else "")
-        t0 = time.perf_counter()
-        spec_by = {id(p): s for p, s in zip(planes, specs)}
-        tp = tape_mod.encode_window(planes, self._hll_row)
-        self.counters["link_bytes"] += tp.link_bytes
-        n_hll = tp.n_hll
-
-        def put(a):
-            return jax.device_put(a, self.store.device)
-
-        wire = put(tp.wire)
-        table = put(tp.table)
-        if n_hll:
-            rows_pad = put(engine.pad_rows_repeat(tp.hll_rows))
-            bank = self._ensure_bank()
-        else:
-            rows_pad = put(np.zeros((1,), np.int32))
-            bank = jnp.zeros((1, 1), jnp.int32)  # dummy, never read
-        store_planes = tp.planes[n_hll:]
-        store_old = tuple(self.store.get(p.target).state
-                          for p in store_planes)
-        want_old = any(p.kind == "bitset_set" for p in store_planes)
-        new_bank, merged, changed, old_packed = engine.tape_apply(
-            bank, wire, table, rows_pad, store_old,
-            n_hll=n_hll, lanes=tp.lanes, want_old=want_old)
-        self.counters["merge_launches"] += 1
-        self.counters["window_launches"] += 1
-        # Writeback — dispatch-time state, same contract as the chunked
-        # path: bank/store/mirror commit here on the dispatcher thread.
-        if n_hll:
-            self.bank = new_bank
-            for p in tp.planes[:n_hll]:
-                self._bump(p.target)
-        for j, p in enumerate(store_planes):
-            row = n_hll + j
-            self.store.swap(p.target, merged[row, : p.cells])
-            self._touch(p.target)
-            if p.kind == "bloom_add":
-                # device == mirror + this batch == scratch, by construction
-                spec = spec_by[id(p)]
-                spec["mirror"]["bits"] = spec["scratch"]
-                spec["mirror"]["synced_dev"] = self.store.get(
-                    p.target).version
-        self.counters["launch_us"] += (time.perf_counter() - t0) * 1e6
-        flag = _start_d2h(changed)
-        old_host = _start_d2h(old_packed) if want_old else None
-        entries = [(i, p, spec_by[id(p)]) for i, p in enumerate(tp.planes)]
-
-        def run():
-            try:
-                fault_inject.fire("d2h_complete", kind="tape",
-                                  target=planes[0].target if planes else "")
-                host_changed = np.asarray(flag)
-                host_old = (np.asarray(old_host)
-                            if old_host is not None else None)
-            except Exception as exc:  # noqa: BLE001
-                exc = classify(exc, seam="d2h_complete")
-                for _i, _p, spec in entries:
-                    _fail_ops(spec["ops"], exc)
-                return None
-            return functools.partial(resolve, host_changed, host_old)
-
-        def resolve(host_changed, host_old):
-            for i, p, spec in entries:
-                if p.kind == "hll_add":
-                    # Per-target PFADD bool: did ANY register of this row
-                    # rise this window (delta-path precedent).
-                    v = bool(host_changed[i])
-                    for op in spec["ops"]:
-                        if not op.future.done():
-                            op.future.set_result(v)
-                elif p.kind == "bloom_add":
-                    for op, newly in zip(spec["ops"], spec["newly"]):
-                        if not op.future.done():
-                            op.future.set_result(newly)
-                else:
-                    old = host_old[i]
-                    for op in spec["ops"]:
-                        idx = np.asarray(op.payload["idx"], np.int64)
-                        bits = ((old[idx >> 3] >> (7 - (idx & 7))) & 1
-                                ).astype(bool)
-                        if not op.future.done():
-                            op.future.set_result(bits)
-
-        scratch_inflight = sum(int(p.plane_bytes) for p in planes)
-        with self._scratch_lock:
-            self.counters["delta_scratch_bytes"] += scratch_inflight
-
-        def run_and_release():
-            try:
-                return run()
-            finally:
-                with self._scratch_lock:
-                    self.counters["delta_scratch_bytes"] -= scratch_inflight
-
-        self.completer.submit(run_and_release)
-
     def ingest_stats(self) -> dict:
         """Cumulative delta-ingest counters + the derived per-key link
         cost (bench's `delta_bytes_per_key` and the backend.* gauges read
         this) and the observed per-window dispatch cost
-        (`launches_per_window` / `launch_us_per_window` — the tape gate:
-        one fused launch per pipeline window)."""
+        (`launches_per_window` / `launch_us_per_window`)."""
         out = dict(self.counters)
         out["delta_bytes_per_key"] = (
             self.counters["link_bytes"]
             / max(self.counters["delta_keys"], 1))
-        windows = self.counters["delta_runs"] + self.counters["tape_runs"]
+        windows = self.counters["delta_runs"]
         out["launches_per_window"] = (
             self.counters["window_launches"] / max(windows, 1))
         out["launch_us_per_window"] = (
@@ -1911,7 +1684,7 @@ class TpuBackend:
         # per-key row vector ships over the link); multi-target coalesced
         # runs carry a per-key slot vector into the run's distinct rows —
         # one call for many sketches that touches only those rows.
-        # `impl` is the planner's device insert choice for this run; it
+        # `impl` is the ingest rule's device insert choice for this run; it
         # reaches the scalar-row kernels (the multi-target kernels stay on
         # the XLA scatter, see engine._bank_add_row). The mesh plane's
         # sharded bank takes only the multi-target kernels, whose changed
@@ -2559,9 +2332,9 @@ class TpuBackend:
             return True  # sticky: a valid mirror keeps serving host ops
         if self.ingest == "hostfold":
             return True
-        # auto: adopt a mirror when the link-vs-fold profile says hostfold
-        # (same probe as the HLL path) and the batch is worth it.
-        return hostfold_policy(self.ingest, nkeys, self.store.device)
+        # Every other ingest value adopts a mirror when the HLL link rule
+        # would fold a batch this size on the host.
+        return ingest_rule("auto", nkeys, self.store.device) == "hostfold"
 
     def _bloom_mirror(self, target: str, obj, m: int) -> dict:
         """The current host replica (build/refresh if a device-side write
@@ -2666,8 +2439,8 @@ class TpuBackend:
         add_packed, contains_packed, add_bytes, contains_bytes = (
             self._bloom_kernels(obj))
         if mutate and not obj.meta.get("blocked"):
-            # Classic-layout adds take the planner's set-bits strategy
-            # (scatter vs the ingest subsystem's segment-or); the blocked
+            # Classic-layout adds take the ingest rule's set-bits strategy
+            # (scatter, or a forced segment-or); the blocked
             # layout's cache-local scatter stays as-is.
             impl = self._plan_bits(
                 sum(op.nkeys or self._payload_nkeys(op) for op in ops))

@@ -79,7 +79,7 @@ class SlotOwnershipBackend:
 
     # -- the waist ----------------------------------------------------------
 
-    def run(self, kind: str, target: str, ops: List, window=None) -> None:
+    def run(self, kind: str, target: str, ops: List) -> None:
         if kind in CLUSTER_KINDS:
             self._run_cluster(kind, ops)
             return
@@ -108,7 +108,7 @@ class SlotOwnershipBackend:
                 if not live:
                     return
                 ops = live
-        self._inner.run(kind, target, ops, window=window)
+        self._inner.run(kind, target, ops)
 
     # -- ownership transitions (journaled; dispatcher thread) ---------------
 
@@ -381,7 +381,7 @@ class MeshOwnershipBackend:
 
     # -- the waist ----------------------------------------------------------
 
-    def run(self, kind: str, target: str, ops: List, window=None) -> None:
+    def run(self, kind: str, target: str, ops: List) -> None:
         if kind in CLUSTER_KINDS:
             self._run_cluster(kind, ops)
             return
@@ -411,7 +411,7 @@ class MeshOwnershipBackend:
                 if not live:
                     return
                 ops = live
-        self._inner.run(kind, target, ops, window=window)
+        self._inner.run(kind, target, ops)
 
     # -- ownership transitions (journaled; dispatcher thread) ---------------
 

@@ -41,20 +41,18 @@ class TpuConfig:
     # hllPatLen) — choose "redis" when flushed sketches must stay
     # server-mergeable under later server-side PFADDs (mixed writers).
     hll_hash: str = "murmur3"
-    # Ingest path. "auto" lets the planner (redisson_tpu.ingest.planner)
-    # pick per batch from a measured-at-first-use cost table; the rest
-    # force one path: "device" ships raw keys (8 B/key) and inserts with
-    # the configured hll_impl; "scatter" / "sort" / "segment" force that
-    # device insert kernel (segment = the Pallas segmented-scatter);
-    # "hostfold" folds into a 16 KB sketch natively and ships that;
-    # "delta" folds hll_add/bloom_add/bitset_set batches into per-target
-    # delta planes on the host and retires every plane staged in one
-    # pipeline window through a single fused device merge (README "Delta
-    # ingest"); "tape" goes one step further and encodes the WHOLE window
-    # into a flat command tape retired by one fused megakernel launch
-    # (README "Window megakernel"); under "auto" both compete in the
-    # planner's cost table as the "delta" / "tape" candidates ("tape"
-    # only once its observed launch saving has been measured).
+    # Ingest path. "auto" follows the link rule (backend_tpu.ingest_rule):
+    # a batch of at least 65,536 keys folds on the host when the native
+    # library is loaded and the measured link makes a folded plane cheaper
+    # to ship than the raw keys; everything else inserts on the device
+    # with hll_impl. The rest force one path: "device" ships raw keys
+    # (8 B/key) and inserts with the configured hll_impl; "scatter" /
+    # "sort" / "segment" force that device insert kernel (segment = the
+    # Pallas segmented-scatter); "hostfold" folds into a 16 KB sketch
+    # natively and ships that; "delta" folds hll_add/bloom_add/bitset_set
+    # batches into per-target delta planes on the host and retires every
+    # plane staged in one pipeline window through a single fused device
+    # merge (README "Delta ingest").
     ingest: str = "auto"
     hash_seed: int = 0
     # Coalescing cap for one dispatcher run. Device kernels still chunk at
@@ -348,7 +346,7 @@ class GeoConfig:
     replication transport (exactly as it is for `replica/`): per-peer
     SiteLinks tail the local journal, fold the sketch-tier write stream
     into stamped delta planes, and ship them; the receiving site applies
-    them through the fused delta/tape merge path as `geo_*` op kinds.
+    them through the fused delta merge path as `geo_*` op kinds.
     Requires `Config.persist` with a dir and the native fold library
     (same precondition as ingest='delta'). Peering is wired at runtime
     with `geo.connect_sites([...])` / `client.geo.connect(peers)` — the

@@ -698,60 +698,6 @@ def delta_merge_stack(old, delta):
     return merged, jnp.any(merged != old, axis=1)
 
 
-@functools.partial(jax.jit, donate_argnums=(0, 1),
-                   static_argnames=("n_hll", "lanes", "want_old"))
-def tape_apply(bank, wire, table, hll_rows, store_old, *,
-               n_hll: int, lanes: int, want_old: bool = False):
-    """Consume one encoded window tape in a SINGLE device call.
-
-    The whole window retire — bank-row gather, old-state assembly,
-    per-entry op_code decode + merge (ops/window_kernel), pre-merge bit
-    pack for SETBIT results, and the bank writeback scatter — compiles
-    into one executable, so a mixed hll/bloom/bitset window costs one
-    dispatch instead of the delta path's gather + per-plane decode +
-    merge + writeback launch train.
-
-    Args: ``bank`` [S, m] int32 (dummy when ``n_hll`` is 0), ``wire``
-    uint8 [T2, W] and ``table`` int32 [T2, 4] from the tape encode,
-    ``hll_rows`` int32 [h2] bank rows repeat-padded with row 0 (pad
-    writes are idempotent — they rewrite row 0 with its own merged
-    registers), ``store_old`` a tuple of the store-backed entries' cell
-    arrays in arena order (NOT donated — they are live store state until
-    the host swaps in the merged rows). Returns ``(bank, merged [T2, L],
-    changed [T2] bool, old_packed [T2, L//8] | None)`` where
-    ``old_packed`` holds the PRE-merge bits of every row (big-endian
-    packbits order) for bitset old-bit reads."""
-    from redisson_tpu.ops import window_kernel as wk
-
-    t2 = table.shape[0]
-    m = bank.shape[1]
-    rows = []
-    if n_hll:
-        g = bank[hll_rows].astype(jnp.uint8)
-        if m < lanes:
-            g = jnp.pad(g, ((0, 0), (0, lanes - m)))
-        rows.extend(g[i] for i in range(n_hll))
-    for s in store_old:
-        c = s.shape[0]
-        s = s.astype(jnp.uint8)
-        if c < lanes:
-            s = jnp.pad(s, (0, lanes - c))
-        rows.append(s)
-    zero = jnp.zeros((lanes,), jnp.uint8)
-    rows.extend([zero] * (t2 - len(rows)))
-    old = jnp.stack(rows)
-    old_packed = None
-    if want_old:
-        old_packed = bitset.pack(old.reshape(-1)).reshape(t2, lanes // 8)
-    merged, changed = wk.window_merge(old, wire, table)
-    if n_hll:
-        h2 = hll_rows.shape[0]
-        sel = jnp.where(jnp.arange(h2) < n_hll, jnp.arange(h2), 0)
-        regs = merged[sel][:, :m]
-        bank = bank.at[hll_rows].set(regs.astype(jnp.int32))
-    return bank, merged, changed, old_packed
-
-
 # ---------------------------------------------------------------------------
 # BitSet
 # ---------------------------------------------------------------------------

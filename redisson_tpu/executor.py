@@ -81,8 +81,6 @@ GUARDED_BY = {
     "CommandExecutor._shutdown": "_lock",
     "CommandExecutor._journal": "_lock:writes",
     "CommandExecutor._trace": "_lock:writes",
-    "CommandExecutor._window_seq":
-        "thread:dispatcher-confined — bumped and read only in _dispatch_one",
     "CommandExecutor._stage_ns":
         "racy:monotonic counter written only in _dispatch, torn read ok",
     "CommandExecutor._runs_staged":
@@ -266,13 +264,6 @@ class CommandExecutor:
         # state) let the per-target/per-kind gates release at stage time.
         self._window = max(1, int(inflight_runs))
         self._eager_release = bool(getattr(backend, "DISPATCH_TIME_STATE", False))
-        # Window handoff: backends that retire a whole pipeline window in
-        # one fused launch (the tape megakernel) receive a monotonically
-        # increasing window sequence with each run, so per-window dispatch
-        # cost (launches_per_window, launch_us_per_window) is attributable
-        # without the backend guessing at run boundaries.
-        self._window_handoff = bool(getattr(backend, "WINDOW_HANDOFF", False))
-        self._window_seq = 0
         self._inflight: set = set()  # _InflightRun tokens
         self._inflight_targets: set = set()  # gated object names
         self._inflight_kinds: set = set()  # gated GLOBAL_COALESCE kinds
@@ -711,15 +702,8 @@ class CommandExecutor:
                 return
         try:
             fault_inject.fire("kernel_launch", kind=kind, target=target)
-            if self._window_handoff:
-                self._window_seq += 1
-                with stage("backend.run", window=self._window_seq,
-                           ops=len(live)):
-                    self._backend.run(kind, target, live,
-                                      window=self._window_seq)
-            else:
-                with stage("backend.run", ops=len(live)):
-                    self._backend.run(kind, target, live)
+            with stage("backend.run", ops=len(live)):
+                self._backend.run(kind, target, live)
             t_staged = self._clock()
             token.stage_s = t_staged - t0
             if spans:

@@ -1,6 +1,7 @@
 """Streaming ingest subsystem: the host->device insert path, end to end.
 
-Three pieces, composed by the backends:
+Two pieces, composed by the backends (the path each batch takes is
+`backend_tpu.ingest_rule`'s):
 
 * `kernels` — the Pallas segmented-scatter insert kernel (sort keys by
   register index, VMEM-tiled segment-max for HLL / segment-or for bit
@@ -10,11 +11,6 @@ Three pieces, composed by the backends:
   prep + H2D transfer of batch N+1 with device dispatch of batch N
   (the round-5 host budget showed 4.3 ms of transfer serialized behind
   65 us of dispatch per 1M-key batch).
-* `planner` — an adaptive path planner that picks
-  scatter / sort / segment / hostfold per (structure, batch size,
-  platform) from a small measured-at-first-use cost table, replacing
-  the hard-wired choices that used to live in `backend_tpu.py` and
-  `bench.py`.
 """
 
 from redisson_tpu.ingest.kernels import (  # noqa: F401
@@ -26,4 +22,3 @@ from redisson_tpu.ingest.kernels import (  # noqa: F401
     segmented_bits_set,
 )
 from redisson_tpu.ingest.pipeline import StagingPipeline  # noqa: F401
-from redisson_tpu.ingest.planner import IngestPlanner, IngestPlan  # noqa: F401

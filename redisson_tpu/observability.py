@@ -482,7 +482,7 @@ def register_wire(registry: MetricsRegistry, wire) -> None:
     registry.gauge("wire.sheds", _snap("sheds_total"))
     registry.gauge("wire.redirects", _snap("redirects_rendered"))
     registry.gauge("wire.windows", _snap("windows_flushed"))
-    registry.gauge("wire.pipeline_depth", _snap("last_window_depth"))
+    registry.gauge("wire.pipeline_depth", _snap("latest_window_depth"))
     registry.gauge("wire.pipeline_depth_avg", _snap("avg_window_depth", 0.0))
     registry.gauge("wire.dropped_conns", _snap("dropped_conns"))
     # loop-stall witness feed: zeros unless REDISSON_TPU_LOOP_WITNESS=1

@@ -25,7 +25,7 @@ TPU; `engine` gates them on the backend platform. The HLL insert fold
 has two device paths: the XLA combining max-scatter
 (`hll.insert_scatter`, ~30 us per 1M-key batch on v5e) and the Pallas
 segmented-scatter in `redisson_tpu.ingest.kernels` (sort + VMEM-tiled
-segment-max), selected per batch by `redisson_tpu.ingest.planner`.
+segment-max), which only a forced `ingest="segment"` selects.
 """
 
 from __future__ import annotations
@@ -106,13 +106,8 @@ def _delta_merge_kernel(old_ref, delta_ref, out_ref, changed_ref):
     old = old_ref[:].astype(jnp.int32)
     merged = jnp.maximum(old, delta_ref[:].astype(jnp.int32))
     out_ref[:] = merged.astype(out_ref.dtype)
-    accumulate_changed(changed_ref, j, merged, old)
-
-
-def accumulate_changed(changed_ref, j, merged, old):
-    """OR this cell block's per-row "merge changed something" flag into
-    the [rows, 1] accumulator; `j` is the cell-block grid index, and the
-    first block of a row tile resets it."""
+    # OR this cell block's per-row "merge changed something" flag into
+    # the [rows, 1] accumulator; the first block of a row tile resets it.
     diff = jnp.max((merged != old).astype(jnp.int32), axis=1, keepdims=True)
 
     @pl.when(j == 0)
@@ -122,15 +117,6 @@ def accumulate_changed(changed_ref, j, merged, old):
     @pl.when(j > 0)
     def _():
         changed_ref[:] = changed_ref[:] | diff
-
-
-def row_block(t: int) -> int:
-    """Rows per grid step for a [T, L] uint8 stack: the whole stack when
-    T <= 32, else 32 (uint8 tiles are (32, 128); callers pad T to a power
-    of two, so 32 divides it)."""
-    if t > 32 and t % 32:
-        raise ValueError(f"{t} rows: pad the stack to a multiple of 32")
-    return min(t, 32)
 
 
 @functools.partial(jax.jit, donate_argnums=(0,),
@@ -150,7 +136,11 @@ def delta_merge(old: jnp.ndarray, delta: jnp.ndarray, block: int = 1 << 13,
     of the old state (the memstat ledger test pins this).
     Returns (merged [T, L], changed [T] bool)."""
     t, l = old.shape
-    rows = row_block(t)
+    # Rows per grid step: the whole stack when T <= 32, else 32 (uint8
+    # tiles are (32, 128); callers pad T to a power of two).
+    if t > 32 and t % 32:
+        raise ValueError(f"{t} rows: pad the stack to a multiple of 32")
+    rows = min(t, 32)
     block = min(block, l)
     # Callers pad L to a power of two >= 1024, so block divides l.
     merged, changed = pl.pallas_call(

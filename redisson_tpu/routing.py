@@ -33,11 +33,6 @@ class RoutingBackend:
         # per-target gates at staging time and pipeline the device work.
         self.DISPATCH_TIME_STATE = bool(
             getattr(sketch_backend, "DISPATCH_TIME_STATE", False))
-        # Window handoff (tape megakernel): forward the executor's window
-        # sequence to the sketch tier, which attributes per-window launch
-        # cost to it. The structure tier never sees it (host-only ops).
-        self.WINDOW_HANDOFF = bool(
-            getattr(sketch_backend, "WINDOW_HANDOFF", False))
         self.pubsub = self.structures.pubsub
 
     # sketch kinds = everything the sketch backend implements, minus the
@@ -52,16 +47,12 @@ class RoutingBackend:
             return handles(kind)
         return hasattr(self.sketch, "_op_" + kind)
 
-    def run(self, kind: str, target: str, ops: List[Op],
-            window: Optional[int] = None) -> None:
+    def run(self, kind: str, target: str, ops: List[Op]) -> None:
         if kind in self._BOTH:
             getattr(self, "_both_" + kind)(target, ops)
             return
         if self._sketch_handles(kind):
-            if window is not None and self.WINDOW_HANDOFF:
-                self.sketch.run(kind, target, ops, window=window)
-            else:
-                self.sketch.run(kind, target, ops)
+            self.sketch.run(kind, target, ops)
             return
         self.structures.run(kind, target, ops)
 

@@ -8,9 +8,7 @@ opposite shape upstream of the device (continuous batching under a latency
 budget); this policy implements it:
 
   * an online **CostModel** learns ns/key and per-dispatch overhead per op
-    kind from the executor's own completions (EWMA over measured batches —
-    the same measured-not-modeled stance as `ingest/planner.py`, which can
-    seed it: see `seed_from_planner`);
+    kind from the executor's own completions (EWMA over measured batches);
   * `batch_key_limit` sizes the batch so its *service time* fits
     `target_batch_service_s` — batches grow only while the device call
     stays short enough that queue wait behind it is bounded;
@@ -98,24 +96,6 @@ class CostModel:
         with self._lock:
             oh = self._overhead_s.get(kind, self._default_overhead_s)
         return oh + max(0, nkeys) * self.s_per_key(kind)
-
-    def seed_from_planner(self, planner=None, nkeys: int = 1 << 16) -> None:
-        """Seed sketch-kind rates from the ingest planner's measured cost
-        table (ns/key per path) instead of the static defaults. Imported
-        lazily: the planner module pulls in jax, which this module must not
-        require (admission/policy run in CPU-only unit tests)."""
-        try:
-            if planner is None:
-                from redisson_tpu.ingest.planner import default_planner
-                planner = default_planner()
-            plan = planner.plan("hll", nkeys)
-            s_per_key = (plan.est_ns_per_key or 0.0) * 1e-9
-        except Exception:
-            return  # stay on defaults; the EWMA corrects within a few batches
-        if s_per_key > 0.0:
-            with self._lock:
-                for kind in ("hll_add", "bloom_add", "bitset_set"):
-                    self._s_per_key.setdefault(kind, s_per_key)
 
     def snapshot(self) -> Dict[str, Any]:
         with self._lock:

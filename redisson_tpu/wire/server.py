@@ -9,8 +9,8 @@ plane into the existing stack through ``ServingLayer.execute_many``.
 Scheduling shape (the whole point of the wire tier): commands arriving on
 MANY connections inside one event-loop wave accumulate into a shared
 staging list; a ``call_soon`` microtask flushes them as ONE
-``execute_many`` window, so the tape megakernel retires a multi-connection
-window in one launch instead of one launch per socket. Replies resolve out
+``execute_many`` window, so the executor coalesces a multi-connection
+window into one run instead of one run per socket. Replies resolve out
 of order across the window; each connection's :class:`ConnectionWindow`
 (serve/windows.py) releases them strictly in submission order.
 
@@ -77,7 +77,7 @@ GUARDED_BY = {
     "WireServer.redirects_rendered": "racy:monotonic counter, torn read ok",
     "WireServer.windows_flushed": "racy:monotonic counter, torn read ok",
     "WireServer.ops_flushed": "racy:monotonic counter, torn read ok",
-    "WireServer.last_window_depth": "racy:gauge sample, torn read ok",
+    "WireServer.latest_window_depth": "racy:gauge sample, torn read ok",
     "WireServer.dropped_conns": "racy:monotonic counter, torn read ok",
     "WireServer.loop_busy_ns": "racy:monotonic counter, torn read ok",
     "_WireConn.closing": "thread:event-loop confined",
@@ -215,7 +215,7 @@ class WireServer:
         self.redirects_rendered = 0
         self.windows_flushed = 0
         self.ops_flushed = 0
-        self.last_window_depth = 0
+        self.latest_window_depth = 0
         self.dropped_conns = 0
         # perf_counter ns the loop thread spent in wire.read, wire.flush
         # and wire.settle (always on; the stage spans cover the same code)
@@ -427,7 +427,7 @@ class WireServer:
         self._pending_targets = []
         self.windows_flushed += 1
         self.ops_flushed += len(staged)
-        self.last_window_depth = len(staged)
+        self.latest_window_depth = len(staged)
         busy0, t0 = self.loop_busy_ns, time.perf_counter_ns()
         try:
             with stage("wire.flush", window=self.windows_flushed,
@@ -804,7 +804,7 @@ class WireServer:
             "redirects_rendered": self.redirects_rendered,
             "windows_flushed": self.windows_flushed,
             "ops_flushed": self.ops_flushed,
-            "last_window_depth": self.last_window_depth,
+            "latest_window_depth": self.latest_window_depth,
             "avg_window_depth": (self.ops_flushed
                                  / max(1, self.windows_flushed)),
             "dropped_conns": self.dropped_conns,

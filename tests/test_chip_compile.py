@@ -8,8 +8,8 @@ Nothing runs on a chip here: each test lowers and compiles one kernel for
 a `v5e:2x2` topology that is described, not attached, and checks that
 the compiled program holds the Mosaic kernel (`tpu_custom_call`) — what
 the chip's compiler refuses, these tests refuse. Results are checked by
-the interpret-mode tests (test_pallas, test_ingest, test_tape) and on the
-chip by chip_smoke.py.
+the interpret-mode tests (test_pallas, test_ingest, test_delta_window)
+and on the chip by chip_smoke.py.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library, and the test workers all import
@@ -119,17 +119,6 @@ def test_popcount_partials(one_chip):
 
     _compile(one_chip, lambda c: pk.popcount_partials(c, interpret=False),
              ((1 << 28,), jnp.uint8))
-
-
-@pytest.mark.parametrize("t,l", [(64, 16384), (4, 1024)])
-def test_window_merge_pallas(one_chip, t, l):
-    from redisson_tpu.ops import window_kernel as wk
-
-    _compile(one_chip,
-             lambda o, w, tab: wk.window_merge_pallas(o, w, tab,
-                                                      interpret=False),
-             ((t, l), jnp.uint8), ((t, l), jnp.uint8),
-             ((t, wk.TABLE_COLS), jnp.int32))
 
 
 # The segmented kernels compile without their XLA sort (a 2^21-key sort

@@ -6,9 +6,8 @@ path is bit-identical to the serial scatter path (device state AND per-op
 results — PFADD "changed", bloom try_add "newly", bitset old bits), mixed
 hll+bloom+bitset windows retire in ONE fused merge launch, the sparse
 (idx, val) encoding kicks in exactly when it is smaller than the dense
-plane, link bytes/key collapse below 1/8 of raw at large batches, the
-planner's measured row overrides a stale dominated prior (the `sort`
-regression), and delta merges bump read-cache epochs exactly like scatter.
+plane, link bytes/key collapse below 1/8 of raw at large batches, and
+delta merges bump read-cache epochs exactly like scatter.
 """
 
 import numpy as np
@@ -18,7 +17,6 @@ from redisson_tpu import native
 from redisson_tpu.client import RedissonTPU
 from redisson_tpu.config import Config, TpuConfig
 from redisson_tpu.ingest import delta as delta_mod
-from redisson_tpu.ingest.planner import IngestPlanner
 
 needs_native = pytest.mark.skipif(
     not native.available(), reason="native fold library unavailable")
@@ -219,48 +217,7 @@ def test_link_bytes_collapse_below_eighth_of_raw():
 
 
 # ---------------------------------------------------------------------------
-# satellite 1: planner priors never outlive the first-use measurement
-# ---------------------------------------------------------------------------
-
-
-def test_planner_measured_row_overrides_dominated_prior():
-    def fake_measure(structure, n):
-        return {"scatter": 1.0, "sort": 5.0, "segment": 2.0}
-
-    p = IngestPlanner(platform="cpu", measure=fake_measure)
-    # A stale prior claims `sort` is 10x cheaper than it really is — a
-    # regression an early bench once hit.
-    p.set_prior("hll", 1 << 16, {"sort": 0.1})
-    plan = p.plan("hll", 1 << 16)
-    assert plan.path == "scatter"  # measured winner, never the stale prior
-    assert plan.costs["sort"] == 5.0  # measurement overrode the prior value
-
-
-def test_planner_prior_only_fills_unmeasured_paths():
-    def fake_measure(structure, n):
-        return {"scatter": 3.0, "sort": 5.0, "segment": 4.0}
-
-    p = IngestPlanner(platform="cpu", measure=fake_measure)
-    # `delta` cannot be timed by the device loop; the prior supplies it.
-    p.set_prior("hll", 1 << 16, {"delta": 0.5})
-    plan = p.plan("hll", 1 << 16)
-    assert plan.path == "delta"
-    assert plan.costs["scatter"] == 3.0
-
-
-def test_planner_auto_never_picks_dominated_path():
-    def fake_measure(structure, n):
-        return {"scatter": 1.0, "sort": 50.0, "segment": 2.0}
-
-    p = IngestPlanner(platform="cpu", measure=fake_measure)
-    for nkeys in (1 << 10, 1 << 14, 1 << 18, 1 << 21):
-        plan = p.plan("hll", nkeys)
-        assert plan.costs[plan.path] == min(plan.costs.values())
-        assert plan.path != "sort"
-
-
-# ---------------------------------------------------------------------------
-# satellite 2: delta merges bump read-cache epochs exactly like scatter
+# delta merges bump read-cache epochs exactly like scatter
 # ---------------------------------------------------------------------------
 
 

@@ -2,7 +2,7 @@
 
 Covers the three pieces the subsystem owns — the Pallas segmented-scatter
 insert kernel (vs its lax fallback AND the pure-python golden oracle),
-the measured-at-first-use path planner, and the double-buffered staging
+the ingest rule that picks a batch's path, and the double-buffered staging
 pipeline (results ordered, batch N+1 staged while batch N dispatches) —
 plus the 64-bit BITCOUNT guard (>2^31 set bits on both tiers) and a
 tier-1-safe smoke of ``bench.py --quick``.
@@ -20,7 +20,6 @@ import pytest
 
 from redisson_tpu.ingest import kernels
 from redisson_tpu.ingest.pipeline import StagingPipeline
-from redisson_tpu.ingest.planner import IngestPlan, IngestPlanner
 from tests import golden
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -142,77 +141,59 @@ def test_client_forced_segment_estimate_matches_scatter():
 
 
 # ---------------------------------------------------------------------------
-# planner
+# ingest rule
 # ---------------------------------------------------------------------------
 
 
-def _fixed_measure(costs):
-    calls = []
+def _link(transfer_ns_per_byte, fold_ns_per_key=4.0):
+    """A measured LinkProfile without the probe."""
+    from redisson_tpu.backend_tpu import LinkProfile
 
-    def measure(structure, n):
-        calls.append((structure, n))
-        return dict(costs)
-
-    return measure, calls
-
-
-def test_planner_picks_cheapest_device_path():
-    measure, _ = _fixed_measure({"scatter": 9.0, "sort": 5.0, "segment": 2.0})
-    p = IngestPlanner(platform="cpu", measure=measure)
-    plan = p.plan("hll", 1 << 16)
-    assert isinstance(plan, IngestPlan)
-    assert plan.path == "segment"
-    assert plan.measured
+    prof = object.__new__(LinkProfile)
+    prof.transfer_ns_per_byte = transfer_ns_per_byte
+    prof.fold_ns_per_key = fold_ns_per_key
+    return prof
 
 
-def test_planner_forced_short_circuits_measurement():
-    measure, calls = _fixed_measure({"scatter": 1.0})
-    p = IngestPlanner(platform="cpu", measure=measure)
-    plan = p.plan("hll", 1 << 16, forced="sort")
-    assert plan.path == "sort"
-    assert not plan.measured
-    assert not calls  # forced paths never trigger a measurement
+_SLOW, _FAST = 10.0, 0.1  # ns/byte: 8 B/key costs 80 / 0.8 ns vs a 4 ns fold
+_BIG = 1 << 20
 
 
-def test_planner_measures_once_per_size_class():
-    measure, calls = _fixed_measure({"scatter": 1.0, "segment": 2.0})
-    p = IngestPlanner(platform="cpu", measure=measure)
-    for n in (1 << 16, 1 << 16, (1 << 16) - 100):  # same bucket
-        p.plan("hll", n)
-    assert len(calls) == 1
-    p.plan("hll", 1 << 18)  # different bucket -> one more measurement
-    assert len(calls) == 2
-    assert "hll@16" in p.table() and "hll@18" in p.table()
+@pytest.mark.parametrize("link,native_ok,nkeys,kw,want", [
+    (_SLOW, False, _BIG, {}, "sort"),                       # native missing
+    (_SLOW, True, _BIG, {"family": "redis"}, "sort"),       # redis hash
+    (_SLOW, True, (1 << 16) - 1, {}, "sort"),               # below the floor
+    (_SLOW, True, _BIG, {}, "hostfold"),                    # classic run
+    (_SLOW, True, _BIG, {"group": True}, "delta"),          # target group
+    (_FAST, True, _BIG, {"group": True}, "sort"),           # fast link
+    (_SLOW, True, _BIG, {"bits": True, "group": True,       # plane cheaper
+                         "plane_bytes": 1 << 17}, "delta"),
+    (_SLOW, True, 1 << 16, {"bits": True, "group": True,    # raw keys cheaper
+                            "plane_bytes": 1 << 25, "raw_per_key": 4},
+     "scatter"),
+], ids=["native_missing", "redis_hash", "below_min_keys",
+        "slow_link_classic_hostfold", "slow_link_group_delta",
+        "fast_link_device", "bits_plane_cheaper", "bits_raw_cheaper"])
+def test_ingest_rule(monkeypatch, link, native_ok, nkeys, kw, want):
+    from redisson_tpu import backend_tpu, native
+
+    monkeypatch.setattr(native, "available", lambda: native_ok)
+    monkeypatch.setattr(backend_tpu, "link_profile", lambda dev: _link(link))
+    assert backend_tpu.ingest_rule("auto", nkeys, None, hll_impl="sort",
+                                   **kw) == want
 
 
-def test_planner_hostfold_wins_on_slow_links():
-    # Device paths pay the per-key transfer overhead; the injected
-    # hostfold candidate does not. A slow link must flip the decision.
-    measure, _ = _fixed_measure({"scatter": 10.0, "segment": 12.0})
-    p = IngestPlanner(platform="cpu", measure=measure)
-    fast = p.plan("hll", 1 << 20, extra_costs={"hostfold": 25.0},
-                  device_overhead=1.0)
-    slow = p.plan("hll", 1 << 20, extra_costs={"hostfold": 25.0},
-                  device_overhead=400.0)
-    assert fast.path == "scatter"
-    assert slow.path == "hostfold"
+def test_rule_inputs_table_never_probes(monkeypatch):
+    from redisson_tpu import backend_tpu
 
-
-def test_planner_size_class_clamps_to_engine_buckets():
-    assert IngestPlanner.size_class(1) == 10
-    assert IngestPlanner.size_class(1 << 12) == 12
-    assert IngestPlanner.size_class((1 << 12) + 1) == 13
-    assert IngestPlanner.size_class(1 << 30) == 21
-
-
-def test_planner_real_measurement_on_cpu():
-    # The real timing loop end to end (tiny batch): every advertised path
-    # gets a positive finite cost and the winner is one of them.
-    p = IngestPlanner()
-    plan = p.plan("bits", 1 << 10)
-    assert set(plan.costs) == {"scatter", "segment"}
-    assert all(0 < v < float("inf") for v in plan.costs.values())
-    assert plan.path in plan.costs
+    table = backend_tpu.RuleInputs("dev0")
+    assert table.table() == {}
+    assert "dev0" not in backend_tpu._LINK_PROFILES  # no probe taken
+    monkeypatch.setitem(backend_tpu._LINK_PROFILES, "dev0",
+                        _link(0.5, float("inf")))
+    got = json.loads(json.dumps(table.table(), allow_nan=False))
+    assert got == {"transfer_ns_per_byte": 0.5, "fold_ns_per_key": None,
+                   "fold_min_keys": backend_tpu.HOSTFOLD_MIN_KEYS}
 
 
 # ---------------------------------------------------------------------------
@@ -356,11 +337,10 @@ def test_bench_quick_smoke():
     assert proc.returncode == 0, proc.stderr[-2000:]
     result = json.loads(proc.stdout.strip().splitlines()[-1])
     # The roofline must price the segmented kernel too, and the recorded
-    # ingest decision must come from the planner's measured cost table.
+    # ingest decision is the link rule's: a 2^14-key batch stays below
+    # the fold's floor, so it inserts on the device.
     assert result["kernel_segment_inserts_per_sec"] > 0
     assert "pct_of_roofline" in result
     assert "pct_of_roofline_segment" in result
-    assert result["ingest"]["path"] in (
-        "scatter", "sort", "segment", "hostfold")
-    assert result["ingest"]["costs_ns_per_key"]
-    assert result["ingest_cost_table_ns_per_key"]
+    assert result["ingest"]["path"] == "scatter"
+    assert result["ingest"]["fold_min_keys"] == 1 << 16

@@ -10,7 +10,7 @@ served cluster has four chips):
 2. bank creation and growth on the mesh give a sharded bank;
 3. shapes: the mesh write program traces at the served size (262,144 x
    16,384, a 16 GiB bank), and so do the flat-index-free write-backs of
-   the tape, hostfold and delta paths at 131,072 rows, where an int32
+   the hostfold and delta paths at 131,072 rows, where an int32
    index over the whole bank would pass 2^31;
 4. after the warm-up's shapes, a window with a new distinct-row count
    builds no program;
@@ -181,7 +181,7 @@ def test_mesh_add_traces_at_the_served_size(mesh4):
     assert grown.shape == (524288, M)
 
 
-@pytest.mark.parametrize("program", ["tape_apply", "absorb_rows", "set_rows"])
+@pytest.mark.parametrize("program", ["absorb_rows", "set_rows"])
 def test_row_write_backs_trace_past_an_int32_flat_index(program):
     bank = jax.ShapeDtypeStruct((131072, M), jnp.int32)
     rows = jax.ShapeDtypeStruct((8,), jnp.int32)
@@ -190,16 +190,8 @@ def test_row_write_backs_trace_past_an_int32_flat_index(program):
         out, changed = jax.eval_shape(engine.hll_bank_absorb_rows, bank,
                                       regs, rows)
         assert changed.shape == (8,)
-    elif program == "set_rows":
-        out = jax.eval_shape(engine.hll_bank_set_rows, bank, regs, rows)
     else:
-        from redisson_tpu.ops import window_kernel as wk
-
-        out = jax.eval_shape(
-            lambda b, w, t, h: engine.tape_apply(b, w, t, h, (), n_hll=5,
-                                                 lanes=M)[0],
-            bank, jax.ShapeDtypeStruct((8, M), jnp.uint8),
-            jax.ShapeDtypeStruct((8, wk.TABLE_COLS), jnp.int32), rows)
+        out = jax.eval_shape(engine.hll_bank_set_rows, bank, regs, rows)
     assert out.shape == (131072, M)
 
 

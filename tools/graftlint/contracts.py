@@ -642,7 +642,7 @@ def declared_cells(repo_root: str = REPO_ROOT,
       wire   — write kinds the RESP command table stages
       geo    — the geo_* apply kinds remote arbitration dispatches
       facade — the delta-plane write trio every distributed subsystem
-               (journal, delta window, tape, geo ship set, replica
+               (journal, delta window, geo ship set, replica
                stream) must agree on
 
     The replay surface is intentionally dynamic: its declared set is the

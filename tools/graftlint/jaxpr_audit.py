@@ -37,7 +37,7 @@ HOST_SIDE = {
     "hll": {"make"},
     "u64": {"const", "to_python", "full"},
     "hashing": {"REDIS_HLL_SEED"},
-    "pallas_kernels": {"use_pallas", "row_block", "accumulate_changed"},
+    "pallas_kernels": {"use_pallas"},
 }
 
 _DTYPES_64 = {"int64", "uint64", "float64"}
@@ -145,7 +145,6 @@ def build_registry():
     from redisson_tpu.ops import bitset, bloom, hashing, hll
     from redisson_tpu.ops import pallas_kernels as pk
     from redisson_tpu.ops import u64 as u
-    from redisson_tpu.ops import window_kernel as wk
     from redisson_tpu.parallel.mesh import SLOT_AXIS, get_mesh
 
     bits = jnp.zeros(((1 << 20) + 8,), jnp.uint8)  # exercises the pad path
@@ -161,14 +160,6 @@ def build_registry():
     lengths = jnp.full((8,), 24, jnp.int32)
     stack = jnp.zeros((3, 2048), jnp.uint8)
     bank = jnp.zeros((100, 128), jnp.int32)
-    # one tape row per op kind (hll / bloom / bitset) plus a pad row, so
-    # the audit traces every switch arm of the window megakernel
-    tape_old = jnp.zeros((4, 256), jnp.uint8)
-    tape_wire = jnp.zeros((4, 256), jnp.uint8)
-    tape_tab = jnp.asarray(
-        [[wk.OP_HLL, 0, 0, 256], [wk.OP_BLOOM, 1, 256, 256],
-         [wk.OP_BITSET, 2, 512, 256], [wk.OP_PAD, 0, 0, 0]],
-        jnp.int32)
     pred = jnp.zeros((8,), bool)
 
     m_np2 = 1000003        # non-power-of-two <= 2^31: long-division path
@@ -274,11 +265,9 @@ def build_registry():
          lambda: (pc(pk.popcount_cells, block=1024), (small,)), {}),
         ("pallas.bitop_cells",
          lambda: (pc(pk.bitop_cells, op="or", block=1024), (stack,)), {}),
-        ("pallas.window_merge",
-         lambda: (pc(wk.window_merge_pallas, block=128, interpret=True),
-                  (tape_old, tape_wire, tape_tab)), {}),
-        ("pallas.window_merge_lax",
-         lambda: (wk.window_merge_lax, (tape_old, tape_wire, tape_tab)), {}),
+        ("pallas.delta_merge",
+         lambda: (pc(pk.delta_merge, block=1024, interpret=True),
+                  (stack, stack)), {}),
         # -- ingest kernels --------------------------------------------------
         ("ingest.hll_insert_segmented",
          lambda: (lambda r, b, k: ik.hll_insert_segmented(
