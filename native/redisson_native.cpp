@@ -304,6 +304,17 @@ static void emit_header(std::string& out, uint8_t type, int64_t payload) {
 // stack (the error path below tears the stream down).
 static const int kMaxRespDepth = 64;
 
+// strtoll of a header line that is not NUL-terminated in the buffer.
+static int64_t line_int(const char* s, size_t n) {
+  char small[32];
+  if (n < sizeof(small)) {
+    std::memcpy(small, s, n);
+    small[n] = 0;
+    return std::strtoll(small, nullptr, 10);
+  }
+  return std::strtoll(std::string(s, n).c_str(), nullptr, 10);
+}
+
 // Try to parse one reply at `pos`; append flattened form to `out`.
 // Returns true and advances pos past the reply on success; false (pos
 // untouched, out possibly partially longer — caller rolls back) if the
@@ -315,22 +326,23 @@ static bool parse_one(RespParser* p, size_t& pos, std::string& out,
   char t = b[pos];
   size_t eol = b.find("\r\n", pos + 1);
   if (eol == std::string::npos) return false;
-  std::string line = b.substr(pos + 1, eol - pos - 1);
+  const char* line = b.data() + pos + 1;
+  size_t line_len = eol - pos - 1;
   size_t after = eol + 2;
   switch (t) {
     case '+': case '-': {
-      emit_header(out, (uint8_t)t, (int64_t)line.size());
-      out.append(line);
+      emit_header(out, (uint8_t)t, (int64_t)line_len);
+      out.append(line, line_len);
       pos = after;
       return true;
     }
     case ':': {
-      emit_header(out, ':', std::strtoll(line.c_str(), nullptr, 10));
+      emit_header(out, ':', line_int(line, line_len));
       pos = after;
       return true;
     }
     case '$': {
-      int64_t len = std::strtoll(line.c_str(), nullptr, 10);
+      int64_t len = line_int(line, line_len);
       if (len < 0) {  // null bulk
         emit_header(out, '$', -1);
         pos = after;
@@ -350,7 +362,7 @@ static bool parse_one(RespParser* p, size_t& pos, std::string& out,
         p->poisoned = true;
         return false;
       }
-      int64_t count = std::strtoll(line.c_str(), nullptr, 10);
+      int64_t count = line_int(line, line_len);
       emit_header(out, '*', count);
       pos = after;
       for (int64_t i = 0; i < count; i++) {
@@ -376,9 +388,11 @@ RTPU_EXPORT int64_t rtpu_resp_parser_feed(RespParser* p, const uint8_t* data,
   p->buf.append((const char*)data, (size_t)len);
   for (;;) {
     size_t pos = p->pos;
-    std::string piece;
-    if (!parse_one(p, pos, piece)) break;
-    p->out.append(piece);
+    size_t mark = p->out.size();
+    if (!parse_one(p, pos, p->out)) {
+      p->out.resize(mark);  // drop a partial reply's flattened prefix
+      break;
+    }
     p->pos = pos;
     p->nready++;
   }
@@ -414,6 +428,88 @@ RTPU_EXPORT int64_t rtpu_resp_parser_take(RespParser* p, uint8_t* dst,
   p->out.clear();
   p->nready = 0;
   return n;
+}
+
+static inline int64_t flat_payload(const std::string& s, size_t pos) {
+  int64_t v;
+  std::memcpy(&v, s.data() + pos + 1, 8);
+  return v;
+}
+
+// End of the flattened item that starts at `pos`.
+static size_t flat_skip(const std::string& s, size_t pos) {
+  int64_t remaining = 1;
+  while (remaining-- > 0) {
+    char t = s[pos];
+    int64_t payload = flat_payload(s, pos);
+    pos += 9;
+    if (t == '*') {
+      if (payload > 0) remaining += payload;
+    } else if (t != ':' && payload > 0) {
+      pos += (size_t)payload;
+    }
+  }
+  return pos;
+}
+
+// Request-direction take: the completed top-level frames as an index, so a
+// server reads a command's arguments without one object per argument.
+//   frames [3 * nready]: per frame (nargs, first_arg, 0) when the frame is
+//     an array of non-null bulk or simple strings, whose bodies are copied
+//     back to back into `bodies` and described by args_off / args_len rows
+//     first_arg .. first_arg + nargs - 1; else (-1, start, end): the
+//     frame's flattened form (the take() stream) at bodies[start, end).
+// Returns the number of argument rows, or -1 when a capacity is short
+// (nothing is consumed then). Resets the stream like take().
+RTPU_EXPORT int64_t rtpu_resp_parser_take_requests(
+    RespParser* p, uint8_t* bodies, int64_t bodies_cap, int64_t* frames,
+    int64_t frames_cap, int64_t* args_off, int64_t* args_len,
+    int64_t args_cap) {
+  const std::string& s = p->out;
+  if (3 * p->nready > frames_cap) return -1;
+  size_t pos = 0;
+  int64_t nb = 0, na = 0;
+  for (int64_t f = 0; f < p->nready; f++) {
+    bool indexed = s[pos] == '*' && flat_payload(s, pos) >= 0;
+    int64_t count = indexed ? flat_payload(s, pos) : 0;
+    size_t q = pos + 9;
+    for (int64_t k = 0; indexed && k < count; k++) {
+      char t = s[q];
+      int64_t len = flat_payload(s, q);
+      indexed = (t == '$' || t == '+') && len >= 0;
+      q += 9 + (size_t)(indexed ? len : 0);
+    }
+    if (indexed) {
+      if (na + count > args_cap) return -1;
+      frames[3 * f] = count;
+      frames[3 * f + 1] = na;
+      frames[3 * f + 2] = 0;
+      q = pos + 9;
+      for (int64_t k = 0; k < count; k++, na++) {
+        int64_t len = flat_payload(s, q);
+        if (nb + len > bodies_cap) return -1;
+        std::memcpy(bodies + nb, s.data() + q + 9, (size_t)len);
+        args_off[na] = nb;
+        args_len[na] = len;
+        nb += len;
+        q += 9 + (size_t)len;
+      }
+      pos = q;
+    } else {
+      size_t end = flat_skip(s, pos);
+      int64_t len = (int64_t)(end - pos);
+      if (nb + len > bodies_cap) return -1;
+      std::memcpy(bodies + nb, s.data() + pos, (size_t)len);
+      frames[3 * f] = -1;
+      frames[3 * f + 1] = nb;
+      frames[3 * f + 2] = nb + len;
+      nb += len;
+      pos = end;
+    }
+  }
+  p->out.clear();
+  p->nready = 0;
+  return na;
 }
 
 // ---------------------------------------------------------------------------

@@ -56,6 +56,35 @@ def pack_u64(values) -> "np.ndarray":
     return values.view(np.uint32).reshape(-1, 2)
 
 
+def pack_keys(bodies: np.ndarray, lengths: np.ndarray,
+              width_buckets: Sequence[int]) -> tuple:
+    """Keys given as their bytes back to back (uint8 `bodies`) and their
+    `lengths` -> ([N, W] uint8 zero-padded, [N] int32 lengths).
+
+    W is the smallest width bucket holding the longest key, so repeated
+    batches of similar keys reuse one compiled kernel. The facade and the
+    wire both lay keys out here, so a value lands in the same registers
+    whichever way it came.
+    """
+    lengths = lengths.astype(np.int32, copy=False)
+    n = lengths.shape[0]
+    max_len = int(lengths.max()) if n else 1
+    w = next((b for b in width_buckets if b >= max_len), None)
+    if w is None:
+        raise ValueError(
+            f"key length {max_len} exceeds max width bucket "
+            f"{width_buckets[-1]}"
+        )
+    data = np.zeros((n, w), np.uint8)
+    if n:
+        # Vectorized fill: a row-major boolean mask selects exactly the
+        # first len(k) cells of each row, in concatenation order — one
+        # C-level scatter instead of a per-key python loop (which
+        # bounded string-key ingest at ~240K keys/s).
+        data[np.arange(w, dtype=np.int32)[None, :] < lengths[:, None]] = bodies
+    return data, lengths
+
+
 class RObject:
     """name + codec + executor; all state lives behind the executor."""
 
@@ -68,31 +97,12 @@ class RObject:
     # -- key encoding -------------------------------------------------------
 
     def _encode_batch(self, values: Iterable) -> tuple:
-        """values -> ([N, W] uint8 zero-padded, [N] int32 lengths).
-
-        W is the smallest configured width bucket holding the longest key, so
-        repeated batches of similar keys reuse one compiled kernel.
-        """
+        """values -> ([N, W] uint8 zero-padded, [N] int32 lengths), as
+        `pack_keys` lays them out."""
         keys: List[bytes] = [encode_key(v, self._codec) for v in values]
-        n = len(keys)
-        lengths = np.fromiter((len(k) for k in keys), np.int32, n) if n else \
-            np.zeros((0,), np.int32)
-        max_len = int(lengths.max()) if n else 1
-        w = next((b for b in self._width_buckets if b >= max_len), None)
-        if w is None:
-            raise ValueError(
-                f"key length {max_len} exceeds max width bucket "
-                f"{self._width_buckets[-1]}"
-            )
-        data = np.zeros((n, w), np.uint8)
-        if n:
-            # Vectorized fill: a row-major boolean mask selects exactly the
-            # first len(k) cells of each row, in concatenation order — one
-            # C-level scatter instead of a per-key python loop (which
-            # bounded string-key ingest at ~240K keys/s).
-            flat = np.frombuffer(b"".join(keys), np.uint8)
-            data[np.arange(w, dtype=np.int32)[None, :] < lengths[:, None]] = flat
-        return data, lengths
+        lengths = np.fromiter(map(len, keys), np.int32, len(keys))
+        return pack_keys(np.frombuffer(b"".join(keys), np.uint8), lengths,
+                         self._width_buckets)
 
     # -- RObject surface (RObjectAsync mirrored with _async suffix) ---------
 

@@ -88,12 +88,19 @@ def _load():
         lib.rtpu_free.argtypes = [ctypes.c_void_p]
         lib.rtpu_resp_parser_new.restype = ctypes.c_void_p
         lib.rtpu_resp_parser_free.argtypes = [ctypes.c_void_p]
-        lib.rtpu_resp_parser_feed.argtypes = [ctypes.c_void_p, u8p, ctypes.c_int64]
+        lib.rtpu_resp_parser_feed.argtypes = [
+            ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64]
         lib.rtpu_resp_parser_feed.restype = ctypes.c_int64
         lib.rtpu_resp_parser_pending.argtypes = [ctypes.c_void_p]
         lib.rtpu_resp_parser_pending.restype = ctypes.c_int64
         lib.rtpu_resp_parser_take.argtypes = [ctypes.c_void_p, u8p, ctypes.c_int64]
         lib.rtpu_resp_parser_take.restype = ctypes.c_int64
+        # raw addresses: the wire loop calls this once a socket read
+        lib.rtpu_resp_parser_take_requests.argtypes = [
+            ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+            ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p,
+            ctypes.c_void_p, ctypes.c_int64]
+        lib.rtpu_resp_parser_take_requests.restype = ctypes.c_int64
         lib.rtpu_hll_fold_batch.argtypes = [
             u8p, i64p, ctypes.c_int64, ctypes.c_uint64, u8p]
         lib.rtpu_hll_fold_u64.argtypes = [
@@ -295,11 +302,7 @@ class RespParser:
     def feed(self, data: bytes) -> List:
         if self._lib is None:
             return self._feed_py(data)
-        if self._h is None:
-            raise ValueError("RespParser is closed")
-        buf = np.frombuffer(data, np.uint8)
-        n = self._lib.rtpu_resp_parser_feed(
-            self._h, _u8p(np.ascontiguousarray(buf)), len(data))
+        n = self._feed_native(data)
         if n == 0:
             return []
         pend = self._lib.rtpu_resp_parser_pending(self._h)
@@ -307,6 +310,43 @@ class RespParser:
         got = self._lib.rtpu_resp_parser_take(self._h, _u8p(out), pend)
         assert got == pend
         return self._unflatten(out.tobytes(), n)
+
+    def feed_requests(self, data: bytes) -> List:
+        """feed() for the request direction: a frame that is an array of
+        bulk (or simple) strings comes out as a :class:`RequestFrame`, every
+        other frame as feed() decodes it."""
+        if self._lib is None:
+            return [_request_frame(item) for item in self._feed_py(data)]
+        n = self._feed_native(data)
+        if n == 0:
+            return []
+        pend = self._lib.rtpu_resp_parser_pending(self._h)
+        bodies = np.empty(pend, np.uint8)
+        # every argument's header alone takes 9 bytes of the stream
+        cap = pend // 9
+        index = np.empty(3 * n + 2 * cap, np.int64)
+        at = index.ctypes.data
+        nargs = self._lib.rtpu_resp_parser_take_requests(
+            self._h, bodies.ctypes.data, pend, at, 3 * n,
+            at + 8 * 3 * n, at + 8 * (3 * n + cap), cap)
+        assert nargs >= 0
+        offs = index[3 * n:3 * n + cap]
+        lens = index[3 * n + cap:]
+        body = bodies.tobytes()
+        out = []
+        for count, a, b in index[:3 * n].reshape(n, 3).tolist():
+            if count >= 0:
+                out.append(RequestFrame(body, offs, lens, a, count))
+            else:
+                out.append(self._unflatten(body[a:b], 1)[0])
+        return out
+
+    def _feed_native(self, data: bytes) -> int:
+        if self._h is None:
+            raise ValueError("RespParser is closed")
+        if not isinstance(data, bytes):
+            data = bytes(data)
+        return self._lib.rtpu_resp_parser_feed(self._h, data, len(data))
 
     @staticmethod
     def _unflatten(stream: bytes, count: int) -> List:
@@ -401,6 +441,62 @@ class RespParser:
                 cur += consumed
             return items, cur - pos
         raise ValueError(f"bad RESP header {t!r}")
+
+
+class RequestFrame:
+    """One command frame, an array of bulk strings, held as an index: the
+    argument bodies lie back to back in `data` (shared by the frames of one
+    socket read) at `offs[first + i]`, `lens[first + i]` bytes long. An
+    argument becomes a `bytes` object only when it is asked for; `run`
+    hands a command a run of arguments as arrays, with no object per
+    argument."""
+
+    __slots__ = ("data", "offs", "lens", "first", "n")
+
+    def __init__(self, data: bytes, offs: np.ndarray, lens: np.ndarray,
+                 first: int, n: int):
+        self.data = data
+        self.offs = offs
+        self.lens = lens
+        self.first = first
+        self.n = n
+
+    def __len__(self) -> int:
+        return self.n
+
+    def __getitem__(self, i):
+        if isinstance(i, slice):
+            return [self[j] for j in range(self.n)[i]]
+        j = self.first + range(self.n)[i]  # IndexError past either end
+        o = int(self.offs[j])
+        return self.data[o:o + int(self.lens[j])]
+
+    def __iter__(self):
+        return iter(self[:])
+
+    def run(self, start: int) -> Tuple[np.ndarray, np.ndarray]:
+        """(bodies, lengths) of arguments start..n-1: their bytes back to
+        back as a uint8 view of `data`, and an int64 view of their
+        lengths."""
+        a, b = self.first + start, self.first + self.n
+        lens = self.lens[a:b]
+        if a >= b:
+            return np.zeros(0, np.uint8), lens
+        o = int(self.offs[a])
+        end = int(self.offs[b - 1] + self.lens[b - 1])
+        return np.frombuffer(self.data, np.uint8, end - o, o), lens
+
+
+def _request_frame(item):
+    """A decoded frame as feed_requests() gives it: a list of strings
+    becomes a RequestFrame, anything else stays as it is."""
+    if not isinstance(item, list) or \
+            not all(isinstance(a, bytes) for a in item):
+        return item
+    lens = np.fromiter(map(len, item), np.int64, len(item))
+    offs = np.zeros(len(item), np.int64)
+    np.cumsum(lens[:-1], out=offs[1:])
+    return RequestFrame(b"".join(item), offs, lens, 0, len(item))
 
 
 def hll_fold(keys: Sequence[bytes], regs: np.ndarray, seed: int = 0) -> np.ndarray:

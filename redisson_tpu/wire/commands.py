@@ -2,10 +2,15 @@
 
 Each data-plane command builds an :class:`EngineCall` — a list of staged
 ``(target, kind, payload, nkeys)`` ops in the executor's narrow-waist shape
-(the exact payloads the model layer builds, reusing ``RObject._encode_batch``
-for key hashing so a value written over the wire and the same value written
-through the facade land in identical sketch registers) plus a renderer that
-turns the resolved results into the RESP reply frame.
+(the exact payloads the model layer builds, laying keys out with the model
+layer's ``pack_keys`` so a value written over the wire and the same value
+written through the facade land in identical sketch registers) plus a
+renderer that turns the resolved results into the RESP reply frame.
+
+A command gets the frame's arguments as a sequence of ``bytes``: the
+parser's :class:`~redisson_tpu.native.RequestFrame`, or a list for a frame
+with an argument of another RESP type. PFADD takes its elements from a
+RequestFrame as one run, with no Python object per element.
 
 The server coalesces EngineCalls from MANY connections into one
 ``ServingLayer.execute_many`` window; introspection commands (INFO, MEMORY,
@@ -19,6 +24,7 @@ from typing import Any, Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
+from redisson_tpu.models.object import pack_keys
 from redisson_tpu.wire import proto
 
 #: one staged op in the executor's narrow-waist shape
@@ -34,16 +40,18 @@ class EngineCall:
 
     ``render(results, proto_ver)`` receives one resolved result per op, in
     op order; ``key`` is the routing key (cluster slot checks), None for
-    keyspace-wide ops."""
+    keyspace-wide ops; ``indexed`` counts the arguments the command took
+    as an index run and never as Python objects."""
 
-    __slots__ = ("ops", "render", "key")
+    __slots__ = ("ops", "render", "key", "indexed")
 
     def __init__(self, ops: List[StagedOp],
                  render: Callable[[List[Any], int], bytes],
-                 key: Optional[str] = None):
+                 key: Optional[str] = None, indexed: int = 0):
         self.ops = ops
         self.render = render
         self.key = key
+        self.indexed = indexed
 
 
 def _text(b: Any) -> str:
@@ -71,13 +79,17 @@ def _need(args: Sequence[bytes], n: int, name: str) -> None:
 def _pfadd(client, args) -> EngineCall:
     _need(args, 2, "pfadd")
     key = _text(args[1])
-    values = list(args[2:])
     obj = client.get_hyper_log_log(key)
-    data, lengths = obj._encode_batch(values)
+    if isinstance(args, proto.RequestFrame):
+        data, lengths = pack_keys(*args.run(2), obj._width_buckets)
+        indexed = len(args) - 2
+    else:
+        data, lengths = obj._encode_batch(args[2:])
+        indexed = 0
     ops = [(key, "hll_add", {"data": data, "lengths": lengths},
             int(data.shape[0]))]
     return EngineCall(
-        ops, lambda rs, p: proto.integer(1 if rs[0] else 0), key)
+        ops, lambda rs, p: proto.integer(1 if rs[0] else 0), key, indexed)
 
 
 def _pfcount(client, args) -> EngineCall:

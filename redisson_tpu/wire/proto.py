@@ -1,9 +1,11 @@
 """The RESP frame codec — ONE implementation per direction.
 
 Request direction (client -> server): encode via the native C++ codec's
-``resp_encode`` / ``resp_encode_pipeline``, parse via ``RespParser`` — both
-re-exported here so every user (``wire/server.py``, ``interop/resp_client``,
-``interop/fake_server``) imports the same symbols from the same place.
+``resp_encode`` / ``resp_encode_pipeline``, parse via ``RespParser`` (the
+server through ``feed_requests``, whose command frames are indexed
+``RequestFrame``s) — all re-exported here so every user
+(``wire/server.py``, ``interop/resp_client``, ``interop/fake_server``)
+imports the same symbols from the same place.
 
 Reply direction (server -> client): the functions below render python
 values into RESP2/RESP3 frames.  ``fake_server`` used to carry its own
@@ -25,11 +27,12 @@ from __future__ import annotations
 
 from typing import Any, Iterable, List, Optional, Sequence, Tuple
 
-from redisson_tpu.native import (RespError, RespParser, resp_encode,
-                                 resp_encode_pipeline)
+from redisson_tpu.native import (RequestFrame, RespError, RespParser,
+                                 resp_encode, resp_encode_pipeline)
 
 __all__ = [
-    "RespError", "RespParser", "resp_encode", "resp_encode_pipeline",
+    "RequestFrame", "RespError", "RespParser", "resp_encode",
+    "resp_encode_pipeline",
     "ok", "simple", "err", "integer", "bulk", "array", "double",
     "null", "map_reply", "render_value", "moved", "ask", "busy",
     "RESP2", "RESP3",

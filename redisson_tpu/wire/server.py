@@ -80,6 +80,8 @@ GUARDED_BY = {
     "WireServer.latest_window_depth": "racy:gauge sample, torn read ok",
     "WireServer.dropped_conns": "racy:monotonic counter, torn read ok",
     "WireServer.loop_busy_ns": "racy:monotonic counter, torn read ok",
+    "WireServer.read_busy_ns": "racy:monotonic counter, torn read ok",
+    "WireServer.indexed_args": "racy:monotonic counter, torn read ok",
     "_WireConn.closing": "thread:event-loop confined",
     "_WireConn.proto_ver": "thread:event-loop confined",
     "_WireConn.authed": "thread:event-loop confined",
@@ -218,8 +220,12 @@ class WireServer:
         self.latest_window_depth = 0
         self.dropped_conns = 0
         # perf_counter ns the loop thread spent in wire.read, wire.flush
-        # and wire.settle (always on; the stage spans cover the same code)
+        # and wire.settle (always on; the stage spans cover the same code),
+        # and the wire.read share of it
         self.loop_busy_ns = 0
+        self.read_busy_ns = 0
+        # arguments a command took as an index run, never as objects
+        self.indexed_args = 0
 
     # -- lifecycle -----------------------------------------------------------
 
@@ -347,7 +353,7 @@ class WireServer:
             # stamp, so SLOWLOG's admission stage covers wire queueing.
             admitted_at = time.monotonic()
             try:
-                frames = parser.feed(data)
+                frames = parser.feed_requests(data)
             except proto.RespError as exc:
                 conn.window.reserve_immediate(
                     proto.err(f"Protocol error: {exc}"))
@@ -356,7 +362,9 @@ class WireServer:
                 for frame in frames:
                     self._dispatch_frame(conn, frame, admitted_at)
             self.bytes_out += conn.pump()
-        self.loop_busy_ns += time.perf_counter_ns() - t0
+        dt = time.perf_counter_ns() - t0
+        self.loop_busy_ns += dt
+        self.read_busy_ns += dt
         return frames is not None
 
     # -- per-frame dispatch --------------------------------------------------
@@ -364,29 +372,30 @@ class WireServer:
     def _dispatch_frame(self, conn: _WireConn, frame: Any,
                         admitted_at: float) -> None:
         self.commands_total += 1
-        if not isinstance(frame, list) or not frame or \
-                not isinstance(frame[0], (bytes, bytearray)):
+        # a RequestFrame, or a list where an argument is of another type
+        if not isinstance(frame, (proto.RequestFrame, list)) or not frame \
+                or not isinstance(frame[0], bytes):
             conn.window.reserve_immediate(
                 proto.err("Protocol error: expected a command array"))
             return
-        args = [bytes(a) if isinstance(a, (bytes, bytearray)) else a
-                for a in frame]
-        name = args[0].upper()
+        name = frame[0].upper()
         if not conn.authed and name not in (b"AUTH", b"HELLO", b"QUIT"):
             conn.window.reserve_immediate(
                 proto.err("Authentication required.", code="NOAUTH"))
             return
         if name in wire_commands.INLINE_COMMANDS:
-            conn.window.reserve_immediate(self._inline(conn, name, args))
+            conn.window.reserve_immediate(
+                self._inline(conn, name, list(frame)))
             return
         try:
-            call = wire_commands.build(self._client, args)
+            call = wire_commands.build(self._client, frame)
         except WireCommandError as exc:
             conn.window.reserve_immediate(proto.err(str(exc)))
             return
         except Exception as exc:
             conn.window.reserve_immediate(proto.err(str(exc) or repr(exc)))
             return
+        self.indexed_args += call.indexed
         if self._cluster is not None and call.key is not None:
             redirect = self._cluster.redirect_for(key_slot(call.key))
             if redirect is not None:
@@ -809,6 +818,8 @@ class WireServer:
                                  / max(1, self.windows_flushed)),
             "dropped_conns": self.dropped_conns,
             "loop_busy_ns": self.loop_busy_ns,
+            "read_busy_ns": self.read_busy_ns,
+            "indexed_args": self.indexed_args,
             # zeros unless the loop-stall witness is watching this loop
             **loop_gauges(self._loop),
         }

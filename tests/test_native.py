@@ -235,3 +235,130 @@ def test_hll_fold_rows_matches_byte_fold():
     ref = np.zeros(16384, np.uint8)
     native.hll_fold(keys, ref)
     np.testing.assert_array_equal(rows, ref)
+
+
+# -- request-side decode: feed_requests against feed ------------------------
+
+def _bulk(b):
+    return b"$%d\r\n" % len(b) + b + b"\r\n"
+
+
+def _command(rng, nargs):
+    """A command array of random bulk strings, 0 and 300 bytes among them."""
+    sizes = rng.choice([0, 1, 5, 16, 17, 300], size=nargs)
+    args = [bytes(rng.integers(0, 256, int(n), dtype=np.uint8)) for n in sizes]
+    return b"*%d\r\n" % nargs + b"".join(_bulk(a) for a in args)
+
+
+def _request_stream(seed):
+    """Pipelined frames: command arrays, and shapes that decode generically
+    (nested arrays, integers, null bulk and null array, simple strings and
+    errors, an empty array)."""
+    rng = np.random.default_rng(seed)
+    others = [b"*3\r\n$3\r\nSET\r\n:1\r\n$1\r\nx\r\n",
+              b"*2\r\n$4\r\nPING\r\n*2\r\n$1\r\na\r\n$-1\r\n",
+              b"*2\r\n$3\r\nGET\r\n$-1\r\n",
+              b"*2\r\n+PING\r\n$1\r\ny\r\n",
+              b":7\r\n", b"$-1\r\n", b"*-1\r\n", b"*0\r\n", b"+OK\r\n",
+              b"-ERR no\r\n", b"$5\r\nhello\r\n"]
+    parts = [_command(rng, int(rng.integers(1, 9))) for _ in range(6)]
+    parts += others
+    order = rng.permutation(len(parts))
+    return b"".join(parts[i] for i in order)
+
+
+def _plain(item):
+    """A decoded frame in a comparable form."""
+    if isinstance(item, native.RespError):
+        return ("error", str(item))
+    if isinstance(item, native.RequestFrame):
+        return ("frame", item[:])
+    if isinstance(item, list):
+        return [_plain(x) for x in item]
+    return item
+
+
+def _decode(parser_cls, stream, cuts, requests):
+    p = parser_cls()
+    out = []
+    try:
+        for lo, hi in zip([0] + cuts, cuts + [len(stream)]):
+            chunk = stream[lo:hi]
+            out.extend(p.feed_requests(chunk) if requests else p.feed(chunk))
+    finally:
+        p.close()
+    return out
+
+
+def _expected_requests(decoded):
+    """What feed_requests gives for feed()'s frames: each list of strings
+    as a RequestFrame of them."""
+    return [("frame", f) if isinstance(f, list)
+            and all(isinstance(a, bytes) for a in f) else _plain(f)
+            for f in decoded]
+
+
+POISON = (b"*1\r\n$4\r\nPING\r\n" + b"?garbage\r\n"
+          + b"*2\r\n$3\r\nGET\r\n$1\r\nk\r\n")
+DEEP = b"*1\r\n" * 70 + b":1\r\n"
+
+
+@pytest.mark.parametrize("impl", ["native", "python"])
+@pytest.mark.parametrize("case", ["every_cut", "pipelined", "empty_and_300",
+                                  "generic_shapes", "poisoned",
+                                  "too_deep"])
+def test_request_decode_matches_feed(impl, case, monkeypatch):
+    if impl == "python":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    rng = np.random.default_rng(17)
+    if case == "every_cut":
+        stream = _request_stream(1)
+        cutss = [[k] for k in range(1, len(stream))]
+    elif case == "pipelined":
+        stream = b"".join(_request_stream(s) for s in range(2, 6))
+        cutss = [[], sorted(set(rng.integers(1, len(stream), 40).tolist()))]
+    elif case == "empty_and_300":
+        stream = (b"*3\r\n$5\r\nPFADD\r\n$0\r\n\r\n" + _bulk(b"z" * 300)
+                  + b"*1\r\n$0\r\n\r\n")
+        cutss = [[], [5, 20, 100, 250]]
+    elif case == "generic_shapes":
+        stream = (b"*3\r\n$3\r\nSET\r\n:1\r\n$1\r\nx\r\n"
+                  b"*2\r\n$4\r\nPING\r\n*1\r\n$1\r\na\r\n:4\r\n")
+        cutss = [[], [3]]
+    elif case == "poisoned":
+        stream = POISON
+        cutss = [[], [len(b"*1\r\n$4\r\nPING\r\n") + 3]]
+    else:
+        stream = DEEP
+        cutss = [[]]
+    for cuts in cutss:
+        generic = _decode(native.RespParser, stream, cuts, False)
+        got = _decode(native.RespParser, stream, cuts, True)
+        assert [_plain(f) for f in got] == _expected_requests(generic), cuts
+    if case == "poisoned":
+        assert [_plain(f) for f in got] == [
+            ("frame", [b"PING"]),
+            ("error", "ERR protocol violation (bad header or nesting)")]
+    if case == "generic_shapes":
+        assert got[0] == [b"SET", 1, b"x"]
+        assert not any(isinstance(f, native.RequestFrame) for f in got)
+
+
+@pytest.mark.parametrize("impl", ["native", "python"])
+def test_request_frame_run_is_the_arguments_back_to_back(impl, monkeypatch):
+    if impl == "python":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    args = [b"PFADD", b"k", b"", b"a" * 17, b"\x00\r\n", b"z" * 300]
+    p = native.RespParser()
+    frames = p.feed_requests(native.resp_encode(*args) * 2)
+    assert len(frames) == 2
+    for f in frames:
+        assert isinstance(f, native.RequestFrame) and len(f) == 6
+        assert f[:] == args and list(f) == args and f[-1] == args[-1]
+        with pytest.raises(IndexError):
+            f[6]
+        bodies, lens = f.run(2)
+        assert bodies.tobytes() == b"".join(args[2:])
+        assert lens.tolist() == [len(a) for a in args[2:]]
+        empty, none = f.run(6)
+        assert empty.size == 0 and none.size == 0

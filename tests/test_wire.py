@@ -9,6 +9,7 @@ results identical to calling the facade directly.
 import socket
 import time
 
+import numpy as np
 import pytest
 
 from redisson_tpu.client import RedissonTPU
@@ -586,3 +587,104 @@ class TestLifecycle:
                 sock.close()
         finally:
             c.shutdown()
+
+
+# ---------------------------------------------------------------------------
+# PFADD from the indexed frame: the facade's key layout, no per-element object
+# ---------------------------------------------------------------------------
+
+
+def _pfadd_elements(case):
+    rng = np.random.default_rng(29)
+    if case == "random_0_300":
+        sizes = rng.integers(0, 301, 40)
+    elif case == "random_0_256":
+        sizes = rng.integers(0, 257, 256)
+    else:
+        sizes = {"empty": [0], "edge_16": [16, 3], "edge_17": [16, 17],
+                 "edge_256": [256, 0, 255], "edge_257": [256, 257],
+                 "none": []}[case]
+    return [bytes(rng.integers(0, 256, int(n), dtype="uint8"))
+            for n in sizes]
+
+
+@pytest.fixture(scope="module")
+def facade():
+    c = RedissonTPU(Config())
+    yield c
+    c.shutdown()
+
+
+@pytest.mark.parametrize("impl", ["native", "python"])
+@pytest.mark.parametrize("case", ["empty", "edge_16", "edge_17", "edge_256",
+                                  "edge_257", "none", "random_0_256",
+                                  "random_0_300"])
+def test_wire_pfadd_payload_matches_encode_batch(case, impl, facade,
+                                                 monkeypatch):
+    from redisson_tpu import native
+    from redisson_tpu.wire import commands as wire_commands
+
+    if impl == "python":
+        monkeypatch.setattr(native, "_load", lambda: None)
+    values = _pfadd_elements(case)
+    parser = proto.RespParser()
+    (frame,) = parser.feed_requests(
+        proto.resp_encode(b"PFADD", b"p:k", *values))
+    assert isinstance(frame, proto.RequestFrame)
+    obj = facade.get_hyper_log_log("p:k")
+    try:
+        want = obj._encode_batch(values)
+    except ValueError as exc:
+        with pytest.raises(ValueError) as got:
+            wire_commands.build(facade, frame)
+        assert str(got.value) == str(exc)
+        assert "exceeds max width bucket" in str(exc)
+        return
+    call = wire_commands.build(facade, frame)
+    ((key, kind, payload, nkeys),) = call.ops
+    assert (key, kind, nkeys) == ("p:k", "hll_add", len(values))
+    assert payload["data"].dtype == want[0].dtype
+    assert payload["data"].shape == want[0].shape
+    assert np.array_equal(payload["data"], want[0])
+    assert payload["lengths"].dtype == want[1].dtype == np.int32
+    assert np.array_equal(payload["lengths"], want[1])
+    assert call.indexed == len(values)
+
+
+def test_wire_pfadd_with_a_non_bulk_element_goes_through_encode_batch(facade):
+    from redisson_tpu.wire import commands as wire_commands
+
+    parser = proto.RespParser()
+    (frame,) = parser.feed_requests(
+        b"*4\r\n$5\r\nPFADD\r\n$3\r\np:k\r\n:42\r\n$1\r\nx\r\n")
+    assert frame == [b"PFADD", b"p:k", 42, b"x"]
+    call = wire_commands.build(facade, frame)
+    payload = call.ops[0][2]
+    want = facade.get_hyper_log_log("p:k")._encode_batch([b"42", b"x"])
+    assert payload["data"].tolist() == want[0].tolist()
+    assert payload["lengths"].tolist() == want[1].tolist()
+    assert call.indexed == 0
+
+
+def test_indexed_args_and_read_busy_counters_over_a_socket():
+    c = _wire_client()
+    try:
+        cli = _connect(c)
+        try:
+            ids = [str(10_000_000 + i).encode() for i in range(256)]
+            s0 = c.wire.snapshot()
+            assert cli.execute("PFADD", "ix:k", *ids) == 1
+            s1 = c.wire.snapshot()
+            assert cli.execute("SETBIT", "ix:b", "7", "1") == 0
+            s2 = c.wire.snapshot()
+            assert cli.execute("PFCOUNT", "ix:k") > 0
+        finally:
+            cli.close()
+        assert s1["indexed_args"] - s0["indexed_args"] == 256
+        assert s2["indexed_args"] - s1["indexed_args"] == 0
+        assert s1["commands_total"] - s0["commands_total"] == 1
+        assert 0 < s1["read_busy_ns"] - s0["read_busy_ns"] \
+            <= s1["loop_busy_ns"] - s0["loop_busy_ns"]
+        assert s2["read_busy_ns"] <= s2["loop_busy_ns"]
+    finally:
+        c.shutdown()
