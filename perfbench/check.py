@@ -6,17 +6,19 @@ replays the writers' commands, re-made from the seed, in the order their
 replies came. Under concurrent writes a read may see any write that was
 sent before its reply and must see every write acknowledged before it was
 sent, so a read's answer is held to the bracket between those two states
-(PFCOUNT, BITCOUNT and BITOP AND are monotone in their inputs). After the
+(every read modelled is monotone in the writes modelled). After the
 window, a sample of the keys written is read back over the wire and held
-to the model's final state. The write replies are held to what some
-serial order can give: a PFADD reply of 1 needs a register its ids can
-still raise; the SETBIT replies of one offset hold exactly one "was 0"
-(none if the fill had set it). run.compare folds these into the one
-number compared, `answer_gap`: the widest relative distance of a read or
-read-back answer outside its bracket, and 1 where a write reply no serial
-order gives, or a command never answered. run.py adds a second number,
-`failed_replies`, held to 0: an error or nil reply in the window is a
-wrong answer, whatever the program meant by it (a shed, an OOM).
+to the model's final state. The write replies of those keys are held to
+what some serial order can give. What each command does, and which write
+replies no serial order gives, is its model's, found by name in the
+state types' modules (perfbench/state/<type>.py; spec.py lists what such
+a module holds). run.compare folds these into the one number compared,
+`answer_gap`: the widest relative distance of a read or read-back answer
+outside its bracket (element by element for an array reply), and 1 where
+a write reply no serial order gives, or a command never answered. run.py
+adds a second number, `failed_replies`, held to 0: an error or nil reply
+in the window is a wrong answer, whatever the program meant by it (a
+shed, an OOM).
 """
 
 from __future__ import annotations
@@ -25,9 +27,7 @@ from typing import Any, Dict, List, Optional, Tuple
 
 import numpy as np
 
-from perfbench import traffic
-from perfbench.ref import bits as rbits
-from perfbench.ref import hll as rhll
+from perfbench import spec, traffic
 
 READ_SAMPLE = 256
 READBACK_SAMPLE = 32
@@ -43,44 +43,36 @@ class Model:
         self.space = config["space"]
         self.seed = seed
         self.keys = {k: (e, i) for k, e, i in state_keys(config)}
+        self.types = {t: spec.state_type(t)
+                      for t in {e["type"] for e in config["state"]}}
         self._base: Dict[str, np.ndarray] = {}
 
     def base(self, key: str) -> np.ndarray:
         """The key's state after the fill (a copy-safe cached array)."""
         if key not in self._base:
             e, i = self.keys[key]
-            f = e["fill"]
-            if e["type"] == "hll":
-                ids = traffic.fill_elements(
-                    self.seed, np.asarray([i]), int(f["elements"]),
-                    traffic.value(f["of"], self.space))[0]
-                st = rhll.hll_registers(ids)
-            else:
-                nbits = traffic.value(e["bits"], self.space)
-                if "density" in f:
-                    st = np.packbits(traffic.fill_cells(
-                        self.seed, i, nbits, float(f["density"])))
-                else:
-                    st = rbits.make(nbits)
-                if "set" in f:
-                    rbits.set_bits(st, [traffic.value(x, self.space)
-                                        for x in f["set"]])
-            self._base[key] = st
+            self._base[key] = self.type_of(key).base(e, i, self.seed,
+                                                     self.space)
         return self._base[key]
 
-    def kind(self, key: str) -> str:
-        return self.keys[key][0]["type"]
+    def type_of(self, key: str):
+        """The module of the key's state type."""
+        return self.types[self.keys[key][0]["type"]]
 
 
 class Writes:
     """The writers' records with each write's key and payload re-made."""
 
-    def __init__(self, role: traffic.Role, rec: Dict[str, np.ndarray]):
+    def __init__(self, role: traffic.Role, rec: Dict[str, np.ndarray],
+                 models: Dict[str, Any]):
         if len(role.commands) != 1:
             raise ValueError("a write is one command")
         self.role = role
         self.rec = rec
         self.cmd = role.commands[0][0].upper()
+        if self.cmd not in models:
+            raise ValueError(f"the model cannot replay {self.cmd}")
+        self.model = models[self.cmd]
         n = len(rec["j"])
         self.key = np.empty(n, object)
         for conn in np.unique(rec["conn"]):
@@ -89,23 +81,14 @@ class Writes:
         self.ok = ~rec["err"]
 
     def payload(self, idx: np.ndarray) -> List[Any]:
-        """What writes `idx` add: uint64 ids (PFADD) or a bit offset
-        (SETBIT)."""
+        """What writes `idx` add, as their command's model says."""
         out = [None] * len(idx)
         conns = self.rec["conn"][idx]
         for conn in np.unique(conns):
             at = np.nonzero(conns == conn)[0]
             cmds = self.role.make(int(conn), self.rec["j"][idx[at]])
             for k, c in zip(at, cmds):
-                args = c[0]
-                if self.cmd == "PFADD":
-                    out[k] = args[2]
-                elif self.cmd == "SETBIT":
-                    if args[3] != "1":
-                        raise ValueError("the model replays SETBIT 1 only")
-                    out[k] = int(args[2])
-                else:
-                    raise ValueError(f"the model cannot replay {self.cmd}")
+                out[k] = self.model.payload(c[0])
         return out
 
 
@@ -114,33 +97,24 @@ class KeyHistory:
 
     def __init__(self, model: Model, writes: Writes, key: str):
         self.model = model
-        self.kind = model.kind(key)
         self.key = key
+        self.apply = writes.model.apply
         idx = np.nonzero(writes.key == key)[0]
         self.idx = idx
         self.t_send = writes.rec["t_send"][idx]
         self.t_reply = writes.rec["t_reply"][idx]
         self.ok = writes.ok[idx]
-        pay = writes.payload(idx) if len(idx) else []
-        if self.kind == "hll":
-            self.pay = [rhll.bucket_rank(np.asarray(p, np.uint64))
-                        for p in pay]
-        else:
-            self.pay = pay
+        self.pay = writes.payload(idx) if len(idx) else []
 
-    def _apply(self, st, k):
-        if self.kind == "hll":
-            b, r = self.pay[k]
-            np.maximum.at(st, b, r)
-        else:
-            rbits.set_bits(st, [self.pay[k]])
+    def base(self) -> np.ndarray:
+        return self.model.base(self.key)
 
     def states_at(self, times: np.ndarray, upper: bool,
                   drop: Optional[np.ndarray] = None) -> List[np.ndarray]:
         """The state at each instant: with the writes acknowledged before
         it (upper=False), or with every write sent before it (upper=True).
         `drop` masks writes the state loses (the control)."""
-        base = self.model.base(self.key)
+        base = self.base()
         if upper:
             t, use = self.t_send, np.ones(len(self.idx), bool)
         else:
@@ -154,51 +128,23 @@ class KeyHistory:
         w = 0
         for q in np.argsort(times, kind="stable"):
             while w < len(order) and t[order[w]] < times[q]:
-                self._apply(st, order[w])
+                self.apply(st, self.pay[order[w]])
                 w += 1
             out[q] = st.copy() if len(times) > 1 else st
         return out
 
 
-    def pfadd_wrong(self, replies: np.ndarray,
-                    lag_s: float = 0.0) -> Tuple[int, int]:
-        """(wrong, checked) PFADD replies of this key: a reply of 1 is
-        wrong where the writes acknowledged before it was sent already
-        hold every (bucket, rank) it adds, since then no register can
-        change wherever it is ordered. `lag_s` > 0 replaces the replies by
-        a reference's that answers from state that much older (the
-        control)."""
-        if self.kind != "hll" or not len(self.idx):
-            return 0, 0
-        st = self.model.base(self.key).copy()
-        stale = st.copy()
-        acked = np.argsort(self.t_reply, kind="stable")
-        acked = acked[self.ok[acked]]
-        wrong = checked = w = v = 0
-        for q in np.argsort(self.t_send, kind="stable"):
-            while w < len(acked) and self.t_reply[acked[w]] < self.t_send[q]:
-                self._apply(st, acked[w])
-                w += 1
-            if not self.ok[q]:
-                continue
-            checked += 1
-            b, r = self.pay[q]
-            reply = replies[q]
-            if lag_s:
-                while v < len(acked) and (self.t_reply[acked[v]]
-                                          < self.t_send[q] - lag_s):
-                    self._apply(stale, acked[v])
-                    v += 1
-                reply = int(np.any(r > stale[b]))
-            if reply == 1 and not np.any(r > st[b]):
-                wrong += 1
-        return wrong, checked
+def _hashable(arg):
+    return arg.tobytes() if isinstance(arg, np.ndarray) else arg
 
 
-def answer(model: Model, cmds: List[list], state, memo=None) -> List[float]:
+def answer(reads: Dict[str, Any], cmds: List[list], state,
+           memo=None) -> List[Any]:
     """The model's answer to one read (its commands in order) with key
-    states from `state(key)`; BITOP's destination lives for the read.
-    `memo` caches results by the identity of the states they read."""
+    states from `state(key)`; a key that a command of the read makes (its
+    model's `makes`) lives for the read, and DEL of it answers how many
+    it deleted. `memo` caches results by the command, its arguments
+    other than keys, and the identity of the states it reads."""
     memo = {} if memo is None else memo
     scratch: Dict[str, np.ndarray] = {}
 
@@ -206,7 +152,7 @@ def answer(model: Model, cmds: List[list], state, memo=None) -> List[float]:
         return scratch[k] if k in scratch else state(k)
 
     def cached(tag, srcs, fn):
-        mk = (tag,) + tuple(id(x) for x in srcs)
+        mk = tag + tuple(id(x) for x in srcs)
         if mk not in memo:
             memo[mk] = (fn(), srcs)  # holding srcs keeps their ids unique
         return memo[mk][0]
@@ -214,37 +160,49 @@ def answer(model: Model, cmds: List[list], state, memo=None) -> List[float]:
     out = []
     for args in cmds:
         name = args[0].upper()
-        if name == "PFCOUNT":
-            srcs = [get(k) for k in args[1:]]
-            out.append(cached("pf", srcs, lambda: rhll.estimate(
-                np.maximum.reduce(srcs) if len(srcs) > 1 else srcs[0])))
-        elif name == "BITOP":
-            op = args[1].upper()
-            srcs = [get(k) for k in args[3:]]
-            scratch[args[2]] = cached(op, srcs,
-                                      lambda: rbits.bitop(op, *srcs))
-            out.append(float(max(len(s) for s in srcs)))
-        elif name == "DEL":
+        if name == "DEL":
             out.append(float(sum(scratch.pop(k, None) is not None
                                  for k in args[1:])))
-        elif name == "BITCOUNT":
-            src = get(args[1])
-            out.append(cached("count", [src],
-                              lambda: float(rbits.bitcount(src))))
-        else:
+            continue
+        if name not in reads:
             raise ValueError(f"the model cannot answer {name}")
+        m = reads[name]
+        keys = m.keys_read(args)
+        dest = m.makes(args) if hasattr(m, "makes") else None
+        tag = (name,) + tuple(
+            _hashable(a) for a in args[1:]
+            if not (isinstance(a, str) and (a in keys or a == dest)))
+        srcs = [get(k) for k in keys]
+        if dest is not None:
+            scratch[dest] = cached(("made",) + tag, srcs,
+                                   lambda: m.make(args, srcs))
+        out.append(cached(tag, srcs, lambda: m.answer(args, srcs)))
     return out
 
 
-def gap(got: float, lo: float, hi: float, estimate: bool) -> float:
-    """Relative distance of `got` outside [lo, hi]; an estimate is
-    rounded to an integer by the program, so it may lie half a unit out."""
-    slack = 0.5 if estimate else 0.0
-    if got < lo - slack:
-        return (lo - slack - got) / max(lo, 1.0)
-    if got > hi + slack:
-        return (got - hi - slack) / max(hi, 1.0)
-    return 0.0
+def gap(got, lo, hi, rounds: bool) -> float:
+    """Relative distance of `got` outside [lo, hi], the widest element's
+    for an array reply; an answer the program rounds to an integer may
+    lie half a unit out. An answer with another number of elements than
+    the model's is wrong."""
+    got, lo, hi = (np.asarray(x, np.float64) for x in (got, lo, hi))
+    if got.size != lo.size:
+        return 1.0
+    got = got.reshape(lo.shape)
+    slack = 0.5 if rounds else 0.0
+    below = (lo - slack - got) / np.maximum(lo, 1.0)
+    above = (got - hi - slack) / np.maximum(hi, 1.0)
+    return float(np.max(np.maximum(np.maximum(below, above), 0.0),
+                        initial=0.0))
+
+
+def replies(rec: Dict[str, np.ndarray], n: int) -> List[Any]:
+    """Read `n`'s replies, one a command: an integer, or the int64 array
+    of an array reply, as the load generator's slots hold them."""
+    row = rec["values"][n]
+    if "widths" not in rec:
+        return list(row)
+    return np.split(row, np.cumsum(rec["widths"])[:-1])
 
 
 class Check:
@@ -255,7 +213,8 @@ class Check:
         self.cell = cell
         self.seed = seed
         self.model = Model(cell.config, seed)
-        self.writes = Writes(writers, wrec)
+        write_models, self.reads = spec.command_models(spec.state_types())
+        self.writes = Writes(writers, wrec, write_models)
         self.readers = readers
         self.rrec = rrec
         self.window = window
@@ -290,13 +249,17 @@ class Check:
         return hot + list(rng.choice(rest, n, replace=False)) if n else hot
 
     def readback_commands(self) -> List[list]:
-        return [["PFCOUNT", k] if self.model.kind(k) == "hll"
-                else ["BITCOUNT", k] for k in self.readback_keys]
+        return [self.model.type_of(k).readback(k)
+                for k in self.readback_keys]
 
     def history(self, key: str) -> KeyHistory:
         if key not in self._hist:
             self._hist[key] = KeyHistory(self.model, self.writes, key)
         return self._hist[key]
+
+    def _rounds(self, args) -> bool:
+        m = self.reads.get(args[0].upper())
+        return bool(m is not None and m.rounds)
 
     # -- the numbers --------------------------------------------------------
 
@@ -308,7 +271,7 @@ class Check:
         worst = 0.0
         keyed: Dict[str, List[int]] = {}
         for n, cmds in enumerate(self.read_cmds):
-            for k in _keys_read(cmds):
+            for k in _keys_read(self.reads, cmds):
                 keyed.setdefault(k, []).append(n)
         lo_st: Dict[Tuple[str, int], np.ndarray] = {}
         hi_st: Dict[Tuple[str, int], np.ndarray] = {}
@@ -320,7 +283,7 @@ class Check:
             t_reply = rr["t_reply"][self.read_idx[ns]]
             if not len(h.idx):
                 for n in ns:
-                    lo_st[k, n] = hi_st[k, n] = stale[k, n] = h.model.base(k)
+                    lo_st[k, n] = hi_st[k, n] = stale[k, n] = h.base()
                 continue
             for n, s in zip(ns, h.states_at(t_send, upper=False)):
                 lo_st[k, n] = s
@@ -331,16 +294,17 @@ class Check:
                     stale[k, n] = s
         memo: Dict[tuple, Any] = {}
         for n, cmds in enumerate(self.read_cmds):
-            lo = answer(self.model, cmds, lambda k: lo_st[k, n], memo)
-            hi = answer(self.model, cmds, lambda k: hi_st[k, n], memo)
-            ans = (answer(self.model, cmds, lambda k: stale[k, n], memo)
-                   if lag_s else rr["values"][self.read_idx[n]])
+            lo = answer(self.reads, cmds, lambda k: lo_st[k, n], memo)
+            hi = answer(self.reads, cmds, lambda k: hi_st[k, n], memo)
+            ans = (answer(self.reads, cmds, lambda k: stale[k, n], memo)
+                   if lag_s else replies(rr, self.read_idx[n]))
             for args, a, l, h in zip(cmds, ans, lo, hi):
-                g = gap(float(a), min(l, h), max(l, h),
-                        args[0].upper() == "PFCOUNT")
+                g = gap(a, np.minimum(l, h), np.maximum(l, h),
+                        self._rounds(args))
                 if g > worst or self.worst is None:
                     worst = g
-                    self.worst = (g, args[:4], float(a), l, h)
+                    self.worst = (g, args[:4],
+                                  float(a) if np.ndim(a) == 0 else a, l, h)
         return worst
 
     def readback_gap(self, got: List[Any], drop_every: int = 0) -> float:
@@ -351,85 +315,47 @@ class Check:
                               got):
             h = self.history(k)
             inf = np.asarray([np.inf])
-            lo = answer(self.model, [args],
+            lo = answer(self.reads, [args],
                         lambda _: h.states_at(inf, upper=False)[0])[0]
-            hi = answer(self.model, [args],
+            hi = answer(self.reads, [args],
                         lambda _: h.states_at(inf, upper=True)[0])[0]
             if drop_every:
                 drop = np.zeros(len(h.idx), bool)
                 drop[::drop_every] = True
-                g = answer(self.model, [args], lambda _: h.states_at(
+                g = answer(self.reads, [args], lambda _: h.states_at(
                     inf, upper=False, drop=drop)[0])[0]
-            if not isinstance(g, (int, float)):
+            if isinstance(g, list) and all(isinstance(x, int) for x in g):
+                g = np.asarray(g)
+            elif not isinstance(g, (int, float, np.ndarray)):
                 return 1.0
-            worst = max(worst, gap(float(g), lo, hi, args[0] == "PFCOUNT"))
+            worst = max(worst, gap(g, lo, hi, self._rounds(args)))
         return worst
 
-    def pfadd_wrong_share(self, lag_s: float = 0.0) -> Optional[float]:
-        """Share of the read-back keys' PFADD replies that say a register
-        changed where none could have (KeyHistory.pfadd_wrong)."""
-        if self.writes.cmd != "PFADD":
-            return None
+    def write_wrong_share(self, control: Dict[str, Any]) -> float:
+        """Share of the read-back keys' write replies that no serial order
+        gives, by the writers' command model (its `wrong`); `control`
+        (run.CONTROL) puts the control's replies in the program's place."""
         wrong = checked = 0
         for k in self.readback_keys:
             h = self.history(k)
-            w, c = h.pfadd_wrong(self.writes.rec["value"][h.idx], lag_s)
+            w, c = self.writes.model.wrong(h, self.writes.rec["value"][h.idx],
+                                           control)
             wrong, checked = wrong + w, checked + c
         return wrong / max(checked, 1)
 
-    def setbit_wrong_share(self, drop_every: int = 0) -> Optional[float]:
-        """Share of SETBIT offsets whose acknowledged replies do not hold
-        exactly one 0, or hold one where the fill had set the bit. None
-        where the writers send no SETBIT. `drop_every` > 0 replaces the
-        program's replies by a serial reference's that loses every n-th
-        acknowledged write (the control)."""
-        w = self.writes
-        if w.cmd != "SETBIT":
-            return None
-        ok = np.nonzero(w.ok)[0]
-        ok = ok[np.argsort(w.rec["t_reply"][ok], kind="stable")]
-        vals = w.rec["value"][ok].copy()
-        keys = w.key[ok].astype(str)
-        offs = np.asarray(w.payload(ok), np.int64)
-        if drop_every:
-            seen = {}
-            for n, (k, o) in enumerate(zip(keys, offs)):
-                bit = seen.get((k, o))
-                if bit is None:
-                    bit = int(rbits.get_bits(self.model.base(k), [o])[0])
-                vals[n] = bit
-                if n % drop_every:
-                    seen[k, o] = 1
-        if np.any((vals != 0) & (vals != 1)):
-            return 1.0
-        wrong = total = 0
-        for k in np.unique(keys):
-            at = keys == k
-            o, zero = offs[at], (vals[at] == 0)
-            uniq, inv = np.unique(o, return_inverse=True)
-            zeros = np.bincount(inv, weights=zero, minlength=len(uniq))
-            want = 1 - rbits.get_bits(self.model.base(k), uniq)
-            wrong += int(np.sum(zeros != want))
-            total += len(uniq)
-        return wrong / max(total, 1)
 
-
-def _keys_read(cmds: List[list]) -> List[str]:
-    """Keys of the configuration that one read's commands read (a BITOP
-    destination is the read's own scratch, not a key of the state)."""
+def _keys_read(reads: Dict[str, Any], cmds: List[list]) -> List[str]:
+    """Keys of the configuration that one read's commands read (a key
+    that the read makes is the read's own, not a key of the state)."""
     out, made = [], set()
     for args in cmds:
         name = args[0].upper()
-        if name == "PFCOUNT":
-            srcs = args[1:]
-        elif name == "BITOP":
-            srcs = args[3:]
-            made.add(args[2])
-        elif name == "BITCOUNT":
-            srcs = args[1:2]
-        elif name == "DEL" and set(args[1:]) <= made:
-            srcs = []
-        else:
+        if name == "DEL" and set(args[1:]) <= made:
+            continue
+        if name not in reads:
             raise ValueError(f"the model cannot answer {name}")
-        out += [k for k in srcs if k not in made]
+        m = reads[name]
+        if hasattr(m, "makes"):
+            made.add(m.makes(args))
+        out += [k for k in m.keys_read(args) if k not in made]
     return out

@@ -172,12 +172,18 @@ ERRORS: dict = {}  # error reply -> how many (first few kinds)
 
 
 def _value(reply):
-    """(integer, failed): every command the mixes send answers an integer
-    or OK; an error or a nil reply is a failed command."""
+    """(value, failed): an integer, OK (0), or an array of integers (a
+    tuple) is an answered reply; an error or a nil reply, an array that
+    holds one, or anything else is a failed command."""
     if isinstance(reply, int):
         return reply, False
     if reply == "OK":
         return 0, False
+    if isinstance(reply, list):
+        bad = [x for x in reply if not isinstance(x, int)]
+        if not bad:
+            return tuple(reply), False
+        reply = bad[0]
     what = str(reply) if isinstance(reply, resp.RespError) else \
         f"not an integer: {reply!r}"[:80]
     if what in ERRORS or len(ERRORS) < 5:
@@ -287,7 +293,23 @@ def _sleep_until(t: float) -> None:
         time.sleep(min(left, 0.05))
 
 
+def _slots(values) -> np.ndarray:
+    """Replies as int64 slots: [n] where every reply is an integer; else
+    [n, width], each array reply zero-padded to the longest (an integer
+    fills one slot)."""
+    if all(isinstance(v, int) for v in values):
+        return np.asarray(values, np.int64)
+    values = [v if isinstance(v, tuple) else (v,) for v in values]
+    out = np.zeros((len(values), max(map(len, values))), np.int64)
+    for row, v in zip(out, values):
+        row[:len(v)] = v
+    return out
+
+
 def _pack(role_name: str, rec, info) -> bytes:
+    """The records as one .npz. A read's replies fill `values`, one slot
+    a command; where some command answers an array, each command gets
+    the width of its longest reply, and `widths` says how many."""
     buf = io.BytesIO()
     if role_name == "writers":
         cols = list(zip(*rec)) if rec else [[]] * 6
@@ -295,11 +317,20 @@ def _pack(role_name: str, rec, info) -> bytes:
                  j=np.asarray(cols[1], np.int64),
                  t_send=np.asarray(cols[2], np.float64),
                  t_reply=np.asarray(cols[3], np.float64),
-                 value=np.asarray(cols[4], np.int64),
+                 value=_slots(cols[4]),
                  err=np.asarray(cols[5], bool),
                  info=np.asarray(json.dumps(info)))
     else:
         cols = list(zip(*rec)) if rec else [[]] * 8
+        per_cmd = [_slots(c) for c in zip(*cols[7])]
+        extra = {}
+        if any(s.ndim > 1 for s in per_cmd):
+            per_cmd = [s.reshape(len(rec), -1) for s in per_cmd]
+            extra["widths"] = np.asarray([s.shape[1] for s in per_cmd],
+                                         np.int64)
+            values = np.concatenate(per_cmd, axis=1)
+        else:
+            values = np.asarray(cols[7], np.int64).reshape(len(rec), -1)
         np.savez(buf, i=np.asarray(cols[0], np.int64),
                  conn=np.asarray(cols[1], np.int64),
                  j=np.asarray(cols[2], np.int64),
@@ -307,7 +338,7 @@ def _pack(role_name: str, rec, info) -> bytes:
                  t_send=np.asarray(cols[4], np.float64),
                  t_reply=np.asarray(cols[5], np.float64),
                  err=np.asarray(cols[6], bool),
-                 values=np.asarray(cols[7], np.int64).reshape(len(rec), -1),
+                 values=values, **extra,
                  info=np.asarray(json.dumps(info)))
     return buf.getvalue()
 

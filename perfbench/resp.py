@@ -50,7 +50,8 @@ def call(sock, parser: "Parser", args):
 
 class Parser:
     """Incremental parser of replies: integers, simple strings, errors,
-    bulk strings and arrays of those."""
+    bulk strings and arrays of those; a nil bulk string or nil array is
+    None."""
 
     def __init__(self):
         self.buf = bytearray()
@@ -99,8 +100,10 @@ class Parser:
             return bytes(self.buf[nxt:nxt + n]), nxt + n + 2
         if tag == b"*":
             n = int(body)
+            if n < 0:  # a nil array
+                return None, nxt
             items = []
-            for _ in range(max(n, 0)):
+            for _ in range(n):
                 item = self._one(nxt)
                 if item is None:
                     return None

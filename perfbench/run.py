@@ -176,9 +176,9 @@ def warm_up(cell: spec.Cell, system, compiles: "Compiles",
     """Run the cell's own load until it is steady, and return when the
     window starts: at least the traffic's `warmup_s`, then until no
     program was built for SETTLE_S and the wire holds no more commands in
-    flight than the writers keep plus READ_BACKLOG_S of reads, so the
-    planner's first-use timings, compiles and the backlog they leave all
-    fall in set-up. Past WARMUP_MAX_S the window starts anyway (logged)."""
+    flight than the writers keep plus READ_BACKLOG_S of reads, so
+    first-use compiles and the backlog they leave all fall in set-up.
+    Past WARMUP_MAX_S the window starts anyway (logged)."""
     w, r = cell.traffic["writers"], cell.traffic["readers"]
     most = (int(w["connections"]) * int(w["pipeline"])
             + float(r["rate"]) * READ_BACKLOG_S)
@@ -201,8 +201,9 @@ def warm_up(cell: spec.Cell, system, compiles: "Compiles",
 
 #: The control: the reference in the program's place, breaking the stated
 #: guarantee that an acknowledged write is seen by every later command.
-#: Reads and PFADD replies see state 50 ms old; the read-back and SETBIT
-#: replies come from state that lost every 1000th acknowledged write.
+#: Reads see state 50 ms old, the read-back state that lost every 1000th
+#: acknowledged write; each write command's model takes one of the two
+#: for its replies (perfbench/state/<type>.py).
 CONTROL = {"lag_s": 0.05, "drop_every": 1000}
 
 
@@ -271,7 +272,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
         ck = check.Check(cell, seed, roles["writers"], roles["readers"],
                          recs["writers"][0], recs["readers"][0], (t0, t1))
         got = [system.command(a) for a in ck.readback_commands()]
-        planner = system.planner_table()
+        rule_inputs = system.rule_inputs()
     finally:
         for ch in children:
             ch.stop()
@@ -280,7 +281,7 @@ def run_cell(cell: spec.Cell, seed: int, seconds: float, traced: bool,
     wrec, winfo = recs["writers"]
     rrec, rinfo = recs["readers"]
     log(f"# writers: {winfo}; readers: {rinfo}")
-    log(f"# planner: {json.dumps(planner)}")
+    log(f"# ingest rule inputs: {json.dumps(rule_inputs)}")
     checks = compare(cell, ck, got, winfo, rinfo)
     log(f"# worst read (gap, command, answer, low, high): {ck.worst}")
 
@@ -362,20 +363,16 @@ def compare(cell, ck, got, winfo, rinfo, control=None):
     write reply that no serial order gives or a command never answered.
     run_cell adds `failed_replies`."""
     control = control or {}
-    lag = control.get("lag_s", 0.0)
+    wrong_share = f"{ck.writes.cmd.lower()}_wrong_share"
     parts = {
-        "read": ck.read_gap(lag_s=lag),
+        "read": ck.read_gap(lag_s=control.get("lag_s", 0.0)),
         "read_back": ck.readback_gap(
             got, drop_every=control.get("drop_every", 0)),
-        "pfadd_wrong_share": ck.pfadd_wrong_share(lag_s=lag),
-        "setbit_wrong_share": ck.setbit_wrong_share(
-            drop_every=control.get("drop_every", 0)),
+        wrong_share: ck.write_wrong_share(control),
         "unanswered": winfo["unanswered"] + rinfo["unanswered"],
     }
     log(f"# answer gaps{' (control)' if control else ''}: {parts}")
-    wrong_replies = any(parts[k] for k in
-                        ("pfadd_wrong_share", "setbit_wrong_share",
-                         "unanswered"))
+    wrong_replies = parts[wrong_share] or parts["unanswered"]
     value = 1.0 if wrong_replies else max(parts["read"], parts["read_back"])
     return {"answer_gap": {"value": float(value),
                            "limit": float(cell.traffic["limits"]

@@ -1,8 +1,33 @@
 """Find a cell's parts by name: `BENCHMARK.json` names the cell, its
 configuration (`perfbench/configs/<config>.json`) and its traffic mix
 (`perfbench/traffic/<traffic>.json`); per-layer metric readers live in
-`perfbench/layers/<metric>.py` and kernel cost functions in
-`perfbench/kernels/<kernel>.py`. Nothing here knows a cell by name."""
+`perfbench/layers/<metric>.py`, kernel cost functions in
+`perfbench/kernels/<kernel>.py` and state types in
+`perfbench/state/<type>.py`. Nothing here knows a cell by name.
+
+A state type is what a configuration's `state` entry names in `type`, and
+its module holds everything the harness knows of that structure:
+
+- `fill(system, keys, seed)`: the program side of the fill, through the
+  System's `client`, `dispatch` and `space`; `keys` are the
+  configuration's `(key, entry, key_index)` of this type, in file order;
+- `base(entry, key_index, seed, space)`: the key's state after the fill,
+  from the plain references (perfbench/ref);
+- `READ_KINDS`: the program's op kinds that answer this type's reads;
+- `readback(key)`: the command that reads a key back after the window;
+- `WRITES`: write command name -> model with `payload(args)` (what the
+  write adds), `apply(state, payload)` and `wrong(history, replies,
+  control)`, the (wrong, checked) replies of one key's history that no
+  serial order gives, or the control's (`run.CONTROL`) in their place;
+- `READS`: read command name -> model with `keys_read(args)`,
+  `answer(args, states)` (a number, or an array for an array reply) and
+  `rounds` (the program rounds the answer to an integer); a read that
+  makes a key of the read's own (BITOP's destination) also has
+  `makes(args)` and `make(args, states)`.
+
+It imports neither jax nor redisson_tpu at module level: the load
+generator never imports jax, and `fill` reaches the program through the
+System."""
 
 from __future__ import annotations
 
@@ -77,6 +102,41 @@ def load_cell(name: str, root: str = ROOT, bench: Dict[str, Any] = None,
                             if _applies(m, name)],
                 per_layer=[m for m in bench["per_layer"]
                            if _applies(m, name)])
+
+
+def state_type(name: str, root: str = ROOT):
+    """perfbench/state/<name>.py, the module of the state type `name`."""
+    path = os.path.join(root, "perfbench", "state", name + ".py")
+    if not os.path.isfile(path):
+        raise ValueError(f"unknown state type {name!r}: there is no {path}")
+    return load_module("state", name, root)
+
+
+def state_types(root: str = ROOT) -> Dict[str, Any]:
+    """Every perfbench/state/<type>.py, by type."""
+    names = sorted(f[:-3] for f in os.listdir(
+        os.path.join(root, "perfbench", "state")) if f.endswith(".py"))
+    return {n: load_module("state", n, root) for n in names}
+
+
+def command_models(types: Dict[str, Any]):
+    """(writes, reads): every state type's WRITES and READS merged, by
+    upper-case command name. A command that two types model is an error,
+    and so is DEL, which the harness answers (a read deletes the keys it
+    made)."""
+    writes: Dict[str, Any] = {}
+    reads: Dict[str, Any] = {}
+    owner = {"DEL": "the harness"}
+    for t, mod in types.items():
+        for table, out in ((mod.WRITES, writes), (mod.READS, reads)):
+            for name, model in table.items():
+                name = name.upper()
+                if name in owner:
+                    raise ValueError(f"{name} is modelled by both "
+                                     f"{owner[name]} and state type {t!r}")
+                owner[name] = f"state type {t!r}"
+                out[name] = model
+    return writes, reads
 
 
 def load_module(kind: str, name: str, root: str = ROOT):

@@ -1,4 +1,5 @@
-"""The system under test, built from a configuration file: the only module
+"""The system under test, built from a configuration file: with the state
+types' `fill` functions (perfbench/state/), which it calls, the only code
 of the benchmark that imports redisson_tpu. It starts the program with the
 configuration's settings, fills its state in bulk, and reads the program's
 counters and spans; the load itself comes over the wire from the load
@@ -27,13 +28,12 @@ import inspect
 import socket
 from typing import Any, Dict, Iterable, List
 
-import numpy as np
+from perfbench import resp, slots, spec, traffic
 
-from perfbench import resp, slots, traffic
-
-#: op kinds that answer a read (per-layer metrics of reads use these)
-READ_KINDS = frozenset({"hll_count", "hll_count_with", "bitset_op",
-                        "bitset_cardinality", "bitset_size", "bitset_get"})
+#: op kinds that answer a read (per-layer metrics of reads use these): the
+#: union of every state type's READ_KINDS
+READ_KINDS = frozenset().union(*(m.READ_KINDS for m in
+                                 spec.state_types().values()))
 
 _BUSY = "loop_busy_ns"
 
@@ -111,8 +111,10 @@ class System:
     def __init__(self, config: Dict[str, Any], traced: bool):
         from redisson_tpu.client import RedissonTPU
 
-        self._space = config["space"]
+        self.space = config["space"]
         self._keys = state_keys(config)
+        self._types = {e["type"]: spec.state_type(e["type"])
+                       for e in config["state"]}
         cfg = program_config(config["program"])
         self.spans: List[tuple] = []
         if traced:
@@ -121,6 +123,9 @@ class System:
         self.client = RedissonTPU.create(cfg)
         manager = self.client.cluster
         self.cluster = manager is not None
+        # where a state type's fill sends an engine op by key
+        self.dispatch = (self.client._dispatch if self.cluster
+                         else self.client._executor)
         # the engine clients behind the facade, and the wire servers
         if not self.cluster:
             self.engines = {"": self.client}
@@ -152,41 +157,16 @@ class System:
 
     # -- fill ---------------------------------------------------------------
 
-    def fill(self, seed: int, keys=None, batch: int = 2048) -> None:
-        """Every key of the configuration, filled as its entry says: HLLs
-        by PFADD through the facade in batches, bit sets with a density by
-        the program's bulk import, bit sets with `set` offsets by SETBIT.
-        In a cluster the facade's router sends each key to its shard."""
-        from redisson_tpu.store import ObjectType
-
-        keys = keys if keys is not None else self._keys
-        hll = [(k, e, i) for k, e, i in keys if e["type"] == "hll"]
-        for lo in range(0, len(hll), batch):
-            part = hll[lo:lo + batch]
-            f = part[0][1]["fill"]
-            elems = traffic.fill_elements(
-                seed, np.asarray([i for _, _, i in part]),
-                int(f["elements"]), traffic.value(f["of"], self._space))
-            b = self.client.create_batch()
-            for (k, _, _), ids in zip(part, elems):
-                b.get_hyper_log_log(k).add_ints_async(ids)
-            b.execute()
-        dispatch = (self.client._dispatch if self.cluster
-                    else self.client._executor)
-        for k, e, i in keys:
-            if e["type"] != "bits":
-                continue
-            nbits = traffic.value(e["bits"], self._space)
-            f = e["fill"]
-            if "density" in f:
-                cells = traffic.fill_cells(seed, i, nbits, float(f["density"]))
-                dispatch.execute_sync(k, "bits_import",
-                                      {"otype": ObjectType.BITSET,
-                                       "array": cells,
-                                       "meta": {"nbits": nbits}})
-            if "set" in f:
-                self.client.get_bit_set(k).set_bits(
-                    [traffic.value(x, self._space) for x in f["set"]])
+    def fill(self, seed: int) -> None:
+        """Every key of the configuration, filled by its state type's
+        `fill` (perfbench/state/<type>.py): one type after another, in the
+        order the configuration first names them, each with its keys in
+        file order."""
+        by_type: Dict[str, list] = {}
+        for k, e, i in self._keys:
+            by_type.setdefault(e["type"], []).append((k, e, i))
+        for t, keys in by_type.items():
+            self._types[t].fill(self, keys, seed)
 
     # -- counters -----------------------------------------------------------
 
@@ -203,12 +183,15 @@ class System:
                 "backend": _summed(b.counters
                                    for b in self.backends.values())}
 
-    def planner_table(self) -> Dict[str, Any]:
-        """The ingest planner's cost table; in a cluster, keyed by engine
+    def rule_inputs(self) -> Dict[str, Any]:
+        """The measured inputs of the program's ingest link rule
+        (`backend_tpu.RuleInputs`); in a cluster, keyed by engine
         (`shard<i>`, or `mesh` for the one shared engine)."""
-        if not self.cluster:
-            return self.backends[""].planner.table()
-        return {n: b.planner.table() for n, b in self.backends.items()}
+        from redisson_tpu.backend_tpu import RuleInputs
+
+        tables = {n: RuleInputs(b.store.device).table()
+                  for n, b in self.backends.items()}
+        return tables if self.cluster else tables[""]
 
     # -- read-back over the wire ------------------------------------------
 

@@ -117,14 +117,30 @@ def test_the_recorded_fixture_reduces_exactly_as_before():
                 "idle_by_cause": {"rtpu/wire.read": 1.0,
                                   "host waiting": 0.5}}},
      25.0),
+    ("wire.read_us_per_command",
+     {"delta": {"wire": {"read_busy_ns": 3_000_000, "commands_total": 20_000,
+                         "loop_busy_ns": 9_000_000}}, "seconds": 20.0},
+     0.15),
+    # a cluster's counters are summed over its servers: the mean of them all
+    ("wire.read_us_per_command",
+     {"delta": {"wire": {"read_busy_ns": 400_000_000 + 200_000_000,
+                         "commands_total": 3_000 + 1_000}}},
+     150.0),
+    # no command read in the window: nothing to read
+    ("wire.read_us_per_command",
+     {"delta": {"wire": {"read_busy_ns": 5_000, "commands_total": 0}},
+      "seconds": 20.0},
+     None),
 ])
 def test_each_stage_reader_on_a_known_ctx(metric, ctx, value):
-    assert spec.load_module("layers", metric).read(ctx) == pytest.approx(value)
+    got = spec.load_module("layers", metric).read(ctx)
+    assert got == (None if value is None else pytest.approx(value))
 
 
 @pytest.mark.parametrize("metric", [
     "wire.loop_busy_pct", "executor.write_wait_p95_ms",
-    "backend.stage_ms_per_run", "completer.d2h_wait_ms_per_run"])
+    "backend.stage_ms_per_run", "completer.d2h_wait_ms_per_run",
+    "wire.read_us_per_command"])
 def test_a_program_without_the_counters_reads_nothing(metric):
     ctx = {"delta": {"wire": {"ops_flushed": 9},
                      "executor": {"runs_completed": 3},

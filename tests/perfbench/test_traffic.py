@@ -70,6 +70,47 @@ def test_resp_encodes_binary_ids_and_parses_replies():
     assert p.feed(b"\n:2\r\n") == [[1, 2]]
 
 
+@pytest.mark.parametrize("reply,value,failed", [
+    (7, 7, False),
+    ("OK", 0, False),
+    ([1, 0, 1], (1, 0, 1), False),
+    ([1, None, 1], 0, True),
+    ([1, resp.RespError("ERR x")], 0, True),
+    ([b"a"], 0, True),
+    (None, 0, True),
+    (resp.RespError("BUSY try again"), 0, True),
+], ids=["integer", "ok", "array_of_integers", "array_with_nil",
+        "array_with_error", "array_of_bulk", "nil", "error"])
+def test_load_generator_reply_values(reply, value, failed):
+    from perfbench import loadgen
+
+    assert loadgen._value(reply) == (value, failed)
+
+
+def test_array_replies_keep_their_slots():
+    """A read whose commands answer an integer and an array: its records
+    keep every element, and the check splits them by command again."""
+    import io
+
+    from perfbench import check, loadgen
+
+    rec = [(i, 0, i, 0.1 * i, 0.1 * i, 0.1 * i + 0.01, False,
+            [5 + i, (1, 0, i % 2)]) for i in range(3)]
+    z = np.load(io.BytesIO(loadgen._pack("readers", rec, {})))
+    rr = {k: z[k] for k in z.files if k != "info"}
+    assert list(rr["widths"]) == [1, 3] and rr["values"].shape == (3, 4)
+    got = check.replies(rr, 1)
+    assert list(got[0]) == [6] and list(got[1]) == [1, 0, 1]
+    # integers alone keep one slot a command and no widths
+    z = np.load(io.BytesIO(loadgen._pack("readers", [r[:7] + ([5, 6],)
+                                                      for r in rec], {})))
+    assert "widths" not in z.files and z["values"].shape == (3, 2)
+
+
+def test_resp_parses_a_nil_array_as_nil():
+    assert resp.Parser().feed(b"*-1\r\n*0\r\n$-1\r\n") == [None, [], None]
+
+
 def test_load_generator_child_never_imports_jax():
     code = ("import sys; sys.path.insert(0, %r); import perfbench.loadgen; "
             "assert 'jax' not in sys.modules, sorted(sys.modules)" % ROOT)
